@@ -1,16 +1,12 @@
 """Collectives microbench — the federated communication fast path.
 
-Two A/Bs, both in a subprocess (the emulated device count must be set
-before jax initializes):
-
-  * ring vs XLA psum at matched payload, per wire format: per-device bytes
-    per aggregation round (the kernel's measured byte ledger — identical to
-    the ``ring_wire_plan`` accounting) and wall time per round on the
-    emulated 8-way data mesh.  The headline number: the int8 wire moves
-    <= 0.27x the bytes of the f32 psum baseline.
-  * ZeRO-1 AdamW gather vs scatter formulation: compiled collective bytes
-    from the dry-run HLO cost model (``repro.launch.hlo_cost``) — the
-    scatter-update schedule must be strictly smaller.
+Ring vs XLA psum at matched payload, per wire format: per-device bytes per
+aggregation round (the kernel's measured byte ledger — identical to the
+``ring_wire_plan`` accounting) and wall time per round on an emulated
+8-way CPU data mesh.  The headline number: the int8 wire moves <= 0.27x
+the bytes of the f32 psum baseline.  It runs in a CPU-pinned subprocess
+(the emulated device count must be set before jax initializes, and the
+child must never compete with its parent for an accelerator).
 
 ``benchmarks/run.py --only collectives`` writes the rows to
 ``BENCH_collectives.json`` (the per-PR comm-perf trajectory artifact).
@@ -83,34 +79,6 @@ with mesh:
                      "bytes_vs_f32_psum": measured / f32_psum_bytes,
                      "max_abs_err": err})
 
-# --- ZeRO-1 update: gather vs scatter collective term (dry-run cost model)
-from repro.configs import get_smoke_config
-from repro.launch.hlo_cost import analyze
-from repro.models.registry import get_model
-from repro.dist.sharding import param_specs, opt_state_specs, to_shardings
-from repro.optim.adamw import adamw_init, adamw_update, adamw_update_zero1
-
-cfg = get_smoke_config("qwen3-0.6b")
-api = get_model(cfg)
-zmesh = jax.make_mesh((4, 2), ("data", "model"))
-params = api.init(cfg, jax.random.PRNGKey(0))
-opt = adamw_init(params)
-psh = to_shardings(param_specs(params, zmesh), zmesh)
-osh = to_shardings(opt_state_specs(params, zmesh), zmesh)
-with zmesh:
-    for name, fn in (("zero1_gather",
-                      lambda p, g, s: adamw_update(p, g, s, 3)),
-                     ("zero1_scatter",
-                      lambda p, g, s: adamw_update_zero1(p, g, s, 3,
-                                                         mesh=zmesh))):
-        jitted = jax.jit(fn, in_shardings=(psh, psh, {"mu": osh, "nu": osh}),
-                         out_shardings=(psh, {"mu": osh, "nu": osh}))
-        parsed = analyze(jitted.lower(params, params, opt).compile()
-                         .as_text())
-        rows.append({"case": name,
-                     "collective_bytes": parsed["collective_total_bytes"],
-                     "by_kind": parsed["collective_bytes"]})
-
 for r in rows:
     print("ROW " + json.dumps(r), flush=True)
 """
@@ -119,6 +87,7 @@ for r in rows:
 def run(full: bool = False):
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    env["JAX_PLATFORMS"] = "cpu"
     env.pop("XLA_FLAGS", None)
     r = subprocess.run(
         [sys.executable, "-c", _SUB.replace("__FULL__", str(full))],
@@ -130,19 +99,12 @@ def run(full: bool = False):
     for line in r.stdout.splitlines():
         if line.startswith("ROW "):
             rows.append(emit("collectives", **json.loads(line[4:])))
-    scatter = next(x for x in rows if x.get("case") == "zero1_scatter")
-    gather = next(x for x in rows if x.get("case") == "zero1_gather")
     int8 = next(x for x in rows if x.get("case") == "ring"
                 and x.get("wire") == "int8")
     rows.append(emit(
         "collectives_summary",
         int8_vs_f32_psum=round(int8["bytes_vs_f32_psum"], 4),
-        int8_under_027=int8["bytes_vs_f32_psum"] <= 0.27,
-        zero1_scatter_smaller=(scatter["collective_bytes"] <
-                               gather["collective_bytes"]),
-        zero1_collective_cut=round(
-            1 - scatter["collective_bytes"] / gather["collective_bytes"],
-            4)))
+        int8_under_027=int8["bytes_vs_f32_psum"] <= 0.27))
     return rows
 
 

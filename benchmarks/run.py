@@ -59,7 +59,7 @@ def main() -> None:
         "fig6": fig6_ablation.run,             # Fig 6: ablation
         "kernels": kernels_bench.run,          # kernel microbench
         "serving": serving_bench.run,          # engine + paged-pool A/Bs
-        "collectives": collectives_bench.run,  # ring vs psum + ZeRO-1 A/Bs
+        "collectives": collectives_bench.run,  # ring vs psum A/B per wire
     }
     only = set(filter(None, args.only.split(",")))
     unknown = only - set(suites) - {"roofline"}

@@ -81,7 +81,6 @@ def sharded_flash_decode(q, k, v, kv_pos, q_pos, mesh, *, k_scale=None,
     table is replicated and localized inside each shard).  Per-shard kernel
     partials + psum-style combine; same signature/result as
     ``repro.kernels.ops.flash_decode``."""
-    from jax.experimental.shard_map import shard_map
 
     from repro.kernels import ops
 
@@ -109,8 +108,8 @@ def sharded_flash_decode(q, k, v, kv_pos, q_pos, mesh, *, k_scale=None,
         args += [k_scale, v_scale]
         specs += [kv_spec, kv_spec]
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=tuple(specs),
-                       out_specs=q_spec, check_rep=False)
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=tuple(specs),
+                       out_specs=q_spec, check_vma=False)
     def body(q, k, v, kv_pos, qp, plen, *rest):
         rest = list(rest)
         tbl = rest.pop(0) if paged else None

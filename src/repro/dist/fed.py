@@ -170,12 +170,11 @@ def aggregate_adapters(member_adapters, weights, mesh=None, *,
         raise ValueError(
             f"member dim {n} must divide the federation axes {axes} ({prod})")
 
-    from jax.experimental.shard_map import shard_map
     member_spec = P(axes if len(axes) > 1 else axes[0])
 
-    @functools.partial(shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(member_spec, member_spec),
-                       out_specs=P(), check_rep=False)
+                       out_specs=P(), check_vma=False)
     def agg(ad, w):
         local = jax.tree.map(lambda a: wsum(w, a), ad)
         return jax.tree.map(lambda x: jax.lax.psum(x, axes), local)

@@ -134,7 +134,6 @@ def ring_aggregate(member_adapters, weights, mesh, *, wire: str = None,
         raise ValueError(
             f"member dim {n} must divide the federation axes {axes} ({prod})")
 
-    from jax.experimental.shard_map import shard_map
     entry = axes if len(axes) > 1 else axes[0]
     member_spec = P(entry)
     carry_state = state is not None
@@ -161,9 +160,9 @@ def ring_aggregate(member_adapters, weights, mesh, *, wire: str = None,
         ledger = [] if byte_ledger is None else byte_ledger
 
         @jax.jit
-        @functools.partial(shard_map, mesh=mesh,
+        @functools.partial(jax.shard_map, mesh=mesh,
                            in_specs=(member_spec, member_spec, st_spec),
-                           out_specs=(P(), st_spec), check_rep=False)
+                           out_specs=(P(), st_spec), check_vma=False)
         def agg(ad, w, st):
             local = jax.tree.map(lambda a: wsum(w, a), ad)
             flat = jnp.concatenate(

@@ -301,10 +301,7 @@ def to_shardings(specs, mesh):
 
 def current_mesh():
     """The ambient ``with mesh:`` context's physical mesh, or None."""
-    try:
-        from jax._src.mesh import thread_resources
-    except ImportError:                       # pragma: no cover - older jax
-        from jax.interpreters.pxla import thread_resources
+    from jax._src.mesh import thread_resources
     mesh = thread_resources.env.physical_mesh
     return None if mesh.empty else mesh
 

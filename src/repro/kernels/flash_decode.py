@@ -34,9 +34,16 @@ Two cache layouts share the kernel body:
     READ-ONLY to the kernel, so one physical block may appear in many
     tables at once (copy-on-write prefix sharing): every sharer streams the
     same tile, and slots a sharer hasn't logically reached are excluded by
-    the causal/ring masks, not by table bookkeeping.  On real TPUs
-    ``block_size`` should be a multiple of the 128-lane tile; the serving
-    smoke configs use smaller blocks under interpret mode.
+    the causal/ring masks, not by table bookkeeping.
+
+TPU tiling: every block's last two dims are either tile multiples or the
+array's full extent, which is what the TPU lowering accepts.  Per-row query
+positions and prefix lengths ride scalar prefetch (SMEM) next to the paged
+table; per-slot rows (``kv_pos``, int8 absmax scales) are laid out as
+``(rows, 1, slots)`` so a tile is one ``(1, block)`` row, and the scales
+multiply score/probability columns after the matmuls instead of the
+``(block, D)`` tiles before them.  So paged blocks of 16 slots (the serving
+default) compile as well as 128.
 
 ``paged_block_copy`` is the pool's copy-on-write data move: one physical
 block's tile duplicated to another block across all layers of a
@@ -168,14 +175,18 @@ def paged_gather(k, v, kv_pos, k_scale, v_scale, block_tables):
 
 def _kernel(*refs, bps: int, kind: str, window: int, softcap: float,
             scale: float, quantized: bool, paged: bool):
+    # scalar-prefetch operands (SMEM) come first: per-row query position and
+    # prefix length, then the paged launch's block table
+    qpos_ref, plen_ref, *refs = refs
     if paged:
-        tbl_ref, *refs = refs                    # scalar-prefetch operand
+        tbl_ref, *refs = refs
     if quantized:
-        (qpos_ref, plen_ref, q_ref, k_ref, v_ref, kpos_ref, ks_ref, vs_ref,
+        (q_ref, k_ref, v_ref, kpos_ref, ks_ref, vs_ref,
          o_m, o_l, o_acc, m_s, l_s, acc_s) = refs
     else:
-        (qpos_ref, plen_ref, q_ref, k_ref, v_ref, kpos_ref,
+        (q_ref, k_ref, v_ref, kpos_ref,
          o_m, o_l, o_acc, m_s, l_s, acc_s) = refs
+    b = pl.program_id(0)
     j = pl.program_id(2)
     local = jax.lax.rem(j, bps)
 
@@ -188,20 +199,22 @@ def _kernel(*refs, bps: int, kind: str, window: int, softcap: float,
     q = q_ref[0, 0].astype(jnp.float32)              # (G, D)
     k = k_ref[0].astype(jnp.float32)                 # (block_kv, D)
     v = v_ref[0].astype(jnp.float32)
-    if quantized:                                    # fused int8 dequant
-        k = k * ks_ref[0].astype(jnp.float32)        # scales (block_kv, 1)
-        v = v * vs_ref[0].astype(jnp.float32)
 
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+    if quantized:
+        # fused int8 dequant: a per-slot scale multiplies a column of the
+        # scores (and a column of p below), so the (1, block_kv) scale rows
+        # apply after the matmuls instead of to every (block_kv, D) tile
+        s = s * ks_ref[0, 0].astype(jnp.float32)
     if softcap > 0:
         s = softcap * jnp.tanh(s / softcap)
-    kp = kpos_ref[...]                               # (1, block_kv)
-    mask = _slot_mask(kp, qpos_ref[0, 0], plen_ref[0, 0],
+    kp = kpos_ref[0]                                 # (1, block_kv)
+    mask = _slot_mask(kp, qpos_ref[b], plen_ref[b],
                       kind=kind, window=window)      # (1, block_kv)
     if paged:
         # ungranted table entries stream pool block 0 — drop them wholesale
         # (a freed block's stale kv_pos may otherwise pass the ring mask)
-        mask = mask & (tbl_ref[pl.program_id(0), j] >= 0)
+        mask = mask & (tbl_ref[b, j] >= 0)
     s = jnp.where(mask, s, _NEG)
 
     m_prev = m_s[...]                                # (G, 1)
@@ -209,8 +222,9 @@ def _kernel(*refs, bps: int, kind: str, window: int, softcap: float,
     p = jnp.where(mask, jnp.exp(s - m_new), 0.0)     # (G, block_kv)
     corr = jnp.exp(m_prev - m_new)
     l_s[...] = l_s[...] * corr + p.sum(-1, keepdims=True)
+    pv = p * vs_ref[0, 0].astype(jnp.float32) if quantized else p
     acc_s[...] = acc_s[...] * corr + jnp.dot(
-        p, v, preferred_element_type=jnp.float32)
+        pv, v, preferred_element_type=jnp.float32)
     m_s[...] = m_new
 
     @pl.when(local == bps - 1)
@@ -254,11 +268,29 @@ def _broadcast_pos(x, batch: int):
                             (batch, 1)).astype(jnp.int32)
 
 
+def _row_pos(x, batch: int):
+    """Scalar or (B,) position -> the (B,) int32 scalar-prefetch row."""
+    return _broadcast_pos(x, batch)[:, 0]
+
+
+def _slot_rows(x):
+    """(R, S) per-slot leaf -> (R, 1, S): a (1, 1, tile) block then ends in
+    the full unit dim and a lane-aligned (or full) slot extent, the tiling
+    the TPU lowering accepts for per-slot rows."""
+    return x.reshape(x.shape[0], 1, x.shape[1])
+
+
+def _scale_rows(x):
+    """(R, S, Hk, 1) int8 absmax scales -> (R, Hk, 1, S): one (1, S) row of
+    per-slot scales per KV head, tiled like ``_slot_rows``."""
+    return x[..., 0].swapaxes(1, 2)[:, :, None, :]
+
+
 def _partial_outputs(B: int, Hk: int, n_splits: int, G_pad: int, D: int,
                      bps: int):
     """(out_specs, out_shape, scratch_shapes) for the per-split (m, l, acc)
     partials — shared by the contiguous and paged launches (the index_map
-    takes the paged launch's trailing scalar-prefetch table arg as *_)."""
+    takes the launch's trailing scalar-prefetch args as *_)."""
     def idx(b, h, j, *_, _bps=bps):
         return (b, h, j // _bps, 0, 0)
 
@@ -339,38 +371,36 @@ def flash_decode(q, k, v, kv_pos, q_pos, *, k_scale=None, v_scale=None,
     # KV tile into a contiguous, well-tiled (block_kv, D) slab.
     kr = k.reshape(B, S_pad, Hk * D)
     vr = v.reshape(B, S_pad, Hk * D)
-    qp = _broadcast_pos(q_pos, B)
-    plen = _broadcast_pos(prefix_len, B)
 
-    smem = lambda: pl.BlockSpec((1, 1), lambda b, h, j: (b, 0),  # noqa: E731
-                                memory_space=pltpu.SMEM)
     in_specs = [
-        smem(), smem(),
-        pl.BlockSpec((1, 1, G_pad, D), lambda b, h, j: (b, h, 0, 0)),
-        pl.BlockSpec((1, block_kv, D), lambda b, h, j: (b, j, h)),
-        pl.BlockSpec((1, block_kv, D), lambda b, h, j: (b, j, h)),
-        pl.BlockSpec((1, block_kv), lambda b, h, j: (b, j)),
+        pl.BlockSpec((1, 1, G_pad, D), lambda b, h, j, *_: (b, h, 0, 0)),
+        pl.BlockSpec((1, block_kv, D), lambda b, h, j, *_: (b, j, h)),
+        pl.BlockSpec((1, block_kv, D), lambda b, h, j, *_: (b, j, h)),
+        pl.BlockSpec((1, 1, block_kv), lambda b, h, j, *_: (b, 0, j)),
     ]
-    args = [qp, plen, qg, kr, vr, kv_pos]
+    args = [qg, kr, vr, _slot_rows(kv_pos)]
     if quantized:
-        in_specs += [pl.BlockSpec((1, block_kv, 1), lambda b, h, j: (b, j, h)),
-                     pl.BlockSpec((1, block_kv, 1), lambda b, h, j: (b, j, h))]
-        args += [k_scale.reshape(B, S_pad, Hk),
-                 v_scale.reshape(B, S_pad, Hk)]
+        scale_spec = pl.BlockSpec((1, 1, 1, block_kv),
+                                  lambda b, h, j, *_: (b, h, 0, j))
+        in_specs += [scale_spec, scale_spec]
+        args += [_scale_rows(k_scale), _scale_rows(v_scale)]
 
     out_specs, out_shape, scratch = _partial_outputs(B, Hk, n_splits, G_pad,
                                                      D, bps)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, Hk, n_blocks),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        scratch_shapes=scratch)
     m, l, acc = pl.pallas_call(
         functools.partial(_kernel, bps=bps, kind=kind, window=window,
                           softcap=softcap, scale=D ** -0.5,
                           quantized=quantized, paged=False),
-        grid=(B, Hk, n_blocks),
-        in_specs=in_specs,
-        out_specs=out_specs,
+        grid_spec=grid_spec,
         out_shape=out_shape,
-        scratch_shapes=scratch,
         interpret=interpret,
-    )(*args)
+    )(_row_pos(q_pos, B), _row_pos(prefix_len, B), *args)
     return _finish(m, l, acc, G, q, return_partials)
 
 
@@ -393,32 +423,30 @@ def _flash_decode_paged(q, k, v, kv_pos, block_tables, q_pos, *, k_scale,
 
     kr = k.reshape(nb, bs, Hk * D)
     vr = v.reshape(nb, bs, Hk * D)
-    qp = _broadcast_pos(q_pos, B)
-    plen = _broadcast_pos(prefix_len, B)
 
-    def pool_idx(b, h, j, t):
-        return (jnp.maximum(t[b, j], 0), 0, h)
+    def blk(b, j, t):
+        return jnp.maximum(t[b, j], 0)
 
-    smem = lambda: pl.BlockSpec(                                # noqa: E731
-        (1, 1), lambda b, h, j, t: (b, 0), memory_space=pltpu.SMEM)
     in_specs = [
-        smem(), smem(),
-        pl.BlockSpec((1, 1, G_pad, D), lambda b, h, j, t: (b, h, 0, 0)),
-        pl.BlockSpec((1, bs, D), pool_idx),
-        pl.BlockSpec((1, bs, D), pool_idx),
-        pl.BlockSpec((1, bs), lambda b, h, j, t: (jnp.maximum(t[b, j], 0),
-                                                  0)),
+        pl.BlockSpec((1, 1, G_pad, D), lambda b, h, j, *_: (b, h, 0, 0)),
+        pl.BlockSpec((1, bs, D), lambda b, h, j, qp, pln, t:
+                     (blk(b, j, t), 0, h)),
+        pl.BlockSpec((1, bs, D), lambda b, h, j, qp, pln, t:
+                     (blk(b, j, t), 0, h)),
+        pl.BlockSpec((1, 1, bs), lambda b, h, j, qp, pln, t:
+                     (blk(b, j, t), 0, 0)),
     ]
-    args = [qp, plen, qg, kr, vr, kv_pos]
+    args = [qg, kr, vr, _slot_rows(kv_pos)]
     if quantized:
-        in_specs += [pl.BlockSpec((1, bs, 1), pool_idx),
-                     pl.BlockSpec((1, bs, 1), pool_idx)]
-        args += [k_scale.reshape(nb, bs, Hk), v_scale.reshape(nb, bs, Hk)]
+        scale_spec = pl.BlockSpec((1, 1, 1, bs), lambda b, h, j, qp, pln, t:
+                                  (blk(b, j, t), h, 0, 0))
+        in_specs += [scale_spec, scale_spec]
+        args += [_scale_rows(k_scale), _scale_rows(v_scale)]
 
     out_specs, out_shape, scratch = _partial_outputs(B, Hk, n_splits, G_pad,
                                                      D, bps)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=3,
         grid=(B, Hk, T),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -430,7 +458,7 @@ def _flash_decode_paged(q, k, v, kv_pos, block_tables, q_pos, *, k_scale,
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
-    )(tbl, *args)
+    )(_row_pos(q_pos, B), _row_pos(prefix_len, B), tbl, *args)
     return _finish(m, l, acc, G, q, return_partials)
 
 
@@ -440,17 +468,22 @@ def paged_block_copy(leaf, src, dst, *, interpret: bool = False):
     move when a lane diverges from a shared prefix block.
 
     Grid is (L,), with the (src, dst) pair as a scalar-prefetch operand:
-    each program DMAs exactly one flattened ``(1, 1, Z)`` tile out of the
-    source block (the index_map dereferences ``src``), and the result is
-    scattered back at ``dst`` — the pool itself is never gathered.  Works
-    for every leaf dtype (bf16/f32 KV, int8 codes, scale rows, int32
-    kv_pos), so the whole tile — validity included — moves verbatim.
+    each program DMAs exactly one block tile out of the source block (the
+    index_map dereferences ``src``), and the result is scattered back at
+    ``dst`` — the pool itself is never gathered.  The tile is the block
+    flattened to ``(Z // 128, 128)`` rows when it divides the 128-lane
+    width, else one ``(1, Z)`` row: either way its last two dims are the
+    array's own, the tiling the TPU lowering accepts.  Works for every leaf
+    dtype (bf16/f32 KV, int8 codes, scale rows, int32 kv_pos), so the whole
+    tile — validity included — moves verbatim.
     """
     L, nb = leaf.shape[0], leaf.shape[1]
     Z = 1
     for d in leaf.shape[2:]:
         Z *= d
-    flat = leaf.reshape(L, nb, Z)
+    W = 128 if Z % 128 == 0 else Z
+    R = Z // W
+    flat = leaf.reshape(L, nb, R, W)
     sd = jnp.stack([jnp.asarray(src, jnp.int32), jnp.asarray(dst, jnp.int32)])
 
     def body(sd_ref, x_ref, o_ref):
@@ -462,9 +495,10 @@ def paged_block_copy(leaf, src, dst, *, interpret: bool = False):
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(L,),
-            in_specs=[pl.BlockSpec((1, 1, Z), lambda l, sd: (l, sd[0], 0))],
-            out_specs=pl.BlockSpec((1, 1, Z), lambda l, sd: (l, 0, 0))),
-        out_shape=jax.ShapeDtypeStruct((L, 1, Z), flat.dtype),
+            in_specs=[pl.BlockSpec((1, 1, R, W),
+                                   lambda l, sd: (l, sd[0], 0, 0))],
+            out_specs=pl.BlockSpec((1, 1, R, W), lambda l, sd: (l, 0, 0, 0))),
+        out_shape=jax.ShapeDtypeStruct((L, 1, R, W), flat.dtype),
         interpret=interpret,
     )(sd, flat)
     return flat.at[:, dst].set(tile[:, 0]).reshape(leaf.shape)

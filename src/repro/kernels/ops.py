@@ -54,10 +54,14 @@ def flash_decode_enabled() -> bool:
     return os.environ.get("REPRO_FLASH_DECODE", "1") != "0"
 
 
-def decode_mode() -> str:
-    """Human-readable decode dispatch (launchers print this)."""
+def decode_mode(cache_len: int) -> str:
+    """Human-readable path ``flash_decode`` takes for a cache of
+    ``cache_len`` logical slots (launchers print this)."""
     if not flash_decode_enabled():
         return "naive-sdpa (REPRO_FLASH_DECODE=0)"
+    if _short_cache_xla(cache_len):
+        return (f"flash_decode (xla, {cache_len} slots < "
+                f"REPRO_FLASH_DECODE_MIN_S={_pallas_min_s()})")
     if on_tpu():
         return "flash_decode (pallas, compiled)"
     if use_kernels():
@@ -90,18 +94,23 @@ def _pallas_min_s() -> int:
     return int(os.environ.get("REPRO_FLASH_DECODE_MIN_S", "1024"))
 
 
+def _short_cache_xla(cache_len: int) -> bool:
+    """True when the compiled kernel is skipped for the XLA path: on TPU,
+    caches shorter than REPRO_FLASH_DECODE_MIN_S.  Forced-interpret mode
+    keeps the kernel so CI exercises it at test sizes."""
+    return on_tpu() and cache_len < _pallas_min_s()
+
+
 def flash_decode(q, k, v, kv_pos, q_pos, **kw):
     """One decode step over the ring or paged cache; see
-    ``repro.kernels.flash_decode`` for signature and semantics.  On TPU,
-    caches shorter than REPRO_FLASH_DECODE_MIN_S take the XLA path (kernel
-    launch not profitable); forced-interpret mode keeps the kernel so CI
-    exercises it at test sizes."""
+    ``repro.kernels.flash_decode`` for signature and semantics, and
+    ``decode_mode`` for which path a cache length takes."""
     with jax.named_scope("obs.flash_decode"):
         if use_kernels():
             tbl = kw.get("block_tables")
             s_logical = (tbl.shape[1] * k.shape[1] if tbl is not None
                          else k.shape[1])
-            if on_tpu() and s_logical < _pallas_min_s():
+            if _short_cache_xla(s_logical):
                 return _flash_decode_xla(q, k, v, kv_pos, q_pos, **kw)
             return _flash_decode(q, k, v, kv_pos, q_pos,
                                  interpret=not on_tpu(), **kw)

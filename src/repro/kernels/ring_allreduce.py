@@ -115,7 +115,8 @@ def _rows(x, qblock: int):
     return r, pad
 
 
-def _hop_pallas(acc, codes, scales, res, *, wire: str, qblock: int):
+def _hop_pallas(acc, codes, scales, res, *, wire: str, qblock: int,
+                interpret: bool = False):
     """Pallas launch of the fused hop over (rows, qblock) tiles."""
     R0 = acc.size // qblock
     acc_r, _ = _rows(acc, qblock)
@@ -124,7 +125,6 @@ def _hop_pallas(acc, codes, scales, res, *, wire: str, qblock: int):
     R = acc_r.shape[0]
     grid = (R // _BLOCK_ROWS,)
     row_spec = pl.BlockSpec((_BLOCK_ROWS, qblock), lambda i: (i, 0))
-    interpret = jax.default_backend() != "tpu"
     if wire == "int8":
         scale_spec = pl.BlockSpec((_BLOCK_ROWS, 1), lambda i: (i, 0))
         scales_r = scales.reshape(-1, 1)
@@ -195,7 +195,8 @@ def fused_hop(acc, codes, scales, res, *, wire: str, qblock: int):
         if wire == "int8":
             scales = jnp.zeros((acc.size // qblock,), jnp.float32)
     if _use_kernels():
-        return _hop_pallas(acc, codes, scales, res, wire=wire, qblock=qblock)
+        return _hop_pallas(acc, codes, scales, res, wire=wire, qblock=qblock,
+                           interpret=jax.default_backend() != "tpu")
     return _hop_jnp(acc, codes, scales, res, wire=wire, qblock=qblock)
 
 

@@ -1,5 +1,6 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"     # the mesh is emulated on host CPUs
 
 """Multi-pod dry-run: lower + compile every (architecture × input shape)
 on the production meshes, and extract the roofline raw terms.
@@ -11,8 +12,10 @@ on the production meshes, and extract the roofline raw terms.
 Results land in experiments/dryrun/<arch>__<shape>__<mesh>[__fed].json;
 benchmarks/roofline.py turns them into EXPERIMENTS.md §Roofline.
 
-NOTE: the XLA_FLAGS line above MUST run before any other import (jax locks
-the device count at first init); do not move it.
+NOTE: the XLA_FLAGS / JAX_PLATFORMS lines above MUST run before any other
+import (jax locks the device count and backend at first init); do not move
+them.  The dry-run never takes an accelerator: it compiles for 512 emulated
+CPU devices even on a machine that has a TPU.
 """
 
 import argparse
@@ -67,9 +70,6 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool,
 
         mem = compiled.memory_analysis()
         cost = compiled.cost_analysis()
-        # jax < 0.5 returns a one-element list of dicts
-        if isinstance(cost, (list, tuple)):
-            cost = cost[0] if cost else {}
         hlo = compiled.as_text()
         # scan-aware accounting (XLA cost_analysis counts while bodies once)
         parsed = hlo_analyze(hlo)

@@ -23,13 +23,10 @@ PRODUCTION_MESH_SHAPES = {
 
 
 def _make_mesh(shape, axes):
-    """jax.make_mesh with Auto axis types where the installed jax supports
-    them (>= 0.5); on older jax Auto is the only behavior anyway."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """jax.make_mesh with Auto axis types (sharding left to the partitioner
+    outside explicit shard_map regions)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -44,6 +44,7 @@ import numpy as np
 from repro import obs
 from repro.configs import ALL_ARCHS, get_config, get_smoke_config
 from repro.kernels import ops
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import make_serve_step
 from repro.models.registry import get_model, train_batch_shapes
 
@@ -229,8 +230,8 @@ def main() -> None:
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=64)
     ap.add_argument("--gen", type=int, default=32)
-    ap.add_argument("--smoke", action="store_true", default=True)
-    ap.add_argument("--full-config", dest="smoke", action="store_false")
+    ap.add_argument("--full-config", action="store_true",
+                    help="published widths instead of the smoke config")
     # engine mode
     ap.add_argument("--engine", action="store_true",
                     help="continuous-batching engine instead of one fixed "
@@ -316,8 +317,8 @@ def main() -> None:
         import os
         os.environ["REPRO_FLIGHT_OUT"] = args.flight_out
 
-    cfg = (get_smoke_config if args.smoke else get_config)(args.arch)
-    print(f"decode path: {ops.decode_mode()}")
+    enable_compile_cache()
+    cfg = (get_config if args.full_config else get_smoke_config)(args.arch)
     api = get_model(cfg)
     params = api.init(cfg, jax.random.PRNGKey(0))
 
@@ -331,6 +332,7 @@ def main() -> None:
                                rate=args.arrival_rate, seed=args.trace_seed)
         cache_len = args.cache_len or max(
             len(r["prompt"]) + r["max_new_tokens"] for r in trace)
+        print(f"decode path: {ops.decode_mode(cache_len)}")
         clock = None
         if args.virtual_clock:
             from repro.fault.clock import VirtualClock
@@ -349,6 +351,7 @@ def main() -> None:
                    journal=args.journal or None,
                    clock=clock, step_time_s=args.step_time_s)
     else:
+        print(f"decode path: {ops.decode_mode(args.prompt_len + args.gen)}")
         run_fixed_batch(cfg, params, api, batch=args.batch,
                         prompt_len=args.prompt_len, gen=args.gen)
 

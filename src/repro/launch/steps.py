@@ -1,13 +1,9 @@
 """Jit-able step functions lowered by the dry-run and used by launchers.
 
-  train_step   — full fine-tuning: value_and_grad + AdamW (on a mesh the
-                 update runs the ZeRO-1 scatter-update schedule: shard-local
-                 moment update + all-gather of the updated param shard only;
-                 REPRO_ZERO1_SCATTER=0 restores the gather form)
+  train_step   — full fine-tuning: value_and_grad + AdamW
   fed_train_step — the paper's step: LoRA-only grads, cluster-weighted psum
                  aggregation over the data (+pod) axes folded into the step
-                 (DESIGN.md §3: federation mapped onto mesh collectives);
-                 the adapter AdamW takes the same scatter-update schedule
+                 (DESIGN.md §3: federation mapped onto mesh collectives)
   prefill_step — full forward building the KV/SSM cache + last logits
   serve_step   — one-token decode against the cache, through the fused
                  flash-decode kernel path (repro.kernels.ops.flash_decode;
@@ -19,26 +15,12 @@ All are pure; cfg/api are closed over (static).
 
 from __future__ import annotations
 
-import functools
-from typing import Optional
-
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.core.lora import lora_mask
 from repro.models.registry import get_model
-from repro.optim.adamw import adamw_init, adamw_update, adamw_update_zero1
-
-
-def _mesh_update(params, grads, opt_state, step, *, lr):
-    """AdamW on the ZeRO-1 scatter-update schedule when a mesh is active
-    (slice to the moment shard, update, all-gather ONLY the updated param
-    shard — `repro.optim.adamw`); plain AdamW otherwise.  Bit-exact either
-    way; REPRO_ZERO1_SCATTER=0 restores the gather formulation."""
-    from repro.dist.sharding import current_mesh
-    return adamw_update_zero1(params, grads, opt_state, step,
-                              mesh=current_mesh(), lr=lr)
+from repro.optim.adamw import adamw_update
 
 
 def make_train_step(cfg: ModelConfig, *, lr: float = 1e-4, accum: int = 1):
@@ -91,7 +73,7 @@ def make_train_step(cfg: ModelConfig, *, lr: float = 1e-4, accum: int = 1):
             (loss, grads), _ = jax.lax.scan(micro, zero, micro_batches)
             loss = loss / accum
             grads = jax.tree.map(lambda g: g / accum, grads)
-        params, opt_state = _mesh_update(params, grads, opt_state, step + 1,
+        params, opt_state = adamw_update(params, grads, opt_state, step + 1,
                                          lr=lr)
         return params, opt_state, loss
 
@@ -117,7 +99,7 @@ def make_fed_train_step(cfg: ModelConfig, *, lr: float = 1e-3):
             return api.loss(merge_lora(params, ad), cfg, batch)
 
         loss, grads = jax.value_and_grad(loss_fn)(adapters)
-        adapters, opt_state = _mesh_update(adapters, grads, opt_state,
+        adapters, opt_state = adamw_update(adapters, grads, opt_state,
                                            step + 1, lr=lr)
         params = merge_lora(params, adapters)
         return params, opt_state, loss

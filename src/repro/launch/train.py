@@ -1,11 +1,14 @@
 """Production training launcher.
 
   PYTHONPATH=src python -m repro.launch.train --arch smollm-360m \
-      --steps 20 --batch 8 --seq 256 [--smoke] [--fed]
+      --steps 20 --batch 8 --seq 256 [--full-config] [--fed]
 
-On this CPU host it runs the reduced (smoke) configs by default; on a real
-TPU slice drop --smoke and point --mesh at the production topology (the
-same step functions the dry-run lowers are used verbatim).
+It runs the reduced (smoke) config by default; ``--full-config`` takes the
+published widths.  The step runs on a (data, model) mesh over whatever
+devices JAX finds (``--model-parallel`` sets the model axis): parameters
+are tensor-parallel over ``model``, the AdamW moments ZeRO-1-sharded over
+``data`` and each batch split over ``data`` — the partition rules of
+``repro.dist.sharding``, the same step functions the dry-run lowers.
 
 ``--trace-out PATH`` dumps the ``repro.obs`` timeline (per-step
 ``train.step`` spans via ``jax.profiler.StepTraceAnnotation``, loss gauge,
@@ -26,8 +29,11 @@ import numpy as np
 
 from repro import obs
 from repro.configs import ALL_ARCHS, get_config, get_smoke_config
-from repro.core.lora import FAMILY_TARGETS, attach_lora
+from repro.core.lora import FAMILY_TARGETS, attach_lora, lora_tree
 from repro.data.tokens import lm_batches, markov_tokens
+from repro.dist.sharding import (data_specs, opt_state_specs, param_specs,
+                                 to_shardings)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import make_fed_train_step, make_train_step
 from repro.models.registry import get_model, train_batch_shapes
@@ -48,6 +54,22 @@ def synth_batch(cfg, batch, seq, it):
     return out
 
 
+def place_state(params, opt, mesh, *, fed: bool):
+    """Put the parameters (tensor-parallel over ``model``) and the AdamW
+    moments (ZeRO-1 over ``data``; adapter-shaped for the fed step) on
+    ``mesh``.  Returns (params, opt, (param_shardings, opt_shardings))."""
+    psh = to_shardings(param_specs(params, mesh), mesh)
+    moments = lora_tree(params) if fed else params
+    o = to_shardings(opt_state_specs(moments, mesh), mesh)
+    osh = {"mu": o, "nu": o}
+    return jax.device_put(params, psh), jax.device_put(opt, osh), (psh, osh)
+
+
+def place_batch(batch, mesh):
+    """Split each batch leaf's leading dim over the data axes."""
+    return jax.device_put(batch, to_shardings(data_specs(batch, mesh), mesh))
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ALL_ARCHS, default="smollm-360m")
@@ -55,8 +77,8 @@ def main() -> None:
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=256)
     ap.add_argument("--lr", type=float, default=3e-4)
-    ap.add_argument("--smoke", action="store_true", default=True)
-    ap.add_argument("--full-config", dest="smoke", action="store_false")
+    ap.add_argument("--full-config", action="store_true",
+                    help="published widths instead of the smoke config")
     ap.add_argument("--fed", action="store_true",
                     help="LoRA-federated step (the paper's training mode)")
     ap.add_argument("--model-parallel", type=int, default=1)
@@ -68,7 +90,8 @@ def main() -> None:
                          "of the compiled train step")
     args = ap.parse_args()
 
-    cfg = (get_smoke_config if args.smoke else get_config)(args.arch)
+    enable_compile_cache()
+    cfg = (get_config if args.full_config else get_smoke_config)(args.arch)
     api = get_model(cfg)
     mesh = make_host_mesh(model=args.model_parallel)
     print(f"arch={cfg.name} devices={mesh.size} mesh={dict(mesh.shape)}")
@@ -80,18 +103,21 @@ def main() -> None:
         step_fn = make_fed_train_step(cfg, lr=args.lr)
     else:
         step_fn = make_train_step(cfg, lr=args.lr)
-    opt = adamw_init(params)
+    opt = adamw_init(lora_tree(params) if args.fed else params)
+    params, opt, (psh, osh) = place_state(params, opt, mesh, fed=args.fed)
     n_params = sum(x.size for x in jax.tree.leaves(params))
     print(f"params: {n_params/1e6:.1f}M")
 
     toks = markov_tokens(200_000, cfg.vocab_size, seed=0)
     it = lm_batches(toks, args.batch, args.seq + 1, seed=0)
 
-    jitted = jax.jit(step_fn, donate_argnums=(0, 1))
+    jitted = jax.jit(step_fn, donate_argnums=(0, 1),
+                     out_shardings=(psh, osh, None))
     with mesh:
         if args.scope_costs:
             # undonated lower: attribution only, params survive for the loop
-            batch = synth_batch(cfg, args.batch, args.seq, it)
+            batch = place_batch(synth_batch(cfg, args.batch, args.seq, it),
+                                mesh)
             compiled = jax.jit(step_fn).lower(
                 params, opt, batch, jnp.asarray(0, jnp.int32)).compile()
             costs = obs.devmem.compiled_scope_costs(compiled)
@@ -105,7 +131,8 @@ def main() -> None:
                           f"bytes={v['bytes']:.3e}")
         t0 = time.time()
         for i in range(args.steps):
-            batch = synth_batch(cfg, args.batch, args.seq, it)
+            batch = place_batch(synth_batch(cfg, args.batch, args.seq, it),
+                                mesh)
             with obs.step_span("train.step", i, batch=args.batch,
                                seq=args.seq):
                 params, opt, loss = jitted(params, opt, batch,

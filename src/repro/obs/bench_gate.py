@@ -252,8 +252,6 @@ GATES: Dict[str, List[GateSpec]] = {
         GateSpec({"case": "ring", "wire": "bf16"}, "bytes_vs_f32_psum",
                  "lower", rel_tol=0.0, bound=0.51),
         GateSpec({"row": "collectives_summary"}, "int8_under_027", "exact"),
-        GateSpec({"row": "collectives_summary"}, "zero1_scatter_smaller",
-                 "exact"),
     ],
 }
 

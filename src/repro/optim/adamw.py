@@ -7,25 +7,9 @@ Functional API:
 Supports masked updates (``mask`` pytree of bools) so the federated client
 can train LoRA leaves only while the quantized base stays frozen — the
 paper's PEFT setup (C2).
-
-ZeRO-1 scatter update (``adamw_update_zero1``): on a mesh whose data
-axes are live, ``repro.dist.sharding.opt_state_specs`` shards the f32
-moments over ``data`` (+``pod``).  The gather formulation (plain
-``adamw_update`` under jit) leaves the layout to XLA, which reshards the
-replicated grads onto the moment layout with a swarm of
-all-to-all/collective-permutes before the final param all-gather.  The
-scatter formulation makes the intended schedule explicit in ONE shard_map:
-slice params+grads to the local moment shard (free — both are replicated
-over the data axes there), update the shard, and all-gather ONLY the
-updated param shard.  Same arithmetic on the same f32 values — bit-exact
-against the gather form — with a strictly smaller collective term
-(``benchmarks/collectives`` measures both via the dry-run HLO cost model).
-``REPRO_ZERO1_SCATTER=0`` restores the gather formulation.
 """
 
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
@@ -37,15 +21,8 @@ def adamw_init(params):
             "nu": jax.tree.map(zeros, params)}
 
 
-def zero1_scatter_enabled() -> bool:
-    """Scatter-update is the default on a mesh; ``REPRO_ZERO1_SCATTER=0``
-    restores the gather formulation (A/B baseline)."""
-    return os.environ.get("REPRO_ZERO1_SCATTER", "1") != "0"
-
-
 def _leaf_update(p, g, mu, nu, c1, c2, *, lr, b1, b2, eps, weight_decay):
-    """One AdamW leaf update — shared by the gather and scatter paths so
-    the two formulations stay bit-identical."""
+    """One AdamW leaf update."""
     g32 = g.astype(jnp.float32)
     mu2 = b1 * mu + (1 - b1) * g32
     nu2 = b2 * nu + (1 - b2) * jnp.square(g32)
@@ -85,103 +62,6 @@ def adamw_update(params, grads, state, step, *, lr=1e-3, b1=0.9, b2=0.999,
     return (jax.tree.unflatten(tdef, out_p),
             {"mu": jax.tree.unflatten(tdef, out_mu),
              "nu": jax.tree.unflatten(tdef, out_nu)})
-
-
-def _widen_info(pspec, ospec):
-    """Per-leaf (dim, axis entry) where ``opt_state_specs`` widened the
-    param spec over the data axes, or None (moments replicated — nothing
-    to scatter)."""
-    from jax.sharding import PartitionSpec as P
-
-    def info(ps, os_):
-        pe = list(ps)
-        for d, e in enumerate(list(os_)):
-            if e is not None and (d >= len(pe) or pe[d] is None):
-                return (d, e)
-        return None
-
-    return jax.tree.map(info, pspec, ospec,
-                        is_leaf=lambda x: isinstance(x, P))
-
-
-def adamw_update_zero1(params, grads, state, step, *, mesh, lr=1e-3, b1=0.9,
-                       b2=0.999, eps=1e-8, weight_decay=0.0, mask=None):
-    """AdamW with the ZeRO-1 scatter-update schedule (see module
-    docstring).  Falls back to ``adamw_update`` when the mesh has no live
-    data axes, when disabled, or for fully-replicated moment leaves inside
-    the shard_map body.  Bit-exact against the gather formulation."""
-    from repro.dist.sharding import (_axis_candidates, _mesh_shape,
-                                     opt_state_specs, param_specs)
-    if mesh is None or not zero1_scatter_enabled():
-        return adamw_update(params, grads, state, step, lr=lr, b1=b1, b2=b2,
-                            eps=eps, weight_decay=weight_decay, mask=mask)
-    shape = _mesh_shape(mesh)
-    if not _axis_candidates(shape):
-        return adamw_update(params, grads, state, step, lr=lr, b1=b1, b2=b2,
-                            eps=eps, weight_decay=weight_decay, mask=mask)
-
-    from jax.experimental.shard_map import shard_map
-
-    pspec = param_specs(params, mesh)
-    ospec = opt_state_specs(params, mesh)
-    winfo = jax.tree.leaves(_widen_info(pspec, ospec),
-                            is_leaf=lambda x: x is None or
-                            isinstance(x, tuple))
-    if mask is None:
-        mask = jax.tree.map(lambda _: True, params)
-    flat_mask = jax.tree.leaves(mask)
-    step_c = jnp.asarray(step, jnp.float32)
-
-    def body(p, g, mu, nu, c1, c2):
-        fp, tdef = jax.tree.flatten(p)
-        fg = jax.tree.leaves(g)
-        fmu = jax.tree.leaves(mu)
-        fnu = jax.tree.leaves(nu)
-        out_p, out_mu, out_nu = [], [], []
-        for pl, gl, mul, nul, wi, m in zip(fp, fg, fmu, fnu, winfo,
-                                           flat_mask):
-            if m is False:
-                out_p.append(pl)
-                out_mu.append(mul)
-                out_nu.append(nul)
-                continue
-            if wi is None:
-                p2, mu2, nu2 = _leaf_update(pl, gl, mul, nul, c1, c2, lr=lr,
-                                            b1=b1, b2=b2, eps=eps,
-                                            weight_decay=weight_decay)
-            else:
-                d, entry = wi
-                axes = (entry,) if isinstance(entry, str) else tuple(entry)
-                idx = jnp.int32(0)
-                for ax in axes:
-                    idx = idx * shape[ax] + jax.lax.axis_index(ax)
-                nshard = mul.shape[d]
-                # grads enter on the MOMENT spec (already shard-shaped:
-                # replicated grads reshard by a free local slice, and an
-                # accum carry pinned to the ZeRO layout passes through
-                # untouched); only the replicated param needs slicing here
-                ps = jax.lax.dynamic_slice_in_dim(pl, idx * nshard, nshard, d)
-                p2, mu2, nu2 = _leaf_update(ps, gl, mul, nul, c1, c2, lr=lr,
-                                            b1=b1, b2=b2, eps=eps,
-                                            weight_decay=weight_decay)
-                # the ONLY collective of the update: gather the updated
-                # param shard (param dtype) — moments stay put
-                p2 = jax.lax.all_gather(p2, axes, axis=d, tiled=True)
-            out_p.append(p2)
-            out_mu.append(mu2)
-            out_nu.append(nu2)
-        return (jax.tree.unflatten(tdef, out_p),
-                jax.tree.unflatten(tdef, out_mu),
-                jax.tree.unflatten(tdef, out_nu))
-
-    from jax.sharding import PartitionSpec as P
-    p2, mu2, nu2 = shard_map(
-        body, mesh=mesh,
-        in_specs=(pspec, ospec, ospec, ospec, P(), P()),
-        out_specs=(pspec, ospec, ospec),
-        check_rep=False)(params, grads, state["mu"], state["nu"],
-                         1.0 - b1 ** step_c, 1.0 - b2 ** step_c)
-    return p2, {"mu": mu2, "nu": nu2}
 
 
 def sgd_update(params, grads, *, lr=1e-2):

@@ -955,16 +955,8 @@ class ForecastEngine:
 
     # -- decode / retire -----------------------------------------------------
 
-    def _decode(self) -> None:
-        active = [i for i, s in enumerate(self.slots)
-                  if s is not None and self._pos[i] >= 0]
-        if not active:
-            return
-        # chaos NaN injector: the poison row is ALWAYS in the batch (all
-        # False when disarmed) so arming it never changes the signature
-        for i, s in enumerate(self.slots):
-            self._poison_row[i] = (bool(self._poison) and s is not None
-                                   and s.request.id in self._poison)
+    def decode_batch(self) -> dict:
+        """The serve step's per-lane batch as the lanes stand now."""
         batch = {
             "token": jnp.asarray(self._tok),
             "pos": jnp.asarray(self._pos),
@@ -978,6 +970,19 @@ class ForecastEngine:
         if self.paged:
             batch["block_tbl"] = jnp.asarray(self.pool.table)
             batch["ring_len"] = jnp.asarray(self.pool.ring_len, jnp.int32)
+        return batch
+
+    def _decode(self) -> None:
+        active = [i for i, s in enumerate(self.slots)
+                  if s is not None and self._pos[i] >= 0]
+        if not active:
+            return
+        # chaos NaN injector: the poison row is ALWAYS in the batch (all
+        # False when disarmed) so arming it never changes the signature
+        for i, s in enumerate(self.slots):
+            self._poison_row[i] = (bool(self._poison) and s is not None
+                                   and s.request.id in self._poison)
+        batch = self.decode_batch()
         t0 = time.perf_counter()
         with obs.span("engine.decode_step", device=True,
                       step=self.step_count, active=len(active)):

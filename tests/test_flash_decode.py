@@ -129,6 +129,21 @@ def test_ops_dispatch_forced_interpret(monkeypatch):
                                rtol=3e-2, atol=3e-2)
 
 
+def test_decode_mode_names_the_path_per_cache_len(monkeypatch):
+    """decode_mode says which path a given cache length takes: on TPU a
+    cache below REPRO_FLASH_DECODE_MIN_S goes to XLA, a longer one to the
+    compiled kernel."""
+    monkeypatch.delenv("REPRO_FLASH_DECODE_MIN_S", raising=False)
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    assert ops.decode_mode(512).startswith("flash_decode (xla, 512 slots")
+    assert ops.decode_mode(2048) == "flash_decode (pallas, compiled)"
+    monkeypatch.setattr(ops, "on_tpu", lambda: False)
+    monkeypatch.setenv("REPRO_FORCE_KERNELS", "1")
+    assert ops.decode_mode(512) == "flash_decode (pallas, interpret)"
+    monkeypatch.delenv("REPRO_FORCE_KERNELS")
+    assert ops.decode_mode(512) == "flash_decode (xla blockwise fallback)"
+
+
 def test_partials_combine_matches_full():
     """Two half-cache partials merged with the pmax/psum formula must equal
     the unsharded kernel — the math repro.dist.decode runs over ``model``."""

@@ -1,6 +1,6 @@
 """Federated communication fast path (repro.kernels.ring_allreduce +
-repro.dist.fedcomm): psum parity, wire formats, error feedback, the
-three-way byte agreement, and the ZeRO-1 scatter-update AdamW.
+repro.dist.fedcomm): psum parity, wire formats, error feedback, and the
+three-way byte agreement.
 
 Multi-device cases run in subprocesses (like test_paged_pool) because the
 emulated device count must be set before jax initializes; the scripts
@@ -21,7 +21,7 @@ from repro.core import comm
 from repro.dist import fed, fedcomm
 
 _ENV_KEYS = ("REPRO_FED_WIRE", "REPRO_FED_QBLOCK", "REPRO_FED_RING",
-             "REPRO_ZERO1_SCATTER", "REPRO_CACHE_SHARD")
+             "REPRO_CACHE_SHARD")
 
 
 def _run_sub(script: str, timeout: int = 900, **env_extra):
@@ -297,82 +297,3 @@ def test_fed_trainer_int8_wire_runs():
     res8 = federated_fit(cfg, cdata, rounds=1, batch_size=4, wire="int8")
     assert all(np.isfinite(l.train_loss) for l in res8.logs)
     assert res8.total_megabytes() < 0.27 * res32.total_megabytes()
-
-
-# ---------------------------------------------------------------------------
-# ZeRO-1 scatter-update AdamW
-# ---------------------------------------------------------------------------
-
-_ZERO1 = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import jax, jax.numpy as jnp, numpy as np
-from repro.configs import get_smoke_config
-from repro.launch.hlo_cost import analyze
-from repro.models.registry import get_model
-from repro.dist.sharding import param_specs, opt_state_specs, to_shardings
-from repro.optim.adamw import adamw_init, adamw_update, adamw_update_zero1
-
-cfg = get_smoke_config("qwen3-0.6b")
-api = get_model(cfg)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
-key = jax.random.PRNGKey(0)
-params = api.init(cfg, key)
-grads = jax.tree.map(
-    lambda p: jax.random.normal(jax.random.fold_in(key, p.size % 9973),
-                                p.shape, jnp.float32) * 0.01, params)
-opt = adamw_init(params)
-psh = to_shardings(param_specs(params, mesh), mesh)
-osh = to_shardings(opt_state_specs(params, mesh), mesh)
-
-with mesh:
-    # scatter-update == gather-update, bit-exact (same f32 arithmetic on
-    # the same shards)
-    pg, sg = adamw_update(params, grads, opt, 3, lr=1e-3, weight_decay=0.01)
-    ps, ss = adamw_update_zero1(params, grads, opt, 3, mesh=mesh, lr=1e-3,
-                                weight_decay=0.01)
-    for a, b in ((pg, ps), (sg["mu"], ss["mu"]), (sg["nu"], ss["nu"])):
-        jax.tree.map(lambda x, y: np.testing.assert_array_equal(
-            np.asarray(x), np.asarray(y)), a, b)
-
-    # the dryrun cost model: the scatter formulation's collective term is
-    # strictly smaller (no all-to-all / collective-permute resharding of
-    # the replicated grads onto the moment layout)
-    totals = {}
-    for name, fn in (("gather", lambda p, g, s: adamw_update(p, g, s, 3)),
-                     ("scatter", lambda p, g, s: adamw_update_zero1(
-                         p, g, s, 3, mesh=mesh))):
-        jitted = jax.jit(fn, in_shardings=(psh, psh, {"mu": osh, "nu": osh}),
-                         out_shardings=(psh, {"mu": osh, "nu": osh}))
-        parsed = analyze(jitted.lower(params, grads, opt).compile().as_text())
-        totals[name] = parsed["collective_total_bytes"]
-print("totals", totals)
-assert totals["scatter"] < totals["gather"], totals
-print("ZERO1_OK")
-"""
-
-
-def test_zero1_scatter_parity_and_collective_term():
-    """ZeRO-1 scatter-update == gather-update param/moment parity
-    (bit-exact), and a strictly smaller compiled collective term, on an
-    emulated (data=4, model=2) mesh."""
-    out = _run_sub(_ZERO1)
-    assert "ZERO1_OK" in out
-
-
-def test_zero1_no_mesh_falls_back():
-    from repro.optim.adamw import (adamw_init, adamw_update,
-                                   adamw_update_zero1)
-    p = {"w": jnp.arange(8, dtype=jnp.float32)}
-    g = {"w": jnp.ones(8, jnp.float32)}
-    st = adamw_init(p)
-    a, _ = adamw_update(p, g, st, 1)
-    b, _ = adamw_update_zero1(p, g, st, 1, mesh=None)
-    np.testing.assert_array_equal(np.asarray(a["w"]), np.asarray(b["w"]))
-
-
-def test_zero1_env_escape_hatch(monkeypatch):
-    from repro.optim.adamw import zero1_scatter_enabled
-    assert zero1_scatter_enabled()
-    monkeypatch.setenv("REPRO_ZERO1_SCATTER", "0")
-    assert not zero1_scatter_enabled()

@@ -182,7 +182,7 @@ def ring_aggregate(member_adapters, weights, mesh, *, wire: str = None,
                 _AGG_CACHE.pop(next(iter(_AGG_CACHE)))
             _AGG_CACHE[key] = (agg, ledger)
 
-    with obs.span("fedcomm.ring_aggregate", device=True, wire=wire,
+    with obs.span("fedcomm.ring_aggregate", wire=wire,
                   axes=",".join(axes)):
         out, st_out = agg(member_adapters, weights, st_in)
     _trace_ring_round(ledger, wire)

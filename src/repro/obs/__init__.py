@@ -11,8 +11,8 @@ instrumented code::
 
     from repro import obs
 
-    with obs.span("engine.decode_step", device=True, step=i):
-        ...
+    with obs.span("engine.decode_step", step=i, active=n):
+        ...                       # under jax.profiler: an annotation too
     obs.counter("ring.wire_bytes.data", nbytes)
     obs.hist("fed.fit_wall_s", dt, sketch=True)   # mergeable percentiles
     obs.dump("trace.json")        # -> chrome://tracing / Perfetto UI

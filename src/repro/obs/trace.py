@@ -15,11 +15,13 @@ report flows through one process-global :class:`Tracer`:
     keep a bounded reservoir so p50/p95/p99 stay O(1) memory over
     million-token runs; below the reservoir capacity the percentiles are
     EXACT (same linear interpolation as ``numpy.percentile``).
-  * **Device alignment** — ``span(..., device=True)`` additionally enters a
-    ``jax.profiler.TraceAnnotation`` and ``step_span`` a
-    ``StepTraceAnnotation``, so when a JAX profiler trace is captured the
-    host spans line up with the XLA device timeline.  jax is imported
-    lazily and optionally: this module itself is dependency-free.
+  * **Device alignment** — while a JAX profiler trace is being captured,
+    every live ``span`` also enters a ``jax.profiler.TraceAnnotation`` (and
+    ``step_span`` a ``StepTraceAnnotation``) carrying the span's scalar
+    args, so the host spans and their args (xplane stats) land on the XLA
+    device timeline's clock.  Retroactive ``add_span`` intervals stay off
+    the profiler.  jax is imported lazily and optionally: this module
+    itself is dependency-free.
 
 ``REPRO_TRACE=0`` turns every entry point into a no-op (one dict lookup +
 an early return — sub-microsecond, measured by ``tests/test_obs.py``), so
@@ -47,6 +49,7 @@ calling thread's track.
 from __future__ import annotations
 
 import atexit
+import functools
 import json
 import os
 import random
@@ -70,6 +73,7 @@ def trace_enabled() -> bool:
     return os.environ.get("REPRO_TRACE", "1") != "0"
 
 
+@functools.lru_cache(maxsize=1)
 def _jax_profiler():
     """Optional jax.profiler handle — None when jax is unavailable, so the
     tracer itself stays zero-dependency."""
@@ -78,6 +82,24 @@ def _jax_profiler():
         return profiler
     except Exception:                           # pragma: no cover
         return None
+
+
+def _annotation(name: str, args: Dict[str, Any], step: Optional[int] = None):
+    """An entered profiler annotation for a live span, carrying its scalar
+    args as xplane stats — or None when no profiler trace is being
+    captured (an annotation then records nothing)."""
+    prof = _jax_profiler()
+    if prof is None or not prof.TraceAnnotation.is_enabled():
+        return None
+    stats = _scalars(args)
+    ann = (prof.TraceAnnotation(name, **stats) if step is None
+           else prof.StepTraceAnnotation(name, step_num=step, **stats))
+    ann.__enter__()
+    return ann
+
+
+def _scalars(args: Dict[str, Any]) -> Dict[str, Any]:
+    return {k: v for k, v in args.items() if isinstance(v, (int, float, str))}
 
 
 # ---------------------------------------------------------------------------
@@ -164,6 +186,9 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **args) -> None:
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
@@ -193,29 +218,24 @@ class _FlightSpan:
                                     self.args)
         return False
 
+    def set(self, **args) -> None:
+        self.args.update(args)
+
 
 class _Span:
-    __slots__ = ("_tr", "name", "cat", "args", "device", "track", "t0",
-                 "_ann")
+    __slots__ = ("_tr", "name", "cat", "args", "track", "t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
-                 device: bool, track: Optional[str],
-                 args: Dict[str, Any]):
+                 track: Optional[str], args: Dict[str, Any]):
         self._tr = tracer
         self.name = name
         self.cat = cat
         self.args = args
-        self.device = device
         self.track = track
-        self._ann = None
 
     def __enter__(self):
         self.t0 = time.perf_counter()
-        if self.device:
-            prof = _jax_profiler()
-            if prof is not None:
-                self._ann = prof.TraceAnnotation(self.name)
-                self._ann.__enter__()
+        self._ann = _annotation(self.name, self.args)
         return self
 
     def __exit__(self, *exc):
@@ -225,6 +245,13 @@ class _Span:
                            time.perf_counter(), self.track, self.args)
         return False
 
+    def set(self, **args) -> None:
+        """Args known only once the span is open (an admission's prefix
+        hit): recorded with the span, and on the profiler's event."""
+        self.args.update(args)
+        if self._ann is not None:
+            self._ann.set_metadata(**_scalars(args))
+
 
 class _StepSpan(_Span):
     """Span + ``jax.profiler.StepTraceAnnotation`` — marks one training /
@@ -232,16 +259,12 @@ class _StepSpan(_Span):
     __slots__ = ("step",)
 
     def __init__(self, tracer, name, step: int, args):
-        super().__init__(tracer, name, "step", False, None, args)
+        super().__init__(tracer, name, "step", None, args)
         self.step = step
 
     def __enter__(self):
         self.t0 = time.perf_counter()
-        prof = _jax_profiler()
-        if prof is not None:
-            self._ann = prof.StepTraceAnnotation(self.name,
-                                                 step_num=self.step)
-            self._ann.__enter__()
+        self._ann = _annotation(self.name, self.args, step=self.step)
         return self
 
 
@@ -264,7 +287,9 @@ class Tracer:
             self._epoch = time.perf_counter()
             self._events: List[dict] = []
             self._tracks: Dict[str, int] = {}   # virtual track name -> tid
-            self._thread_tids: Dict[int, int] = {}
+            # per-thread tids live in a thread-local, not a dict keyed by
+            # threading.get_ident(): Python reuses an exited thread's ident
+            self._thread_tid = threading.local()
             self._next_tid = 1
             self.dropped_events = 0
             self.counters: Dict[str, float] = {}
@@ -288,11 +313,10 @@ class Tracer:
                 self._push({"name": "thread_name", "ph": "M", "pid": 0,
                             "tid": tid, "args": {"name": track}})
             return tid
-        ident = threading.get_ident()
-        tid = self._thread_tids.get(ident)
+        tid = getattr(self._thread_tid, "tid", None)
         if tid is None:
             tid = self._next_tid = self._next_tid + 1
-            self._thread_tids[ident] = tid
+            self._thread_tid.tid = tid
             name = threading.current_thread().name
             self._push({"name": "thread_name", "ph": "M", "pid": 0,
                         "tid": tid, "args": {"name": name}})
@@ -321,17 +345,18 @@ class Tracer:
 
     # -- spans / events ------------------------------------------------------
 
-    def span(self, name: str, cat: str = "", device: bool = False,
-             track: Optional[str] = None, **args):
-        """Context manager timing a live region.  ``device=True`` also
-        enters a ``jax.profiler.TraceAnnotation`` so the host span lines up
-        with the XLA device trace under the JAX profiler; ``track`` pins
-        the span to a named virtual track instead of the calling thread."""
+    def span(self, name: str, cat: str = "", track: Optional[str] = None,
+             **args):
+        """Context manager timing a live region; under the JAX profiler it
+        is also a ``jax.profiler.TraceAnnotation`` with the scalar
+        ``args`` as stats, so the host span lines up with the XLA device
+        trace.  ``track`` pins the span to a named virtual track instead of
+        the calling thread.  ``set(**args)`` adds args to an open span."""
         if not trace_enabled():
             if _flight.flight_enabled():
                 return _FlightSpan(name, cat, track, args)
             return _NULL_SPAN
-        return _Span(self, name, cat, device, track, args)
+        return _Span(self, name, cat, track, args)
 
     def step_span(self, name: str, step: int, **args):
         """``span`` + ``jax.profiler.StepTraceAnnotation(step_num=step)``."""
@@ -484,8 +509,8 @@ def get_tracer() -> Tracer:
     return _TRACER
 
 
-def span(name: str, cat: str = "", device: bool = False, **args):
-    return _TRACER.span(name, cat, device=device, **args)
+def span(name: str, cat: str = "", **args):
+    return _TRACER.span(name, cat, **args)
 
 
 def step_span(name: str, step: int, **args):

@@ -100,13 +100,21 @@ virtual clock, default 0.05), ``REPRO_SERVE_JOURNAL`` (journal path).
 
 Observability (``repro.obs``, ``REPRO_TRACE=0`` disables): every request
 gets its own Perfetto track carrying the lifecycle
-``req.submit -> req.queued -> req.prefill -> req.first_token ->
+``req.submit -> req.queued -> engine.admit -> req.first_token ->
 req.decode -> req.lifecycle -> req.retire`` (park/evict as instant
 events, plus ``pool.share_hit`` / ``pool.cow_copy`` / ``pool.swap_out`` /
 ``pool.swap_in`` instants with byte counts whenever sharing or the swap
-tier fire); each engine tick emits an ``engine.decode_step`` span (wrapped in
-``jax.profiler.TraceAnnotation`` so host and XLA device traces line up)
-plus a ``pool`` counter track (blocks in use / active lanes).  Exactly one
+tier fire).  ``engine.admit`` (args ``queued_ms`` — since the request last
+entered the queue —, ``prompt_len``, ``prefilled``, ``shared_tokens``,
+``resumed``) lasts until the admission's last host sync, with a child span
+per sync point: ``engine.admit.prefill``, ``.first_token``, ``.index``
+(or ``.swap_in``).  Each tick is an ``engine.tick`` span holding its
+phases: ``engine.slo_sweep``, ``engine.schedule``, the admissions,
+``engine.grant``, ``engine.batch``, ``engine.decode_step`` (``active`` of
+``lanes``), ``engine.emit`` and ``engine.swap_drain``; under the JAX
+profiler every one is also an annotation with its args, so host phases and
+XLA device work share one timeline.  A ``pool`` counter track samples
+blocks in use and active lanes each decode.  Exactly one
 ``req.lifecycle`` span is emitted per FINISHED request — eviction and
 recompute re-emit the per-residency phases, never the lifecycle — so a
 trace's lifecycle-span count always equals ``requests_finished``.
@@ -219,6 +227,9 @@ class ForecastEngine:
         self.finished: Dict[str, FinishedRequest] = {}
         self.slots: List[Optional[GenState]] = [None] * num_slots
         self._submit_time: Dict[str, float] = {}
+        # when each queued request last entered the queue: its submit, or
+        # its requeue after a park, eviction or swap-out
+        self._queued_at: Dict[str, float] = {}
 
         # -- fault tolerance (SLOs / shedding / quarantine / journal) ----
         def _env_f(name):
@@ -352,6 +363,7 @@ class ForecastEngine:
                 self.journal.log_submit(request)
             self.metrics.record_submit()
         self._submit_time[request.id] = time.perf_counter()
+        self._queued_at[request.id] = self._submit_time[request.id]
         # SLO anchor: resumes (journal replay, evict requeue) keep the
         # original window; a fresh submit — including a shed request's
         # retry — starts one
@@ -409,6 +421,7 @@ class ForecastEngine:
         self.metrics.record_shed()
         self.shed_log[req.id] = retry
         self._slo_submit.pop(req.id, None)
+        self._queued_at.pop(req.id, None)
         obs.instant("serve.shed", track=f"req:{req.id}", id=req.id,
                     queued=queued, retry_after_s=retry,
                     queue_depth=self.scheduler.pending,
@@ -519,6 +532,7 @@ class ForecastEngine:
             self.swap.pop(res["swap"])
         gen = [int(t) for t in res.get("generated", [])]
         t0 = self._slo_submit.pop(req.id, None)
+        self._queued_at.pop(req.id, None)
         self.metrics.record_deadline_miss(ttft=kind == "ttft_slo")
         first = res.get("first_token_time") or 0.0
         submit_t = (res.get("submitted")
@@ -559,39 +573,46 @@ class ForecastEngine:
         batched decode.  Under a virtual clock the tick ends by advancing
         ``step_time_s`` virtual seconds; the journal (if any) commits its
         buffered token records at the same boundary."""
-        self._slo_sweep()
-        free_blocks = self.pool.free_blocks if self.paged else -1
-        blocks_needed = self._admit_blocks if self.paged else None
-        for req in self.scheduler.admit(
-                now_step=self.step_count,
-                free_slots=self.pool.free_slots,
-                tokens_in_flight=self.tokens_in_flight,
-                free_blocks=free_blocks,
-                blocks_needed=blocks_needed):
-            try:
-                self._admit(req)
-            except RuntimeError:
-                # share-aware pricing raced a chain invalidation (or the
-                # pool shrank between pricing and grant): the admission was
-                # rolled back — put the request back at the head and stop
-                # admitting this tick
-                self.scheduler.requeue_front([req])
-                break
-        if self.paged:
-            self._grant_pass()
-        self._decode()
-        self.step_count += 1
-        if self.journal is not None:
-            self.journal.commit()
-        if self.clock is not None:
-            self.clock.advance(self.step_time_s)
-        # drain swap-outs to host np arrays AFTER the decode dispatched —
-        # the device gather overlaps the step instead of blocking it
-        while self._swap_pending:
-            handle = self.swap.get(self._swap_pending.pop())
-            if handle is not None and not handle.get("host"):
-                handle["cache"] = jax.tree.map(np.asarray, handle["cache"])
-                handle["host"] = True
+        with obs.step_span("engine.tick", self.step_count):
+            with obs.span("engine.slo_sweep"):
+                self._slo_sweep()
+            with obs.span("engine.schedule"):
+                admits = self.scheduler.admit(
+                    now_step=self.step_count,
+                    free_slots=self.pool.free_slots,
+                    tokens_in_flight=self.tokens_in_flight,
+                    free_blocks=self.pool.free_blocks if self.paged else -1,
+                    blocks_needed=self._admit_blocks if self.paged else None)
+            for req in admits:
+                try:
+                    self._admit(req)
+                except RuntimeError:
+                    # share-aware pricing raced a chain invalidation (or
+                    # the pool shrank between pricing and grant): the
+                    # admission was rolled back — put the request back at
+                    # the head and stop admitting this tick
+                    self.scheduler.requeue_front([req])
+                    break
+                self._queued_at.pop(req.id, None)
+            if self.paged:
+                with obs.span("engine.grant"):
+                    self._grant_pass()
+            self._decode()
+            self.step_count += 1
+            if self.journal is not None:
+                self.journal.commit()
+            if self.clock is not None:
+                self.clock.advance(self.step_time_s)
+            # drain swap-outs to host np arrays AFTER the decode dispatched —
+            # the device gather overlaps the step instead of blocking it
+            if self._swap_pending:
+                with obs.span("engine.swap_drain"):
+                    while self._swap_pending:
+                        handle = self.swap.get(self._swap_pending.pop())
+                        if handle is not None and not handle.get("host"):
+                            handle["cache"] = jax.tree.map(np.asarray,
+                                                           handle["cache"])
+                            handle["host"] = True
 
     def run(self, max_steps: int = 0) -> Dict[str, FinishedRequest]:
         """Drive steps until every submitted request retires."""
@@ -634,103 +655,118 @@ class ForecastEngine:
         track = f"req:{req.id}"
         t_admit = time.perf_counter()
         res = req.resume or {}
-        obs.add_span("req.queued",
-                     res.get("submitted")
-                     or self._submit_time.get(req.id, t_admit), t_admit,
-                     track=track, id=req.id)
-        slot = self.pool.acquire()
-        if self.swap_tier and res.get("swap") in self.swap:
-            handle = self.swap.pop(res["swap"])
-            try:
-                self._swap_in(req, slot, handle)
-            except RuntimeError:               # pool raced below the price
-                self.swap[res["swap"]] = handle
+        queued_at = self._queued_at.get(req.id, t_admit)
+        obs.add_span("req.queued", queued_at, t_admit, track=track,
+                     id=req.id)
+        # the span ends at the admission's last host sync (the prefix
+        # index's logits pull, else the first token), before the token is
+        # handed out
+        with obs.span("engine.admit", track=track, id=req.id,
+                      queued_ms=1e3 * (t_admit - queued_at),
+                      prompt_len=req.prompt_len,
+                      resumed=req.resume is not None) as admit_span:
+            slot = self.pool.acquire()
+            if self.swap_tier and res.get("swap") in self.swap:
+                handle = self.swap.pop(res["swap"])
+                admit_span.set(prefilled=0, shared_tokens=0)
+                try:
+                    self._swap_in(req, slot, handle)
+                except RuntimeError:           # pool raced below the price
+                    self.swap[res["swap"]] = handle
+                    self.pool.release(slot)
+                    raise
+                return
+            P = req.prompt_len
+            Pb = self._bucketed_len(req)
+            shared: List[int] = []
+            full_hit, chain_logits = False, None
+            if self.paged:
+                if self.share_prefixes:
+                    shared, full_hit, chain_logits = \
+                        self.pool.match_prefix(req.prompt)
+                try:
+                    self.pool.share_map(slot, shared)
+                    if not full_hit:
+                        self.pool.grant_tail(
+                            slot, len(shared),
+                            self.pool.blocks_for(Pb) - len(shared))
+                except RuntimeError:           # pool raced below the price
+                    self.pool.release(slot)    # decrefs any shared mapping
+                    raise
+                if shared:
+                    self.metrics.record_share(len(shared), full_hit)
+                    obs.instant("pool.share_hit", track=track, id=req.id,
+                                slot=slot, blocks=len(shared),
+                                full_prompt=bool(full_hit),
+                                bytes=len(shared) * self.pool.block_bytes)
+            zero_prefill = full_hit and chain_logits is not None
+            admit_span.set(
+                prefilled=0 if zero_prefill else P,
+                shared_tokens=min(len(shared) * self.pool.block_size, P)
+                if shared else 0)
+
+            if zero_prefill:
+                # whole prompt lives in the pool already: zero prefill,
+                # zero new blocks — the chain's stored last-token logits
+                # row seeds the first sample exactly as a fresh prefill's
+                # would
+                logits = jnp.asarray(chain_logits)[None, None]
+                self.metrics.record_admit(0)
+            else:
+                toks = np.zeros((1, Pb), np.int32)
+                toks[0, :P] = req.prompt
+                # true_len rides along whenever bucketing is on (one
+                # bucketed prefill signature even for exact-fit prompts); a
+                # resume that skipped bucketing prefills at its exact length
+                true_len = (jnp.asarray([P], jnp.int32)
+                            if self.prefill_bucket
+                            and (Pb != P or not req.resume) else None)
+                with obs.span("engine.admit.prefill", track=track,
+                              padded_len=Pb, slot=slot):
+                    cache1, logits = self._prefill_fn(self.params,
+                                                      jnp.asarray(toks),
+                                                      true_len)
+                    if self.paged:
+                        # shared prefix blocks are read-only — the donor's
+                        # data is bit-identical, so mask them out of the
+                        # scatter
+                        self.pool.insert(cache1, slot,
+                                         skip_blocks=len(shared))
+                    else:
+                        self.pool.insert(cache1, slot)
+                self.metrics.record_admit(P)
+
+            prior: List[int] = list(res.get("generated", []))
+            sp = req.sampling
+            # sample counter continues across eviction/recompute: token i of
+            # the ORIGINAL request is always drawn from fold_in(key, i)
+            with obs.span("engine.admit.first_token", track=track):
+                base_key = np.asarray(jax.random.PRNGKey(sp.seed), np.uint32)
+                tok0, ok0 = self._first_fn(
+                    logits, jnp.asarray(base_key),
+                    jnp.asarray(sp.temperature, jnp.float32),
+                    jnp.asarray(sp.top_k, jnp.int32),
+                    jnp.asarray(sp.top_p, jnp.float32),
+                    jnp.asarray(len(prior), jnp.int32))
+                finite, tok0 = bool(ok0), int(tok0)
+            if not finite:
+                # prefill already went non-finite: quarantine at admission,
+                # BEFORE the prompt could be indexed as a prefix donor (a
+                # poisoned chain would hand NaN logits to every sharer)
+                self.quarantined[req.id] = QuarantinedRequest(
+                    req.id, "nonfinite_logits", self.step_count,
+                    int(res.get("prompt_len", req.prompt_len)), len(prior))
+                self.metrics.record_quarantine("nonfinite_logits")
                 self.pool.release(slot)
-                raise
-            return
-        P = req.prompt_len
-        Pb = self._bucketed_len(req)
-        shared: List[int] = []
-        full_hit, chain_logits = False, None
-        if self.paged:
-            if self.share_prefixes:
-                shared, full_hit, chain_logits = \
-                    self.pool.match_prefix(req.prompt)
-            try:
-                self.pool.share_map(slot, shared)
-                if not full_hit:
-                    self.pool.grant_tail(
-                        slot, len(shared),
-                        self.pool.blocks_for(Pb) - len(shared))
-            except RuntimeError:               # pool raced below the price
-                self.pool.release(slot)        # decrefs any shared mapping
-                raise
-            if shared:
-                self.metrics.record_share(len(shared), full_hit)
-                obs.instant("pool.share_hit", track=track, id=req.id,
-                            slot=slot, blocks=len(shared),
-                            full_prompt=bool(full_hit),
-                            bytes=len(shared) * self.pool.block_bytes)
-
-        if full_hit and chain_logits is not None:
-            # whole prompt lives in the pool already: zero prefill, zero
-            # new blocks — the chain's stored last-token logits row seeds
-            # the first sample exactly as a fresh prefill's would
-            logits = jnp.asarray(chain_logits)[None, None]
-            self.metrics.record_admit(0)
-        else:
-            toks = np.zeros((1, Pb), np.int32)
-            toks[0, :P] = req.prompt
-            # true_len rides along whenever bucketing is on (one bucketed
-            # prefill signature even for exact-fit prompts); a resume that
-            # skipped bucketing prefills at its exact length
-            true_len = (jnp.asarray([P], jnp.int32)
-                        if self.prefill_bucket and (Pb != P or not req.resume)
-                        else None)
-            with obs.span("req.prefill", device=True, track=track,
-                          id=req.id, prompt_len=P, padded_len=Pb, slot=slot,
-                          shared_blocks=len(shared),
-                          resumed=req.resume is not None):
-                cache1, logits = self._prefill_fn(self.params,
-                                                  jnp.asarray(toks),
-                                                  true_len)
-                if self.paged:
-                    # shared prefix blocks are read-only — the donor's data
-                    # is bit-identical, so mask them out of the scatter
-                    self.pool.insert(cache1, slot, skip_blocks=len(shared))
-                else:
-                    self.pool.insert(cache1, slot)
-            self.metrics.record_admit(P)
-
-        prior: List[int] = list(res.get("generated", []))
-        sp = req.sampling
-        base_key = np.asarray(jax.random.PRNGKey(sp.seed), np.uint32)
-        # sample counter continues across eviction/recompute: token i of the
-        # ORIGINAL request is always drawn from fold_in(key, i)
-        tok0, ok0 = self._first_fn(
-            logits, jnp.asarray(base_key),
-            jnp.asarray(sp.temperature, jnp.float32),
-            jnp.asarray(sp.top_k, jnp.int32),
-            jnp.asarray(sp.top_p, jnp.float32),
-            jnp.asarray(len(prior), jnp.int32))
-        if not bool(ok0):
-            # prefill already went non-finite: quarantine at admission,
-            # BEFORE the prompt could be indexed as a prefix donor (a
-            # poisoned chain would hand NaN logits to every sharer)
-            self.quarantined[req.id] = QuarantinedRequest(
-                req.id, "nonfinite_logits", self.step_count,
-                int(res.get("prompt_len", req.prompt_len)), len(prior))
-            self.metrics.record_quarantine("nonfinite_logits")
-            self.pool.release(slot)
-            self._audit_quarantine(req, "nonfinite_logits", slot=slot,
-                                   generated=len(prior))
-            return
-        tok0 = int(tok0)
-        if not full_hit and self.share_prefixes and req.resume is None:
-            # index this prompt for future sharers (resumes carry
-            # generated continuations — not reusable prompts)
-            self.pool.register_prefix(
-                slot, req.prompt, np.asarray(logits[0, -1]))
+                self._audit_quarantine(req, "nonfinite_logits", slot=slot,
+                                       generated=len(prior))
+                return
+            if not full_hit and self.share_prefixes and req.resume is None:
+                # index this prompt for future sharers (resumes carry
+                # generated continuations — not reusable prompts)
+                with obs.span("engine.admit.index", track=track):
+                    self.pool.register_prefix(
+                        slot, req.prompt, np.asarray(logits[0, -1]))
 
         now = time.perf_counter()
         st = GenState(request=req, slot=slot, pos=P, last_token=tok0,
@@ -837,6 +873,9 @@ class ForecastEngine:
                 victims.append(self._evict(victim))
         if victims:
             victims.sort(key=lambda r: self._seq.get(r.id, 0))
+            now = time.perf_counter()
+            for r in victims:
+                self._queued_at[r.id] = now
             self.scheduler.requeue_front(victims)
 
     def _park(self, slot: int, st: GenState) -> None:
@@ -928,8 +967,8 @@ class ForecastEngine:
         need = self.pool.blocks_for(min(handle["pos"], self.pool.ring_len))
         granted = self.pool.grant_prefix(slot, need)   # raises w/o effects
         nbytes = need * self.pool.block_bytes
-        with obs.span("req.swap_in", device=True, track=track, id=req.id,
-                      slot=slot, blocks=need, bytes=nbytes):
+        with obs.span("engine.admit.swap_in", track=track, slot=slot,
+                      blocks=need, bytes=nbytes):
             self.pool.insert(jax.tree.map(jnp.asarray, handle["cache"]),
                              slot)
         del granted
@@ -977,28 +1016,33 @@ class ForecastEngine:
                   if s is not None and self._pos[i] >= 0]
         if not active:
             return
-        # chaos NaN injector: the poison row is ALWAYS in the batch (all
-        # False when disarmed) so arming it never changes the signature
-        for i, s in enumerate(self.slots):
-            self._poison_row[i] = (bool(self._poison) and s is not None
-                                   and s.request.id in self._poison)
-        batch = self.decode_batch()
+        with obs.span("engine.batch"):
+            # chaos NaN injector: the poison row is ALWAYS in the batch (all
+            # False when disarmed) so arming it never changes the signature
+            for i, s in enumerate(self.slots):
+                self._poison_row[i] = (bool(self._poison) and s is not None
+                                       and s.request.id in self._poison)
+            batch = self.decode_batch()
         t0 = time.perf_counter()
-        with obs.span("engine.decode_step", device=True,
-                      step=self.step_count, active=len(active)):
+        with obs.span("engine.decode_step", step=self.step_count,
+                      active=len(active), lanes=len(self.slots)):
             tok, ok, self.pool.cache = self._step_fn(self.params,
                                                      self.pool.cache, batch)
             tok_np = np.asarray(tok)          # blocks until the step lands
             ok_np = np.asarray(ok)
+        with obs.span("engine.emit"):
+            self._emit(active, tok_np, ok_np, time.perf_counter() - t0)
+
+    def _emit(self, active: List[int], tok_np, ok_np, step_s: float) -> None:
+        """A decode step's tokens to their lanes: metrics, the pool counter
+        track, retires and the next tick's batch rows."""
         self.metrics.record_decode_step(
-            len(active), len(active), time.perf_counter() - t0,
+            len(active), len(active), step_s,
             in_flight=self.active_requests,
             blocks_in_use=self.pool.blocks_in_use,
             fragmentation=self.pool.fragmentation)
         obs.counter_track("pool", blocks_in_use=self.pool.blocks_in_use,
-                          active_lanes=len(active),
-                          free_runs=self.pool.free_runs,
-                          fragmentation=self.pool.fragmentation)
+                          active_lanes=len(active))
         if obs.enabled() and self.step_count % 16 == 0:
             obs.watermark("engine.decode")     # devmem track, sampled
         now = time.perf_counter()

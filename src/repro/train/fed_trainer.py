@@ -362,14 +362,16 @@ def federated_fit(cfg: ModelConfig, client_data, *, rounds: int = 5,
                     batches = _stack_batches(x, y, ft.local_steps,
                                              batch_size,
                                              seed=1000 * r + s)
+                    # the fit's time ends at its loss on the host, not at
+                    # the dispatch: the straggler rules read it
                     with obs.span("fed.client_fit",
                                   track=f"fed:cluster{c}", client=s,
                                   cluster=c, round=r, steps=ft.local_steps):
                         ad, l = local_update(loss_fn, params,
                                              servers[c].adapters,
                                              batches, steps=ft.local_steps)
+                        l_val = float(l)
                     measured = time.perf_counter() - fit_t0
-                    l_val = float(l)
                 att = (plan.attempt(s, r, measured) if plan
                        else Attempt(s, r, "ok", measured))
                 for k in att.kinds:

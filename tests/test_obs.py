@@ -3,14 +3,17 @@ numpy, no-op overhead, Chrome trace-event schema round-trip, engine trace
 validity, metrics fixes, and the federated ring-telemetry byte agreement
 ("one number, now four ways")."""
 
+import collections
 import json
 import os
+import re
 import subprocess
 import sys
 import threading
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -97,6 +100,27 @@ def test_span_nesting_and_thread_tracks():
     assert {e["args"]["name"] for e in meta} >= {"wk0", "wk1", "wk2"}
 
 
+def test_threads_run_one_after_another_get_their_own_tracks():
+    """An exited thread's ``threading.get_ident()`` is reused by the next
+    thread; each thread still gets a track of its own, with its name."""
+    tr = Tracer()
+
+    def work():
+        with tr.span("work"):
+            pass
+
+    for name in ("first", "second"):
+        t = threading.Thread(target=work, name=name)
+        t.start()
+        t.join(timeout=10)
+        assert not t.is_alive()
+    work = [e for e in tr.events("work") if e["ph"] == "X"]
+    assert len(work) == 2
+    track_name = {e["tid"]: e["args"]["name"]
+                  for e in tr.events("thread_name")}
+    assert [track_name[e["tid"]] for e in work] == ["first", "second"]
+
+
 def test_virtual_tracks_and_span_count():
     tr = Tracer()
     tr.add_span("req.lifecycle", 0.0, 1.0, track="req:a", id="a")
@@ -142,7 +166,7 @@ def test_disabled_tracer_is_noop_and_cheap(monkeypatch):
 
 def test_chrome_trace_schema_roundtrip(tmp_path):
     tr = Tracer()
-    with tr.span("a", device=False, k=1):
+    with tr.span("a", k=1):
         tr.instant("evt", track="t1", x=2)
     tr.counter_track("pool", blocks_in_use=3, active_lanes=1)
     tr.counter("bytes", 42)
@@ -223,7 +247,7 @@ def test_metrics_latency_percentiles():
 # ---------------------------------------------------------------------------
 
 CACHE_LEN = 48
-_LIFECYCLE = ["req.submit", "req.queued", "req.prefill", "req.first_token",
+_LIFECYCLE = ["req.submit", "req.queued", "engine.admit", "req.first_token",
               "req.decode", "req.lifecycle", "req.retire"]
 
 
@@ -274,6 +298,131 @@ def test_engine_trace_two_request_lifecycle():
         assert life[rid]["args"]["tokens"] == len(done[rid].tokens)
         assert life[rid]["args"]["ttft_s"] == pytest.approx(
             done[rid].ttft_s)
+
+
+_PHASES = ("engine.slo_sweep", "engine.schedule", "engine.grant",
+           "engine.batch", "engine.decode_step", "engine.emit")
+
+
+def _inside(inner, outer) -> bool:
+    return (outer["ts"] <= inner["ts"] and inner["ts"] + inner["dur"]
+            <= outer["ts"] + outer["dur"] + 1.0)
+
+
+def _lm_engine():
+    cfg = get_smoke_config("qwen3-0.6b")
+    params = get_model(cfg).init(cfg, jax.random.PRNGKey(0))
+    return cfg, ForecastEngine(cfg, params, num_slots=2, cache_len=CACHE_LEN)
+
+
+def _three_requests(cfg):
+    """Two lanes, three requests: the third waits in the queue, and the
+    second repeats the first's prompt (a whole-prompt hit)."""
+    rng = np.random.default_rng(2)
+    p0 = rng.integers(0, cfg.vocab_size, 16).astype(np.int32)
+    p2 = rng.integers(0, cfg.vocab_size, 7).astype(np.int32)
+    return [Request(id="a", prompt=p0, max_new_tokens=5),
+            Request(id="b", prompt=p0.copy(), max_new_tokens=3),
+            Request(id="c", prompt=p2, max_new_tokens=3)]
+
+
+def test_engine_tick_phase_and_admission_spans():
+    """One engine.tick per step(), the tick's phases nested inside it,
+    one engine.admit per admission with its sync points nested inside it,
+    and the admission args (queue wait, prefilled and shared tokens)."""
+    cfg, eng = _lm_engine()
+    obs.reset()
+    for r in _three_requests(cfg):
+        eng.submit(r)
+    eng.run(max_steps=200)
+    tr = obs.get_tracer()
+    ticks = [e for e in tr.events("engine.tick") if e["ph"] == "X"]
+    assert len(ticks) == eng.step_count
+    assert [e["args"]["step"] for e in ticks] == list(range(eng.step_count))
+    for name in _PHASES:
+        evs = tr.events(name)
+        assert evs, name
+        assert all(any(_inside(e, t) for t in ticks) for e in evs), name
+    dec = tr.events("engine.decode_step")
+    assert len(dec) == eng.metrics.decode_steps
+    assert all(e["args"]["lanes"] == 2 and 1 <= e["args"]["active"] <= 2
+               for e in dec)
+
+    admits = {e["args"]["id"]: e for e in tr.events("engine.admit")}
+    assert len(tr.events("engine.admit")) == \
+        eng.metrics.requests_admitted == 3
+    assert all(any(_inside(a, t) for t in ticks) for a in admits.values())
+    for name in ("engine.admit.prefill", "engine.admit.first_token",
+                 "engine.admit.index"):
+        evs = tr.events(name)
+        assert evs, name
+        assert all(any(_inside(e, a) for a in admits.values())
+                   for e in evs), name
+    a, b, c = admits["a"]["args"], admits["b"]["args"], admits["c"]["args"]
+    assert (a["prefilled"], a["shared_tokens"]) == (16, 0)
+    assert (b["prefilled"], b["shared_tokens"]) == (0, 16)    # whole hit
+    assert (c["prefilled"], c["shared_tokens"]) == (7, 0)
+    assert not any(x["resumed"] for x in (a, b, c))
+    assert a["prompt_len"] == 16 and c["prompt_len"] == 7
+    # "c" waited in the queue for a lane through at least one decode step
+    assert c["queued_ms"] > max(a["queued_ms"], b["queued_ms"])
+    assert c["queued_ms"] >= 1e-3 * min(e["dur"] for e in dec)
+
+
+def test_engine_spans_on_the_profiler_timeline(tmp_path):
+    """Under jax.profiler the engine's spans are host events of the trace,
+    named as the tracer names them and carrying its args as stats."""
+    cfg, eng = _lm_engine()
+    reqs = _three_requests(cfg)
+    eng.submit(Request(id="warm", prompt=reqs[2].prompt, max_new_tokens=2))
+    eng.run(max_steps=200)                    # compile outside the trace
+    obs.reset()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for r in reqs:
+            eng.submit(r)
+        eng.run(max_steps=200)
+    finally:
+        jax.profiler.stop_trace()
+    path, = [os.path.join(d, f) for d, _, fs in os.walk(tmp_path)
+             for f in fs if f.endswith(".xplane.pb")]
+    host = collections.defaultdict(list)
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("engine."):
+                        host[e.name].append((e.start_ns, dict(list(e.stats))))
+    prof = {n: [st for _, st in sorted(evs, key=lambda x: x[0])]
+            for n, evs in host.items()}
+    tr = obs.get_tracer()
+    admits = tr.events("engine.admit")
+    assert [(st["queued_ms"], st["prefilled"], st["id"])
+            for st in prof["engine.admit"]] == \
+        [(e["args"]["queued_ms"], e["args"]["prefilled"], e["args"]["id"])
+         for e in admits]
+    assert [(st["active"], st["lanes"], st["step"])
+            for st in prof["engine.decode_step"]] == \
+        [(e["args"]["active"], e["args"]["lanes"], e["args"]["step"])
+         for e in tr.events("engine.decode_step")]
+    assert len(prof["engine.tick"]) == tr.span_count("engine.tick")
+    for name in _PHASES + ("engine.admit.prefill",
+                           "engine.admit.first_token"):
+        assert len(prof[name]) == tr.span_count(name), name
+
+
+def test_serve_programs_named_for_the_trace():
+    """The trace's readers find sampling by its named scope and the serve
+    step and prefill by their program names."""
+    cfg, eng = _lm_engine()
+    step = eng._step_fn.lower(eng.params, eng.pool.cache,
+                              eng.decode_batch()).compile().as_text()
+    assert "serve_step" in step.splitlines()[0]
+    assert re.search(r'op_name="[^"]*obs\.sample/[^"]*sort', step)
+    toks = jnp.zeros((1, 8), jnp.int32)
+    prefill = eng._prefill_fn.lower(eng.params, toks, None).as_text(
+        dialect="hlo")
+    assert "_prefill" in prefill.splitlines()[0]
 
 
 def test_engine_trace_valid_chrome_json(tmp_path):
@@ -394,3 +543,43 @@ def test_fed_trainer_round_telemetry():
     for l in res.logs:
         assert f"fed.adapter_delta_norm.cluster{l.cluster}" in tr.gauges
         assert f"fed.round_loss.cluster{l.cluster}" in tr.gauges
+
+
+class _LateLoss:
+    """A loss that reaches the host ``delay_s`` after the fit returns, as
+    a dispatched computation's does."""
+
+    def __init__(self, loss, delay_s):
+        self.loss, self.delay_s = loss, delay_s
+
+    def __float__(self):
+        time.sleep(self.delay_s)
+        return float(self.loss)
+
+
+def test_client_fit_time_covers_the_fit(monkeypatch):
+    """A client's recorded fit time (ledger wall time, the span) runs
+    until its loss is on the host, not until the fit was dispatched."""
+    from repro.train import fed_trainer
+    delay_s = 0.2
+    fit = fed_trainer.local_update
+
+    def slow_fit(*a, **kw):
+        ad, loss = fit(*a, **kw)
+        return ad, _LateLoss(loss, delay_s)
+
+    monkeypatch.setattr(fed_trainer, "local_update", slow_fit)
+    cfg = get_smoke_config("fedtime-llama2-7b")
+    rng = np.random.default_rng(0)
+    L, T, M = cfg.fedtime.lookback, cfg.fedtime.horizon, 2
+    data = [(rng.standard_normal((6, L, M)).astype(np.float32),
+             rng.standard_normal((6, T, M)).astype(np.float32))
+            for _ in range(3)]
+    obs.reset()
+    res = fed_trainer.federated_fit(cfg, data, rounds=1, batch_size=2,
+                                    key=jax.random.PRNGKey(0))
+    walls = [rec.wall_s for rec in res.fleet.records if rec.participated]
+    assert walls and min(walls) >= delay_s
+    fits = [e for e in obs.get_tracer().events("fed.client_fit")
+            if e["ph"] == "X"]
+    assert fits and min(e["dur"] for e in fits) >= 1e6 * delay_s

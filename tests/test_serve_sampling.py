@@ -112,3 +112,25 @@ def test_sampled_token_always_in_masked_support(seed, top_k, top_p,
         k_eff = min(top_k, V)
         kth = np.sort(np.asarray(logits[0]))[-k_eff]
         assert np.asarray(logits)[0, tok] >= kth
+
+
+def test_knobs_that_underflow_float32_keep_the_top_token(key):
+    """A positive temperature that is 0 in float32 (1e-160), or subnormal
+    there (3.4e-39), with top_k 1: the greedy token, not a draw from
+    inf/nan logits; likewise a top_p that is 0 (5e-324) or subnormal
+    (3.4e-39) in float32 keeps the top-1 token."""
+    logits = _logits(key, 2, 32)
+    best = np.argmax(np.asarray(logits), axis=-1)
+    for temperature in (1e-160, 3.4e-39):
+        tok = sample(jax.random.fold_in(key, 1), logits,
+                     temperature=temperature, top_k=1)
+        np.testing.assert_array_equal(np.asarray(tok), best)
+    for top_p in (5e-324, 3.4e-39):
+        tok = sample(jax.random.fold_in(key, 1), logits, temperature=1.0,
+                     top_k=1, top_p=top_p)
+        np.testing.assert_array_equal(np.asarray(tok), best)
+    tok = sample_vec(_keys(2), logits,
+                     temperature=np.asarray([1e-160, 1e-160]),
+                     top_k=jnp.asarray([1, 1], jnp.int32),
+                     top_p=jnp.zeros(2))
+    np.testing.assert_array_equal(np.asarray(tok), best)

@@ -1,68 +1,79 @@
-"""Fused flash-decode Pallas TPU kernel over ring or paged block KV caches.
+"""Fused flash-decode Pallas TPU kernels over ring or paged block KV caches.
 
 One decode step: G grouped queries per KV head attend to every valid slot of
-the cache.  Grid is (batch, kv_head, KV blocks); the KV axis is innermost,
-so each program streams one cache tile through VMEM while a running
-(m, l, acc) online-softmax state persists in scratch.  The KV axis is
-further carved into ``n_splits`` independent splits: each split flushes its
-own partial (m, l, acc) and a final cross-split combine (plain jnp — the
-payload is n_splits x G x D per head) produces the output.  This split-KV
-shape is what makes single-token decode fill the chip: without it, one
-(batch, head) pair maps to one core-sequential stream.
-
-Fused into the streamed pass:
+the cache, with a running (m, l, acc) online-softmax state in f32.  Fused
+into the streamed pass, in both launches:
   - int8 -> f32 dequantization from the per-slot absmax scales
     (``REPRO_KV_INT8`` caches), so the quantized cache is never materialized
-    in HBM at full precision;
+    in HBM at full precision; the scales multiply score/probability columns
+    after the matmuls instead of the (slots, D) tiles before them;
   - ring-buffer validity / causal / prefix / sliding-window masking from the
     absolute slot positions ``kv_pos`` (slot position -1 == empty);
-  - GQA query-group packing: the G queries of one KV head are one
-    (G, block_kv) MXU matmul instead of G vector products.
+  - GQA query-group packing: the G queries of one KV head are one MXU
+    matmul instead of G vector products.
 
-Two cache layouts share the kernel body:
+Two cache layouts, two launches with a kernel body each:
 
-  * contiguous ring (the training / fixed-batch shape): k/v are
-    (B, S, Hk, dh) per-request rings, one tile is a ``block_kv`` slice.
+  * contiguous ring (training / fixed-batch ``generate``): k/v are
+    (B, S, Hk, dh) per-request rings.  Grid (batch, kv_head, KV blocks): each
+    program streams one ``block_kv`` tile through VMEM, and the KV axis is
+    carved into ``n_splits`` independent splits whose (m, l, acc) partials a
+    final combine (plain jnp) merges.
   * paged block pool (the serving engine's layout): k/v are
-    (n_blocks, block_size, Hk, dh) — ONE pool shared by every request —
-    and ``block_tables`` (B, T) maps each request's logical block j to a
-    physical pool block (-1 == not granted).  The table is a
-    scalar-prefetch operand (``pltpu.PrefetchScalarGridSpec``): the BlockSpec
-    index_map dereferences it, so each program DMAs exactly the tile the
-    table names — the pool is never gathered in HBM.  Ungranted entries
-    stream pool block 0 and are masked wholesale in-kernel.  Tables are
-    READ-ONLY to the kernel, so one physical block may appear in many
-    tables at once (copy-on-write prefix sharing): every sharer streams the
-    same tile, and slots a sharer hasn't logically reached are excluded by
-    the causal/ring masks, not by table bookkeeping.
+    (n_blocks, block_size, Hk, dh) — ONE pool shared by every request — and
+    ``block_tables`` (B, T) maps each request's logical block j to a
+    physical pool block (-1 == not granted).  Grid (B,): one program a
+    request row, which loops over the row's *live* table entries only —
+    ``n_live`` = 1 + its last granted entry (0 for a row with none, such as
+    an idle lane), a scalar-prefetch operand next to the table.  Entries
+    past ``n_live`` are ungranted by definition, so skipping them is exact;
+    holes below it stay masked.  Each loop step gathers P = 256 /
+    block_size table-named pages (16 at 16-slot blocks; at most T) with
+    one DMA a page from the pool, left in HBM (``memory_space=pl.ANY``) in
+    its stored shape, into a VMEM slab of all Hk heads, double-buffered so
+    step i+1's pages land while step i computes.  The heads run as batched
+    matmuls with one (Hk, G, .) softmax state.  QK^T takes bf16 operands
+    when the query and the stored KV (or int8 codes) are bf16 — the
+    products are exact — and ``P.V`` keeps p in f32 (HIGHEST precision: a
+    default-precision f32 dot on the MXU rounds its operands to bf16).
+    Each page's slot positions (and, for int8 pools, its per-head scales)
+    come by DMA with it, from a view of the layer's per-slot rows laid out
+    128 lanes wide (``_lane_rows``: 128 / block_size pages side by side, a
+    narrower DMA does not lower); lane rotations then set the step's pages
+    side by side in one lane-dense row, -1 where an entry is ungranted or
+    past ``n_live``.  Tables are READ-ONLY to the kernel, so one physical
+    block may appear in many tables at once (copy-on-write prefix
+    sharing): every sharer streams the same page, and slots a sharer
+    hasn't logically reached are excluded by the causal/ring masks, not by
+    table bookkeeping.  No split-KV here: a
+    v5e chip has one TensorCore, so splits would fill nothing, and the
+    (m, l, acc) partials contract stays for ``return_partials`` alone.
 
-TPU tiling: every block's last two dims are either tile multiples or the
-array's full extent, which is what the TPU lowering accepts.  Per-row query
-positions and prefix lengths ride scalar prefetch (SMEM) next to the paged
-table; per-slot rows (``kv_pos``, int8 absmax scales) are laid out as
-``(rows, 1, slots)`` so a tile is one ``(1, block)`` row, and the scales
-multiply score/probability columns after the matmuls instead of the
-``(block, D)`` tiles before them.  So paged blocks of 16 slots (the serving
-default) compile as well as 128.
+TPU tiling: every BlockSpec block's last two dims are either tile multiples
+or the array's full extent, which is what the TPU lowering accepts.  Per-row
+query positions and prefix lengths ride scalar prefetch (SMEM); the ring
+launch lays per-slot rows out as ``(rows, 1, slots)`` so a tile is one
+``(1, block)`` row.
 
 ``paged_block_copy`` is the pool's copy-on-write data move: one physical
 block's tile duplicated to another block across all layers of a
 layer-stacked pool leaf, with the src/dst pair riding scalar prefetch so
 the copy is a pure per-layer DMA (no gather of the pool).
 
-Block policy (``block_kv``/``n_splits`` <= 0 selects it): tile and split
-counts are derived from the cache length instead of fixed defaults —
-short caches get fewer, wider tiles; long caches cap the tile at 1024 and
-let ``_pick_splits`` fill the chip.  ``flash_decode_xla`` is the same
-algorithm without Pallas, with a measured two-regime policy: up to
+Block policy (``block_kv``/``n_splits`` <= 0 selects it, ring launch): tile
+and split counts are derived from the cache length instead of fixed
+defaults — short caches get fewer, wider tiles; long caches cap the tile at
+1024 and let ``_pick_splits`` choose the splits.  ``flash_decode_xla`` is
+the same algorithm without Pallas, with a measured two-regime policy: up to
 ``REPRO_DECODE_WIDE_MAX`` (4096) slots a single-pass "wide" form (int8
 codes transposed *before* dequant — half the transpose traffic of
 dequant-then-transpose, the reason the old blockwise scan lost to naive
 sdpa at 4k; it does materialize one O(S) f32 copy, the accepted trade at
 short S), above it a ``jax.lax.scan`` over 2048-slot tiles with in-scan
-dequant (O(block) temporaries).  Both support ``return_partials`` for the sequence-sharded
-path (``repro.dist.decode``): a shard computes local (m, l, acc) over its
-slots and the cross-shard combine is a pmax/psum over the ``model`` axis.
+dequant (O(block) temporaries).  All paths support ``return_partials`` for
+the sequence-sharded path (``repro.dist.decode``): a shard computes local
+(m, l, acc) over its slots and the cross-shard combine is a pmax/psum over
+the ``model`` axis.
 """
 
 from __future__ import annotations
@@ -174,12 +185,10 @@ def paged_gather(k, v, kv_pos, k_scale, v_scale, block_tables):
 # ---------------------------------------------------------------------------
 
 def _kernel(*refs, bps: int, kind: str, window: int, softcap: float,
-            scale: float, quantized: bool, paged: bool):
+            scale: float, quantized: bool):
     # scalar-prefetch operands (SMEM) come first: per-row query position and
-    # prefix length, then the paged launch's block table
+    # prefix length
     qpos_ref, plen_ref, *refs = refs
-    if paged:
-        tbl_ref, *refs = refs
     if quantized:
         (q_ref, k_ref, v_ref, kpos_ref, ks_ref, vs_ref,
          o_m, o_l, o_acc, m_s, l_s, acc_s) = refs
@@ -211,10 +220,6 @@ def _kernel(*refs, bps: int, kind: str, window: int, softcap: float,
     kp = kpos_ref[0]                                 # (1, block_kv)
     mask = _slot_mask(kp, qpos_ref[b], plen_ref[b],
                       kind=kind, window=window)      # (1, block_kv)
-    if paged:
-        # ungranted table entries stream pool block 0 — drop them wholesale
-        # (a freed block's stale kv_pos may otherwise pass the ring mask)
-        mask = mask & (tbl_ref[b, j] >= 0)
     s = jnp.where(mask, s, _NEG)
 
     m_prev = m_s[...]                                # (G, 1)
@@ -288,9 +293,9 @@ def _scale_rows(x):
 
 def _partial_outputs(B: int, Hk: int, n_splits: int, G_pad: int, D: int,
                      bps: int):
-    """(out_specs, out_shape, scratch_shapes) for the per-split (m, l, acc)
-    partials — shared by the contiguous and paged launches (the index_map
-    takes the launch's trailing scalar-prefetch args as *_)."""
+    """(out_specs, out_shape, scratch_shapes) for the ring launch's
+    per-split (m, l, acc) partials (the index_map takes the trailing
+    scalar-prefetch args as *_)."""
     def idx(b, h, j, *_, _bps=bps):
         return (b, h, j // _bps, 0, 0)
 
@@ -340,8 +345,9 @@ def flash_decode(q, k, v, kv_pos, q_pos, *, k_scale=None, v_scale=None,
     (int8 when ``k_scale``/``v_scale`` absmax scales are given, shaped like
     k/v with a trailing 1); kv_pos: (B, S) / (n_blocks, block_size) absolute
     slot positions (-1 == empty); q_pos: scalar or (B,) query position.
-    ``block_kv``/``n_splits`` <= 0 derive the tile/split counts from the
-    cache length (paged tiles are always one pool block).  Returns
+    ``block_kv``/``n_splits`` <= 0 derive the ring launch's tile/split
+    counts from the cache length; the paged launch ignores both (its steps
+    are whole pool pages, and it does not split).  Returns
     (B, 1, H, D) in q.dtype, or the raw f32 partials (m, l, acc) of shapes
     (B, Hk, G, 1)/(B, Hk, G, 1)/(B, Hk, G, D) when ``return_partials``
     (sequence-sharded combine, repro.dist.decode).
@@ -350,7 +356,7 @@ def flash_decode(q, k, v, kv_pos, q_pos, *, k_scale=None, v_scale=None,
         return _flash_decode_paged(
             q, k, v, kv_pos, block_tables, q_pos, k_scale=k_scale,
             v_scale=v_scale, kind=kind, window=window, prefix_len=prefix_len,
-            softcap=softcap, n_splits=n_splits, interpret=interpret,
+            softcap=softcap, interpret=interpret,
             return_partials=return_partials)
     B, S, Hk, D = k.shape
     kv_pos = jnp.asarray(kv_pos, jnp.int32)
@@ -396,7 +402,7 @@ def flash_decode(q, k, v, kv_pos, q_pos, *, k_scale=None, v_scale=None,
     m, l, acc = pl.pallas_call(
         functools.partial(_kernel, bps=bps, kind=kind, window=window,
                           softcap=softcap, scale=D ** -0.5,
-                          quantized=quantized, paged=False),
+                          quantized=quantized),
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
@@ -404,62 +410,251 @@ def flash_decode(q, k, v, kv_pos, q_pos, *, k_scale=None, v_scale=None,
     return _finish(m, l, acc, G, q, return_partials)
 
 
+# Slots the paged launch gathers a loop step.  At qwen3 widths a step's K
+# and V are 1 MiB of bf16 (2 MiB double-buffered), well inside VMEM, and
+# enough bytes to hide the step's fixed cost: on a v5e chip the kernel took
+# 14-18% longer at 128 slots, and no less time at 512 than at 256.
+_PAGED_STEP_SLOTS = 256
+
+
+def _pages_per_step(block_size: int, table_width: int) -> int:
+    """Pool pages one step of the paged launch gathers:
+    ``_PAGED_STEP_SLOTS`` slots' worth, at most the table's width."""
+    return max(1, min(table_width, _PAGED_STEP_SLOTS // block_size))
+
+
+def live_pages(tbl, xp=jnp):
+    """Table entries the paged launch visits, per row: 1 + the row's last
+    granted logical block, 0 for a row with none (such as an idle lane).
+    ``xp`` is the array module (``numpy`` for a host-side table)."""
+    T = tbl.shape[1]
+    return xp.max(xp.where(tbl >= 0, xp.arange(1, T + 1), 0), axis=1)
+
+
+def _pages_per_row(block_size: int) -> int:
+    """Pool pages whose per-slot rows share one 128-lane row of the
+    kernel's metadata view (``_lane_rows``)."""
+    return 128 // block_size if 128 % block_size == 0 else 1
+
+
+def _lane_rows(x, fill):
+    """Per-slot pool metadata ``(nb, bs, R)`` -> ``(rows, R, W)``: the slots
+    of ``_pages_per_row`` consecutive pool pages side by side along ``W``
+    (a 128 multiple) lanes, one lane row per metadata channel, so a page's
+    DMA moves whole lane tiles (a narrower DMA does not lower).  One layout
+    change of the layer's metadata, not a gather through the table."""
+    nb, bs, R = x.shape
+    k = _pages_per_row(bs)
+    W = -(-(k * bs) // 128) * 128
+    x = jnp.pad(x, ((0, -nb % k), (0, 0), (0, 0)), constant_values=fill)
+    x = x.reshape(-1, k, bs, R).transpose(0, 3, 1, 2).reshape(-1, R, k * bs)
+    return jnp.pad(x, ((0, 0), (0, 0), (0, W - k * bs)),
+                   constant_values=fill)
+
+
+def _place(rows, offs, his, *, bs: int, S: int, fill):
+    """Step row ``(R, S)`` from the step's page rows ``(pages, R, W)``:
+    page p's ``bs`` slots, found at lane ``offs[p]`` of its row, land at
+    lanes ``[p*bs, p*bs + his[p])`` — ``his[p]`` is ``bs`` for a live,
+    granted page and 0 for one that is not, whose lanes keep ``fill``.
+    Built 128 lanes at a time from lane rotations of the page rows."""
+    pages, R, W = rows.shape
+    lane = jax.lax.broadcasted_iota(jnp.int32, (R, 128), 1)
+    chunks = []
+    for c in range(-(-S // 128)):
+        out = jnp.full((R, 128), fill, rows.dtype)
+        for p in range(pages):
+            lo = p * bs - c * 128           # page p's first lane in chunk c
+            if lo >= 128 or lo + bs <= 0:
+                continue
+            # rolled[j] = row[j - lo + off]: the page's slot 0 moves to lo
+            shift = (lo % W - offs[p] + W) % W
+            piece = pltpu.roll(rows[p], shift, 1)[:, :128]
+            out = jnp.where((lane >= lo) & (lane < lo + his[p]), piece, out)
+        chunks.append(out)
+    row = chunks[0] if len(chunks) == 1 else jnp.concatenate(chunks, axis=1)
+    return row if row.shape[1] == S else row[:, :S]
+
+
+def _paged_kernel(qpos_ref, plen_ref, nlive_ref, tbl_ref, q_ref, kp_hbm,
+                  k_hbm, v_hbm, *refs, pages: int, bs: int, kind: str,
+                  window: int, softcap: float, scale: float, quantized: bool,
+                  qk_dtype, qk_precision):
+    """One request row of the paged launch: a loop over the row's live
+    steps, each gathering ``pages`` table-named pool pages (all KV heads,
+    their slot positions and, for int8 pools, their scales) by DMA,
+    double-buffered so step i+1's pages land while step i computes."""
+    if quantized:
+        ks_hbm, vs_hbm, *refs = refs
+    o_m, o_l, o_acc, kbuf, vbuf, kpbuf, *refs = refs
+    if quantized:
+        ksbuf, vsbuf, *refs = refs
+    sem, m_s, l_s, acc_s = refs
+    b = pl.program_id(0)
+    T = tbl_ref.shape[1]
+    k_row = _pages_per_row(bs)
+    n_live = nlive_ref[b]
+    n_steps = (n_live + pages - 1) // pages
+    S = pages * bs
+
+    def page(i, p):
+        # an entry past the table re-reads its last one and an ungranted
+        # one streams pool block 0; _place then gives their slots position
+        # -1, so the mask drops them
+        e = i * pages + p
+        tbl = tbl_ref[b, jnp.minimum(e, T - 1)]
+        live = (e < n_live) & (tbl >= 0)
+        return jnp.maximum(tbl, 0), live
+
+    def fetch(i, slot):
+        pairs = [(k_hbm, kbuf), (v_hbm, vbuf)]
+        rows = [(kp_hbm, kpbuf)]
+        if quantized:
+            rows += [(ks_hbm, ksbuf), (vs_hbm, vsbuf)]
+        copies = []
+        for p in range(pages):
+            blk, _ = page(i, p)
+            for hbm, buf in pairs:
+                copies.append(pltpu.make_async_copy(
+                    hbm.at[blk], buf.at[slot, pl.ds(p * bs, bs)],
+                    sem.at[slot]))
+            for hbm, buf in rows:
+                copies.append(pltpu.make_async_copy(
+                    hbm.at[blk // k_row], buf.at[slot, p], sem.at[slot]))
+        return copies
+
+    m_s[...] = jnp.full_like(m_s, _NEG)
+    l_s[...] = jnp.zeros_like(l_s)
+    acc_s[...] = jnp.zeros_like(acc_s)
+
+    @pl.when(n_steps > 0)
+    def _first():
+        for c in fetch(0, 0):
+            c.start()
+
+    def step(i, carry):
+        slot = jax.lax.rem(i, 2)
+
+        @pl.when(i + 1 < n_steps)
+        def _next():
+            for c in fetch(i + 1, 1 - slot):
+                c.start()
+
+        for c in fetch(i, slot):
+            c.wait()
+        offs, his = [], []
+        for p in range(pages):
+            blk, live = page(i, p)
+            offs.append(blk % k_row * bs if k_row > 1 else 0)
+            his.append(jnp.where(live, bs, 0))
+
+        def row(buf, fill):
+            # the step's (R, S) row of a metadata buffer, rotated as 32-bit
+            x = buf[slot]
+            x = x if x.dtype == jnp.int32 else x.astype(jnp.float32)
+            return _place(x, offs, his, bs=bs, S=S, fill=fill)
+
+        # heads lead for the batched matmuls: (S, Hk, D) -> (Hk, S, D)
+        q = q_ref[0].astype(qk_dtype)                 # (Hk, G, D)
+        k = jnp.swapaxes(kbuf[slot], 0, 1).astype(qk_dtype)
+        s = jnp.einsum("hgd,hkd->hgk", q, k, precision=qk_precision,
+                       preferred_element_type=jnp.float32) * scale
+        if quantized:
+            s = s * row(ksbuf, 0)[:, None, :]
+        if softcap > 0:
+            s = softcap * jnp.tanh(s / softcap)
+        kp = row(kpbuf, -1)                           # (1, S)
+        mask = _slot_mask(kp, qpos_ref[b], plen_ref[b], kind=kind,
+                          window=window)
+        s = jnp.where(mask, s, _NEG)                  # (Hk, G, S)
+        m_prev = m_s[...]                             # (Hk, G, 1)
+        m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        l_s[...] = l_s[...] * corr + p.sum(-1, keepdims=True)
+        if quantized:
+            p = p * row(vsbuf, 0)[:, None, :]
+        v = jnp.swapaxes(vbuf[slot], 0, 1).astype(jnp.float32)
+        # p stays f32: HIGHEST keeps the MXU from rounding it to bf16
+        acc_s[...] = acc_s[...] * corr + jnp.einsum(
+            "hgk,hkd->hgd", p, v, precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+        m_s[...] = m_new
+        return carry
+
+    jax.lax.fori_loop(0, n_steps, step, 0)
+    o_m[0] = m_s[...]
+    o_l[0] = l_s[...]
+    o_acc[0] = acc_s[...]
+
+
 def _flash_decode_paged(q, k, v, kv_pos, block_tables, q_pos, *, k_scale,
                         v_scale, kind: str, window: int, prefix_len,
-                        softcap: float, n_splits: int, interpret: bool,
+                        softcap: float, interpret: bool,
                         return_partials: bool):
-    """Paged-pool kernel launch: grid (B, Hk, T) where T is the block-table
-    width; the table is a scalar-prefetch operand and every index_map
-    dereferences it, so each program streams exactly the pool tile its
-    request granted — no gather, no per-request copy of the pool."""
+    """Paged-pool kernel launch: grid (B,), one program a request row,
+    looping over the row's live table entries in steps of
+    ``_pages_per_step`` pages.  The pool stays in HBM; the kernel DMAs
+    exactly the pages the table names, all KV heads at once, with their
+    slot positions and scales."""
     nb, bs, Hk, D = k.shape
     tbl = jnp.asarray(block_tables, jnp.int32)
     B, T = tbl.shape
-    kv_pos = jnp.asarray(kv_pos, jnp.int32)
+    pages = _pages_per_step(bs, T)
+    S = pages * bs
     qg, G, G_pad = _pack_queries(q, Hk)
-    n_splits = _pick_splits(T, n_splits)
-    bps = T // n_splits
     quantized = k_scale is not None
+    # stored bf16 (or int8 codes, exact in bf16) against a bf16 query: the
+    # MXU products are exact, so QK^T runs on bf16 operands in one pass;
+    # otherwise f32 operands at HIGHEST, which the MXU does not round
+    bf16 = q.dtype == jnp.bfloat16 and k.dtype in (jnp.bfloat16, jnp.int8)
+    qk_dtype = jnp.bfloat16 if bf16 else jnp.float32
+    qk_precision = None if bf16 else jax.lax.Precision.HIGHEST
 
-    kr = k.reshape(nb, bs, Hk * D)
-    vr = v.reshape(nb, bs, Hk * D)
-
-    def blk(b, j, t):
-        return jnp.maximum(t[b, j], 0)
-
-    in_specs = [
-        pl.BlockSpec((1, 1, G_pad, D), lambda b, h, j, *_: (b, h, 0, 0)),
-        pl.BlockSpec((1, bs, D), lambda b, h, j, qp, pln, t:
-                     (blk(b, j, t), 0, h)),
-        pl.BlockSpec((1, bs, D), lambda b, h, j, qp, pln, t:
-                     (blk(b, j, t), 0, h)),
-        pl.BlockSpec((1, 1, bs), lambda b, h, j, qp, pln, t:
-                     (blk(b, j, t), 0, 0)),
-    ]
-    args = [qg, kr, vr, _slot_rows(kv_pos)]
+    kp = _lane_rows(jnp.asarray(kv_pos, jnp.int32)[..., None], -1)
+    hbm = [kp, k, v]
+    W = kp.shape[-1]
+    rows = [pltpu.VMEM((2, pages, 1, W), jnp.int32)]
     if quantized:
-        scale_spec = pl.BlockSpec((1, 1, 1, bs), lambda b, h, j, qp, pln, t:
-                                  (blk(b, j, t), h, 0, 0))
-        in_specs += [scale_spec, scale_spec]
-        args += [_scale_rows(k_scale), _scale_rows(v_scale)]
+        hbm += [_lane_rows(x[..., 0], 0) for x in (k_scale, v_scale)]
+        rows += [pltpu.VMEM((2, pages, Hk, W), k_scale.dtype)] * 2
 
-    out_specs, out_shape, scratch = _partial_outputs(B, Hk, n_splits, G_pad,
-                                                     D, bps)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, Hk, T),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        scratch_shapes=scratch)
+    def out_spec(last):
+        return pl.BlockSpec((1, Hk, G_pad, last), lambda b, *_: (b, 0, 0, 0))
+
     m, l, acc = pl.pallas_call(
-        functools.partial(_kernel, bps=bps, kind=kind, window=window,
-                          softcap=softcap, scale=D ** -0.5,
-                          quantized=quantized, paged=True),
-        grid_spec=grid_spec,
-        out_shape=out_shape,
+        functools.partial(_paged_kernel, pages=pages, bs=bs, kind=kind,
+                          window=window, softcap=softcap, scale=D ** -0.5,
+                          quantized=quantized, qk_dtype=qk_dtype,
+                          qk_precision=qk_precision),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(B,),
+            in_specs=[pl.BlockSpec((1, Hk, G_pad, D),
+                                   lambda b, *_: (b, 0, 0, 0))]
+            + [pl.BlockSpec(memory_space=pl.ANY)] * len(hbm),
+            out_specs=[out_spec(1), out_spec(1), out_spec(D)],
+            scratch_shapes=[
+                pltpu.VMEM((2, S, Hk, D), k.dtype),
+                pltpu.VMEM((2, S, Hk, D), v.dtype),
+                *rows,
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((Hk, G_pad, 1), jnp.float32),
+                pltpu.VMEM((Hk, G_pad, 1), jnp.float32),
+                pltpu.VMEM((Hk, G_pad, D), jnp.float32),
+            ]),
+        out_shape=[
+            jax.ShapeDtypeStruct((B, Hk, G_pad, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hk, G_pad, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hk, G_pad, D), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(_row_pos(q_pos, B), _row_pos(prefix_len, B), tbl, *args)
-    return _finish(m, l, acc, G, q, return_partials)
+    )(_row_pos(q_pos, B), _row_pos(prefix_len, B),
+      live_pages(tbl).astype(jnp.int32), tbl, qg, *hbm)
+    return _finish(m[:, :, None], l[:, :, None], acc[:, :, None], G, q,
+                   return_partials)
 
 
 def paged_block_copy(leaf, src, dst, *, interpret: bool = False):

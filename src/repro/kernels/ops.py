@@ -54,6 +54,13 @@ def flash_decode_enabled() -> bool:
     return os.environ.get("REPRO_FLASH_DECODE", "1") != "0"
 
 
+def pallas_decode(cache_len: int) -> bool:
+    """True when ``flash_decode`` runs the Pallas kernel (compiled or
+    interpret) for a cache of ``cache_len`` logical slots."""
+    return (flash_decode_enabled() and use_kernels()
+            and not _short_cache_xla(cache_len))
+
+
 def decode_mode(cache_len: int) -> str:
     """Human-readable path ``flash_decode`` takes for a cache of
     ``cache_len`` logical slots (launchers print this)."""

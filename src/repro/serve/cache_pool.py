@@ -62,6 +62,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.flash_decode import live_pages
+
 PAGED_FAMILIES = ("dense", "moe")
 
 
@@ -453,6 +455,11 @@ class PagedCachePool(_LanePool):
         cancelled (shared blocks count too: the sharer holds a reference
         even though release may not free them)."""
         return int((self.table[slot] >= 0).sum())
+
+    def live_pages(self) -> int:
+        """Table entries the paged flash-decode kernel visits this step,
+        summed over lanes (``flash_decode.live_pages`` of the table)."""
+        return int(live_pages(self.table, np).sum())
 
     @property
     def block_bytes(self) -> int:

@@ -111,9 +111,11 @@ per sync point: ``engine.admit.prefill``, ``.first_token``, ``.index``
 (or ``.swap_in``).  Each tick is an ``engine.tick`` span holding its
 phases: ``engine.slo_sweep``, ``engine.schedule``, the admissions,
 ``engine.grant``, ``engine.batch``, ``engine.decode_step`` (``active`` of
-``lanes``), ``engine.emit`` and ``engine.swap_drain``; under the JAX
-profiler every one is also an annotation with its args, so host phases and
-XLA device work share one timeline.  A ``pool`` counter track samples
+``lanes``; on the paged Pallas flash-decode path also ``live_pages``, the
+table entries the kernel visits), ``engine.emit`` and
+``engine.swap_drain``; under the JAX profiler every one is also an
+annotation with its args, so host phases and XLA device work share one
+timeline.  A ``pool`` counter track samples
 blocks in use and active lanes each decode.  Exactly one
 ``req.lifecycle`` span is emitted per FINISHED request — eviction and
 recompute re-emit the per-residency phases, never the lifecycle — so a
@@ -133,6 +135,7 @@ import numpy as np
 from repro import obs
 from repro.configs.base import ModelConfig
 from repro.fault.clock import VirtualClock
+from repro.kernels import ops
 from repro.launch.steps import make_serve_step
 from repro.models.registry import get_model
 from repro.serve.cache_pool import (PAGED_FAMILIES, CachePool,
@@ -201,6 +204,9 @@ class ForecastEngine:
                                  "paged pool")
             self.pool = CachePool(self.api, cfg, num_slots, cache_len,
                                   force_window=force_window)
+        # the paged Pallas flash-decode launch, which visits only each
+        # lane's live table entries (the page-visit counter counts those)
+        self._paged_kernel = paged and ops.pallas_decode(self.pool.ring_len)
         # CoW prefix sharing + host swap tier: paged-pool features, on by
         # default there (REPRO_PREFIX_SHARE=0 / REPRO_SWAP_TIER=0 or the
         # ctor args turn them off independently)
@@ -1023,9 +1029,14 @@ class ForecastEngine:
                 self._poison_row[i] = (bool(self._poison) and s is not None
                                        and s.request.id in self._poison)
             batch = self.decode_batch()
+        pages = {}
+        if self._paged_kernel:
+            pages["live_pages"] = self.pool.live_pages()
+            self.metrics.record_page_visits(pages["live_pages"],
+                                            self.pool.table.size)
         t0 = time.perf_counter()
         with obs.span("engine.decode_step", step=self.step_count,
-                      active=len(active), lanes=len(self.slots)):
+                      active=len(active), lanes=len(self.slots), **pages):
             tok, ok, self.pool.cache = self._step_fn(self.params,
                                                      self.pool.cache, batch)
             tok_np = np.asarray(tok)          # blocks until the step lands

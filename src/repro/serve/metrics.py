@@ -49,6 +49,11 @@ Summary fields
 ``cow_copies``/``cow_bytes``       copy-on-write block copies / bytes moved
 ``swap_outs``/``swap_out_bytes``   lanes swapped to host / HBM bytes freed
 ``swap_ins``/``swap_in_bytes``     lanes restored from host / bytes refilled
+``decode_page_visit_share`` block-table entries the paged flash-decode
+                          kernel visits (1 + each lane's last granted
+                          block) over all lanes x table width, summed over
+                          decode steps (paged pool on the Pallas
+                          flash-decode path; 0 otherwise)
 ``mean_fragmentation``    mean free-list shredding per step ((runs−1)/
                           (free−1) from ``BlockAllocator``; 0 contiguous,
                           1 fully shredded)
@@ -97,6 +102,9 @@ class EngineMetrics:
     swap_out_bytes: int = 0
     swap_ins: int = 0
     swap_in_bytes: int = 0
+    decode_pages_live: int = 0                # sum over steps of the table
+                                              # entries the kernel visits
+    decode_pages_grid: int = 0                # sum over steps of B * T
     frag_sum: float = 0.0                     # sum over steps of pool frag
     peak_fragmentation: float = 0.0
     ttft_s: List[float] = dataclasses.field(default_factory=list)
@@ -136,6 +144,12 @@ class EngineMetrics:
         self.peak_fragmentation = max(self.peak_fragmentation, fragmentation)
         self.peak_in_flight = max(self.peak_in_flight, in_flight or active)
         self.last_event_at = time.perf_counter()
+
+    def record_page_visits(self, live: int, grid: int) -> None:
+        """One paged decode step: the kernel visits ``live`` of the block
+        table's ``grid`` (lanes x table width) entries."""
+        self.decode_pages_live += live
+        self.decode_pages_grid += grid
 
     def record_park(self) -> None:
         self.parked_events += 1
@@ -239,6 +253,9 @@ class EngineMetrics:
             "swap_out_bytes": self.swap_out_bytes,
             "swap_ins": self.swap_ins,
             "swap_in_bytes": self.swap_in_bytes,
+            "decode_page_visit_share": (
+                self.decode_pages_live / self.decode_pages_grid
+                if self.decode_pages_grid else 0.0),
             "mean_fragmentation": (self.frag_sum / self.decode_steps
                                    if self.decode_steps else 0.0),
             "peak_fragmentation": self.peak_fragmentation,

@@ -11,7 +11,8 @@ import pytest
 
 from repro.configs import get_smoke_config
 from repro.kernels import ops, ref
-from repro.kernels.flash_decode import flash_decode, flash_decode_xla
+from repro.kernels.flash_decode import (_pages_per_step, flash_decode,
+                                        flash_decode_xla)
 from repro.models.layers import attention as attn_mod
 from repro.models.layers.attention import (_quant_kv as _quant, attn_decode,
                                            init_attention, init_attn_cache,
@@ -166,6 +167,128 @@ def test_partials_combine_matches_full():
     o_full = flash_decode_xla(q, k, v, kv_pos, pos, block_kv=64, **kw)
     np.testing.assert_allclose(np.asarray(out), np.asarray(o_full),
                                rtol=1e-5, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# paged launch: live-page skip, all-head tiles, multi-page steps
+# ---------------------------------------------------------------------------
+
+# Qwen3 decode shape (Hk 8, G 2, D 128) over 16-slot pages; T = 20 is not a
+# multiple of the pages a step gathers, so a row's last step is short.
+_PG = dict(T=20, bs=16, Hk=8, G=2, D=128)
+
+
+def _paged_rows_case(rows, *, T, bs, Hk, G, D, int8=False, seed=0):
+    """A paged pool holding ``rows`` of (query position, table row), with
+    every granted slot's position written as a ring of ``T * bs`` slots
+    holds it (the latest position <= the query's at that ring slot).  A
+    block cited by several rows (copy-on-write sharing) must hold the same
+    positions for each."""
+    ring = T * bs
+    nb = 6 * T
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(ks[0], (len(rows), 1, Hk * G, D), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (nb, bs, Hk, D), jnp.float32)
+    v = jax.random.normal(ks[2], (nb, bs, Hk, D), jnp.float32)
+    kw = {}
+    if int8:
+        k, ksc = _quant(k)
+        v, vsc = _quant(v)
+        kw = dict(k_scale=ksc.astype(jnp.bfloat16),
+                  v_scale=vsc.astype(jnp.bfloat16))
+    else:
+        k, v = k.astype(jnp.bfloat16), v.astype(jnp.bfloat16)
+    # block 0 is in no table but holds a freed block's stale positions: an
+    # ungranted entry, which streams block 0, must still be masked
+    kv_pos = np.full((nb, bs), -1, np.int32)
+    kv_pos[0] = np.arange(bs)
+    written = {}
+    for qp, row in rows:
+        assert 0 not in row
+        for j, blk in enumerate(row):
+            if blk < 0:
+                continue
+            for o in range(bs):
+                r = j * bs + o
+                p = r + ring * ((qp - r) // ring) if r <= qp else -1
+                assert written.setdefault((blk, o), p) == p
+                kv_pos[blk, o] = p
+    tbl = jnp.asarray([row for _, row in rows], jnp.int32)
+    q_pos = jnp.asarray([qp for qp, _ in rows], jnp.int32)
+    return q, k, v, jnp.asarray(kv_pos), tbl, q_pos, kw
+
+
+def _paged_rows(case):
+    T = _PG["T"]
+    full = list(range(40, 40 + T))
+    if case == "cow_hole":
+        # a hole below the last granted entry; block 7 cited by both rows
+        return [(5 * 16 + 2, [7, 3, -1, 9, 11, 12] + [-1] * (T - 6)),
+                (2 * 16 + 4, [7, 13, 14] + [-1] * (T - 3))]
+    if case == "ring_wrap":
+        # every entry granted, the ring overwritten once and a half
+        return [(T * 16 + T * 8 + 3, full),
+                (T * 16 - 1, list(range(60, 60 + T)))]
+    # ragged live extents: inactive lane with nothing granted, one slot,
+    # a partial last page, the whole table
+    return [(-1, [-1] * T), (0, [5] + [-1] * (T - 1)),
+            (4 * 16 + 6, [21, 2, 17, 8, 30] + [-1] * (T - 5)),
+            (T * 16 - 1, full)]
+
+
+@pytest.mark.parametrize("case, int8, kw", [
+    pytest.param("ragged", False, {}, id="ragged-bf16"),
+    pytest.param("ragged", True, {}, id="ragged-int8"),
+    pytest.param("cow_hole", False, {}, id="cow_hole-bf16"),
+    pytest.param("cow_hole", True, {}, id="cow_hole-int8"),
+    pytest.param("ring_wrap", False, {}, id="ring_wrap-bf16"),
+    pytest.param("ragged", False, dict(softcap=30.0), id="softcap"),
+    pytest.param("ring_wrap", False, dict(window=40), id="window"),
+    pytest.param("cow_hole", False, dict(kind="prefix", prefix_len=[70, 20]),
+                 id="prefix"),
+    pytest.param("ragged", False, dict(kind="full"), id="full"),
+    pytest.param("ragged", False, dict(return_partials=True),
+                 id="partials-bf16"),
+    pytest.param("ring_wrap", True, dict(return_partials=True, window=100),
+                 id="partials-int8-window"),
+    pytest.param("ragged", True, dict(bs=24), id="ragged-int8-bs24"),
+    pytest.param("cow_hole", True, dict(bs=8), id="cow_hole-int8-bs8"),
+])
+def test_paged_kernel_matches_oracle(case, int8, kw):
+    """The paged launch (interpret mode) against the plain oracle over the
+    gathered pool, at the Qwen3 shape: rows visit only their live pages
+    (an idle row visits none), ungranted holes and shared blocks, the ring
+    wrap, every mask kind, int8 dequant, and the sequence-sharded
+    partials.  Pages of 16 slots share a 128-lane row of positions and
+    scales eight to a row; the block-size cases cover a page that fills a
+    padded row alone (24) and a step whose slots are not a whole number of
+    128-lane rows (8: 20 pages of 8)."""
+    shape = dict(_PG, bs=kw.get("bs", _PG["bs"]))
+    kw = {k: x for k, x in kw.items() if k != "bs"}
+    if shape["bs"] == _PG["bs"]:
+        assert _PG["T"] % _pages_per_step(_PG["bs"], _PG["T"])
+    q, k, v, kv_pos, tbl, q_pos, skw = _paged_rows_case(
+        _paged_rows(case), int8=int8, seed=len(case), **shape)
+    if "prefix_len" in kw:
+        kw["prefix_len"] = jnp.asarray(kw["prefix_len"], jnp.int32)
+    partials = kw.pop("return_partials", False)
+    got = flash_decode(q, k, v, kv_pos, q_pos, block_tables=tbl,
+                       interpret=True, return_partials=partials, **skw, **kw)
+    if partials:
+        want = flash_decode_xla(q, k, v, kv_pos, q_pos, block_tables=tbl,
+                                return_partials=True, **skw, **kw)
+        for g, w in zip(got, want):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       rtol=1e-4, atol=1e-4)
+        m, l, acc = got
+        B, Hk, G, D = acc.shape
+        got = (acc / jnp.maximum(l, 1e-30)).reshape(B, 1, Hk * G, D)
+    want = ref.flash_decode_ref(q, k, v, kv_pos, q_pos, block_tables=tbl,
+                                **skw, **kw)
+    tol = _tol(int8, jnp.bfloat16)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=tol, atol=tol)
 
 
 def test_sharded_flash_decode_on_emulated_mesh():

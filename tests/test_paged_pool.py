@@ -380,6 +380,58 @@ def test_paged_engine_matches_solo(dense):
     assert eng.metrics.summary()["mean_block_utilization"] > 0
 
 
+def test_decode_page_visit_share_counts_live_table_entries(dense,
+                                                          monkeypatch):
+    """``decode_page_visit_share`` is the table entries the paged kernel
+    visits (1 + each lane's last granted block, 0 for a lane holding none)
+    over lanes x table width, summed over decode steps — counted here by a
+    plain loop over the table each serve step is given — and every
+    ``engine.decode_step`` span carries its step's ``live_pages``.  Only
+    the Pallas path is counted: an engine on the XLA path records none."""
+    from repro import obs
+    cfg, api, params = dense
+    xla = ForecastEngine(cfg, params, num_slots=2, cache_len=CACHE_LEN,
+                         paged=True, block_size=8)
+    xla.submit(Request(id="x", prompt=_prompts(cfg, [5], seed=3)[0],
+                       max_new_tokens=2))
+    xla.step()
+    assert xla.metrics.summary()["decode_page_visit_share"] == 0.0
+    monkeypatch.setenv("REPRO_FORCE_KERNELS", "1")
+    obs.reset()
+    eng = ForecastEngine(cfg, params, num_slots=3, cache_len=CACHE_LEN,
+                         paged=True, block_size=8)
+    long_p, short_p = _prompts(cfg, [20, 5], seed=31)
+    eng.submit(Request(id="long", prompt=long_p, max_new_tokens=6))
+    eng.submit(Request(id="short", prompt=short_p, max_new_tokens=6))
+    tables = []
+    step_fn = eng._step_fn
+
+    def spy(params, cache, batch):
+        tables.append(np.asarray(batch["block_tbl"]))
+        return step_fn(params, cache, batch)
+
+    eng._step_fn = spy
+    for _ in range(3):
+        eng.step()
+
+    def live(row):
+        granted = [j for j, blk in enumerate(row) if blk >= 0]
+        return granted[-1] + 1 if granted else 0
+
+    per_step = [sum(live(row) for row in t) for t in tables]
+    first = [live(row) for row in tables[0]]
+    T = eng.pool.blocks_per_slot
+    assert sorted(first)[0] == 0                  # the idle lane
+    assert any(0 < n < T for n in first)          # a partial lane
+    assert eng.metrics.summary()["decode_page_visit_share"] == \
+        pytest.approx(sum(per_step) / sum(t.size for t in tables))
+    spans = obs.get_tracer().events("engine.decode_step")
+    assert [e["args"]["live_pages"] for e in spans] == per_step
+    # a hole below a lane's last granted entry is visited (and masked)
+    eng.pool.table[2, 3] = eng.pool.table[0, 0]
+    assert eng.pool.live_pages() == sum(live(r) for r in eng.pool.table)
+
+
 def test_paged_engine_int8(dense, monkeypatch):
     monkeypatch.setenv("REPRO_KV_INT8", "1")
     cfg, api, params = dense

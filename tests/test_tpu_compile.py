@@ -74,17 +74,31 @@ def test_flash_decode_ring_compiles(one_chip, kv_dtype):
     assert "tpu_custom_call" in _compiled_text(fn, *args)
 
 
-@pytest.mark.parametrize("kv_dtype", [jnp.bfloat16, jnp.int8])
-@pytest.mark.parametrize("block_size", [16, 128])
-def test_flash_decode_paged_compiles(one_chip, block_size, kv_dtype):
+def _custom_call_names(text):
+    """Instruction names of the compiled program's Pallas kernels."""
+    return [line.split("=", 1)[0].strip().lstrip("%")
+            for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
+@pytest.mark.parametrize("block_size, kv_dtype, batch, slots", [
+    pytest.param(bs, dt, b, n, id=f"{bs}-{jnp.dtype(dt).name}{tag}")
+    for b, n, tag in ((B, S, ""), (16, 4096, "-b16x4096"))
+    for bs in (16, 128) for dt in (jnp.bfloat16, jnp.int8)])
+def test_flash_decode_paged_compiles(one_chip, block_size, kv_dtype, batch,
+                                     slots):
+    """Paged decode compiles, at the serving benchmark's shape too (16
+    lanes x 4,096 positions: 256 table entries of 16 slots), and the
+    kernel keeps ``flash_decode`` in its name, by which the benchmark's
+    roofline reader finds it in a device trace."""
     from repro.kernels.flash_decode import flash_decode
     s = lambda shp, dt: _sds(one_chip, shp, dt)  # noqa: E731
-    nb, T = B * S // block_size, S // block_size
-    args = [s((B, 1, H, D), jnp.bfloat16),
+    nb, T = batch * slots // block_size, slots // block_size
+    args = [s((batch, 1, H, D), jnp.bfloat16),
             s((nb, block_size, HK, D), kv_dtype),
             s((nb, block_size, HK, D), kv_dtype),
-            s((nb, block_size), jnp.int32), s((B,), jnp.int32),
-            s((B, T), jnp.int32)]
+            s((nb, block_size), jnp.int32), s((batch,), jnp.int32),
+            s((batch, T), jnp.int32)]
     if kv_dtype == jnp.int8:
         args += [s((nb, block_size, HK, 1), jnp.bfloat16)] * 2
 
@@ -93,7 +107,8 @@ def test_flash_decode_paged_compiles(one_chip, block_size, kv_dtype):
         return flash_decode(q, k, v, kv_pos, q_pos, block_tables=tbl,
                             k_scale=ks, v_scale=vs)
 
-    assert "tpu_custom_call" in _compiled_text(fn, *args)
+    names = _custom_call_names(_compiled_text(fn, *args))
+    assert names and all("flash_decode" in n for n in names), names
 
 
 @pytest.mark.parametrize("leaf", ["kv", "kv_int8", "kv_scale", "kv_pos"])

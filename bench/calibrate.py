@@ -41,8 +41,8 @@ def serve_seed(cell, seed, seconds, control, out):
     finished = s.finish(cell["limits"]["sample_tokens"])
     s.close()
     for int8 in ([False, True] if control else [False]):
-        checks = serve.check_outputs(s.cfg, cell["config_data"], cell,
-                                     s.reqs, finished, seed, int8=int8)
+        checks = serve.check_outputs(s.model, s.cfg, cell, s.reqs, finished,
+                                     seed, int8=int8)
         emit(out, workload=cell["name"], seed=seed,
              side="control" if int8 else "program",
              finished=len(finished),

@@ -42,14 +42,35 @@ def load_workload(name: str) -> dict:
     return cell
 
 
-def metric_reader(name: str):
-    """The ``read(run)`` function of ``bench/metrics/<name>.py``."""
-    path = os.path.join(BENCH, "metrics", f"{name}.py")
+def _load_file(kind: str, name: str):
+    """``bench/<kind>/<name>.py`` as a module of its own."""
+    path = os.path.join(BENCH, kind, f"{name}.py")
     spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
+        f"bench_{kind}_" + name.replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def metric_reader(name: str):
+    """The ``read(run)`` function of ``bench/metrics/<name>.py``."""
+    return _load_file("metrics", name).read
+
+
+def model_module(config_data: dict):
+    """``bench/models/<model_type>.py``, the module of the configuration's
+    architecture: ``model_config``, ``params``, ``logits_at`` and the FLOP
+    and byte counts.  A ``model_type`` with no module is an error, never
+    another architecture's module."""
+    name = config_data["model_type"]
+    models = os.path.join(BENCH, "models")
+    path = os.path.join(models, f"{name}.py")
+    if not os.path.isfile(path):
+        known = sorted(f[:-3] for f in os.listdir(models) if f.endswith(".py"))
+        raise FileNotFoundError(
+            f"no module for model_type {name!r}: {path} is missing "
+            f"(bench/models has {known})")
+    return _load_file("models", name)
 
 
 def benchmark_spec() -> dict:

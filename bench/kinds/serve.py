@@ -10,9 +10,10 @@ request sent in it has its first token and until the finished requests
 hold the cell's ``sample_tokens``; then its memory is read and freed, and
 a sample of the finished requests, drawn from the seed with the longest
 among them and covering ``sample_tokens`` served tokens, goes through the
-plain float32 reference (``reference/qwen3.py``): each served token's
-logit has to lie within the cell's limit of the reference's best at that
-position.
+plain float32 reference of the configuration's architecture (its module's
+``logits_at``, ``bench/models/<model_type>.py``, which hands on to
+``bench/reference/``): each served token's logit has to lie within the
+cell's limit of the reference's best at that position.
 """
 
 from __future__ import annotations
@@ -26,9 +27,6 @@ import numpy as np
 
 import common
 import flops
-import weights
-from model import model_config
-from reference import qwen3 as ref
 from traffic import gen
 
 DRAIN_S = 60.0          # how long a request due in the window may wait
@@ -219,11 +217,12 @@ class Session:
     def __init__(self, cell: dict, seed: int, seconds: float,
                  annotate=_noop):
         cdata, self.mix = cell["config_data"], cell["traffic_data"]
-        self.cfg = model_config(cdata)
+        self.model = common.model_module(cdata)
+        self.cfg = self.model.model_config(cdata)
         ecfg = cdata["engine"]
         self.annotate = annotate
         preroll = self.mix.get("preroll_s", 0.0)
-        self.params = weights.lm_params(self.cfg, seed)
+        self.params = self.model.params(self.cfg, seed)
         self.reqs = gen.serve_requests(self.mix, seed, self.cfg.vocab_size,
                                        preroll + seconds)
         self.engine = make_engine(self.cfg, ecfg, self.params)
@@ -330,7 +329,8 @@ def run(cell: dict, args, jax, clock, device) -> int:
         device["busy_s"] = summ["busy_s"]
         device["window_s"] = summ["window_s"]
         ctx = {
-            "kind": "serve", "cfg": cfg, "trace": tr, "lo": lo, "hi": hi,
+            "kind": "serve", "model": s.model, "cfg": cfg, "trace": tr,
+            "lo": lo, "hi": hi,
             "window_s": summ["window_s"], "busy_s": summ["busy_s"],
             "peak": flops.peaks(device["kind"]), "loop": s.mix["loop"],
             "block_size": engine.pool.block_size,
@@ -352,8 +352,7 @@ def run(cell: dict, args, jax, clock, device) -> int:
     s.close()
 
     # ---- correctness -------------------------------------------------------
-    checks = check_outputs(cfg, cell["config_data"], cell, s.reqs, finished,
-                           args.seed)
+    checks = check_outputs(s.model, cfg, cell, s.reqs, finished, args.seed)
     correct = all(c["ok"] for c in checks)
     metrics = (layer if args.trace else
                {k: e2e[k] for k in common.end_to_end_names(spec, cell["name"])})
@@ -383,7 +382,7 @@ def sample(finished: dict, reqs_by_id: dict, seed: int, tokens: int) -> list:
     return out
 
 
-def served_gap(params, cfg_file, prompt, served, length, rows_n,
+def served_gap(model, params, cfg_file, prompt, served, length, rows_n,
                int8=False):
     """Widest gap by which a served token's reference logit lies below the
     reference's best at its position (or, for the control, the gap of the
@@ -395,11 +394,11 @@ def served_gap(params, cfg_file, prompt, served, length, rows_n,
     seq[P:P + n - 1] = served[:-1]
     rows = np.full(rows_n, P + n - 2, np.int32)
     rows[:n] = np.arange(P - 1, P + n - 1)
-    lg = np.asarray(ref.logits_at(params, cfg_file, jnp.asarray(seq),
-                                  jnp.asarray(rows)))[:n]
+    lg = np.asarray(model.logits_at(params, cfg_file, jnp.asarray(seq),
+                                    jnp.asarray(rows)))[:n]
     if int8:
-        ctl = np.asarray(ref.logits_at(params, cfg_file, jnp.asarray(seq),
-                                       jnp.asarray(rows), int8=True))[:n]
+        ctl = np.asarray(model.logits_at(params, cfg_file, jnp.asarray(seq),
+                                         jnp.asarray(rows), int8=True))[:n]
         served = ctl.argmax(-1)
     best = lg.max(-1)
     return float(np.max(best - lg[np.arange(n), np.asarray(served)]))
@@ -412,19 +411,20 @@ def ref_shape(mix: dict) -> tuple:
     return -(-S // 128) * 128, mix["output"]["max"]
 
 
-def check_outputs(cfg, cdata, cell, reqs, finished, seed,
+def check_outputs(model, cfg, cell, reqs, finished, seed,
                   int8=False) -> List[dict]:
-    mix = cell["traffic_data"]
+    cdata, mix = cell["config_data"], cell["traffic_data"]
     lim = cell["limits"]
     by_id = {r["id"]: r for r in reqs}
     wrong_len = sum(len(f.tokens) != by_id[i]["max_new_tokens"]
                     for i, f in finished.items())
     picked = sample(finished, by_id, seed, lim["sample_tokens"])
-    params = weights.lm_params(cfg, seed)
+    params = model.params(cfg, seed)
     length, rows_n = ref_shape(mix)
     gap = 0.0
     for i in picked:
-        gap = max(gap, served_gap(params, cdata, by_id[i]["prompt"],
+        gap = max(gap, served_gap(model, params, cdata,
+                                  by_id[i]["prompt"],
                                   np.asarray(finished[i].tokens), length,
                                   rows_n, int8=int8))
     n_tok = sum(len(finished[i].tokens) for i in picked)

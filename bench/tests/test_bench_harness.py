@@ -1,6 +1,7 @@
 """The benchmark's arithmetic, its generator, its files and its refusal to
 run without a chip — all on the CPU, without the program's hot path."""
 
+import hashlib
 import json
 import os
 import shutil
@@ -141,14 +142,28 @@ def test_sample_covers_the_tokens_and_holds_the_longest():
     assert serve.sample(finished, by_id, 1, 10**6) != []
 
 
-def test_harness_finds_added_files_by_name(tmp_path, monkeypatch):
-    """A new configuration, traffic mix, cell and per-layer metric are
-    files under bench/ and nothing else."""
+def _bench_copy(tmp_path, monkeypatch):
+    """A copy of bench/ under ``tmp_path`` that the harness reads in place
+    of this one: (root, bench, spec), ``spec`` this BENCHMARK.json for the
+    caller to extend and write to ``root``."""
     root = tmp_path / "repo"
     shutil.copytree(BENCH, root / "bench",
                     ignore=shutil.ignore_patterns("tests", "__pycache__"))
     spec = common.benchmark_spec()
-    b = root / "bench"
+    monkeypatch.setattr(common, "BENCH", str(root / "bench"))
+    monkeypatch.setattr(common, "ROOT", str(root))
+    return root, root / "bench", spec
+
+
+def _digests(top):
+    return {p: hashlib.sha256(p.read_bytes()).hexdigest()
+            for p in top.rglob("*") if p.is_file()}
+
+
+def test_harness_finds_added_files_by_name(tmp_path, monkeypatch):
+    """A new configuration, traffic mix, cell and per-layer metric are
+    files under bench/ and nothing else."""
+    root, b, spec = _bench_copy(tmp_path, monkeypatch)
     cfg = json.load(open(b / "configs" / "qwen3-0.6b.json"))
     json.dump(dict(cfg, engine=dict(cfg["engine"], num_slots=8)),
               open(b / "configs" / "qwen3-0.6b-8slot.json", "w"))
@@ -170,14 +185,137 @@ def test_harness_finds_added_files_by_name(tmp_path, monkeypatch):
                               "layer": "device", "moves": "itl_p95_ms",
                               "workloads": ["serve.added"]})
     json.dump(spec, open(root / "BENCHMARK.json", "w"))
-    monkeypatch.setattr(common, "BENCH", str(b))
-    monkeypatch.setattr(common, "ROOT", str(root))
     cell = common.load_workload("serve.added")
     assert cell["config_data"]["engine"]["num_slots"] == 8
     assert cell["traffic_data"]["clients"] == 4
     assert common.per_layer_names(common.benchmark_spec(), "serve.added") \
         == ["serve.added_metric"]
     assert common.metric_reader("serve.added_metric")({}) == 42.0
+
+
+# A second architecture, Qwen3 with an untied output head: the program
+# serves it (``lm_head``), the qwen3 module cannot make its weights.
+UNTIED_MODEL = '''"""Qwen3 with an untied output head ``lm_head``."""
+import jax
+
+import common
+from reference import qwen3_untied as ref
+from weights import _dense, seed_key
+
+base = common.model_module({"model_type": "qwen3"})
+model_config = base.model_config
+prefill_flops, decode_flops = base.prefill_flops, base.decode_flops
+decode_attention_flops = base.decode_attention_flops
+paged_decode_bytes = base.paged_decode_bytes
+
+
+def params(cfg, seed):
+    @jax.jit
+    def make_head(key):
+        return {"w": _dense(jax.random.fold_in(key, 1),
+                            (cfg.d_model, cfg.vocab_size), cfg.param_dtype)}
+    return dict(base.params(cfg, seed), lm_head=make_head(seed_key(seed)))
+
+
+def logits_at(params, data, tokens, rows, *, int8=False):
+    return ref.logits_at(params, data, tokens, rows, int8=int8)
+'''
+
+UNTIED_REFERENCE = '''"""Plain float32 Qwen3 with an untied output head."""
+import functools
+
+import jax
+import jax.numpy as jnp
+
+from reference import qwen3 as q
+
+
+@functools.partial(jax.jit, static_argnames=("cfg_items", "int8"))
+def _logits_at(params, tokens, rows, cfg_items, int8):
+    cfg = dict(cfg_items)
+    with jax.default_matmul_precision("highest"):
+        f32 = jax.tree.map(lambda a: a.astype(jnp.float32), params)
+        x = f32["embed"]["table"][tokens]
+        x, _ = jax.lax.scan(
+            lambda h, lp: (q.block(h, lp, cfg, int8), None), x, f32["layers"])
+        x = q.rms(x[rows], f32["final_norm"]["scale"], cfg["rms_norm_eps"])
+        return q.matmul(x, HEAD, int8)
+
+
+def logits_at(params, cfg, tokens, rows, *, int8=False):
+    keys = ("num_attention_heads", "num_key_value_heads", "head_dim",
+            "rms_norm_eps", "rope_theta")
+    return _logits_at(params, tokens, rows,
+                      tuple((k, cfg[k]) for k in keys), int8)
+'''
+
+
+def test_an_architecture_enters_by_files_alone(tmp_path, monkeypatch,
+                                               capsys):
+    """A model_type the harness had no module for: its module, its
+    reference, a configuration and a cell, all new files, serve to
+    ``correct`` on the CPU; the same run against a reference that ties the
+    head reads not correct; no file that was there changes."""
+    import types
+
+    import jax
+
+    from kinds import serve
+    root, b, spec = _bench_copy(tmp_path, monkeypatch)
+    before = _digests(root)
+    data = json.load(open(os.path.join(BENCH, "tests", "data",
+                                       "tiny-lm.json")))
+    json.dump(dict(data, model_type="qwen3_untied",
+                   tie_word_embeddings=False,
+                   reduced=data["reduced"] + ["tie_word_embeddings"]),
+              open(b / "configs" / "tiny-untied.json", "w"))
+    shutil.copy(os.path.join(BENCH, "tests", "data", "tiny-closed.json"),
+                b / "traffic" / "tiny_closed.json")
+    json.dump({"config": "tiny-untied", "traffic": "tiny_closed",
+               "kind": "serve", "chips": 1,
+               "limits": {"max_logit_gap": 1e-3, "sample_tokens": 40}},
+              open(b / "workloads" / "serve.untied.json", "w"))
+    spec["workloads"].append({"name": "serve.untied",
+                              "config": "tiny-untied",
+                              "traffic": "tiny_closed", "chips": 1,
+                              "why": "untied head"})
+    json.dump(spec, open(root / "BENCHMARK.json", "w"))
+    (b / "models" / "qwen3_untied.py").write_text(UNTIED_MODEL)
+    ref = b / "reference" / "qwen3_untied.py"
+    monkeypatch.syspath_prepend(str(b))
+
+    def run(head):
+        ref.write_text(UNTIED_REFERENCE.replace("HEAD", head))
+        # the next import reads the file as it now is
+        monkeypatch.delitem(sys.modules, "reference.qwen3_untied",
+                            raising=False)
+        if "reference" in sys.modules:
+            monkeypatch.delattr(sys.modules["reference"], "qwen3_untied",
+                                raising=False)
+        cell = common.load_workload("serve.untied")
+        args = types.SimpleNamespace(workload=cell["name"], seed=2**33 + 7,
+                                     seconds=1.5, trace=0)
+        capsys.readouterr()
+        assert serve.run(cell, args, jax, common.Clock(),
+                         {"platform": "cpu", "kind": "TPU v5 lite",
+                          "count": 1}) == 0
+        return json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+
+    sound = run('f32["lm_head"]["w"]')
+    assert sound["correct"] is True
+    assert sound["checks"]["tokens_compared"]["value"] >= 40
+    tied = run('f32["embed"]["table"].T')
+    assert tied["correct"] is False
+    assert tied["checks"]["max_logit_gap"]["value"] > 1e-3
+    after = _digests(root)
+    assert {p: after.get(p) for p in before} == before
+
+
+def test_a_model_type_without_a_module_is_an_error():
+    with pytest.raises(FileNotFoundError) as e:
+        common.model_module({"model_type": "deepseek_v2"})
+    assert os.path.join(BENCH, "models", "deepseek_v2.py") in str(e.value)
+    assert "qwen3" in str(e.value)
 
 
 def test_a_run_without_a_tpu_fails_with_no_result():
@@ -200,6 +338,10 @@ def test_peaks_are_keyed_by_device_kind():
         flops.peaks("cpu")
 
 
+def qwen3():
+    return common.model_module({"model_type": "qwen3"})
+
+
 class _Cfg:
     """Shapes of a small decoder for hand counts."""
     num_layers, d_model, num_heads, num_kv_heads = 2, 8, 2, 1
@@ -212,16 +354,18 @@ class _Cfg:
 
 def test_layer_params_hand_count():
     # q 8x8, k 8x4, v 8x4, o 8x8; gate/up 8x16, down 16x8
-    assert flops.layer_matmul_params(_Cfg) == (64 + 32 + 32 + 64, 384)
+    assert qwen3().layer_matmul_params(_Cfg) == (64 + 32 + 32 + 64, 384)
 
 
 def test_serve_flops_hand_count():
+    m = qwen3()
     # prompt of 3: 2 FLOPs per weight per token, 6 causal pairs, one logit row
-    assert flops.prefill_flops(_Cfg, 3) == \
+    assert m.prefill_flops(_Cfg, 3) == \
         2 * 2 * 576 * 3 + 4 * 2 * 4 * 2 * 6 + 2 * 8 * 10
     # token at position 4 sees 5 keys
-    assert flops.decode_flops(_Cfg, 4) == \
+    assert m.decode_flops(_Cfg, 4) == \
         2 * 2 * 576 + 2 * 8 * 10 + 4 * 2 * 4 * 2 * 5
+    assert m.decode_attention_flops(_Cfg, 4) == 4 * 2 * 4 * 2 * 5
 
 
 def test_paged_decode_bytes_hand_count():
@@ -230,7 +374,86 @@ def test_paged_decode_bytes_hand_count():
     per_block = 4 * (2 * 1 * 4 * 2 + 4)
     q_out = 2 * 2 * 4 * 2
     want = 2 * ((1 + 2) * per_block + 2 * q_out)
-    assert flops.paged_decode_bytes(_Cfg, [0, 5], 4) == want
+    assert qwen3().paged_decode_bytes(_Cfg, [0, 5], 4) == want
+
+
+def _config(name, path=None):
+    path = path or os.path.join(BENCH, "configs", f"{name}.json")
+    with open(path) as f:
+        return dict(json.load(f), name=name)
+
+
+# The counts at Qwen3-0.6B's own widths as bench/flops.py gave them before
+# they moved into bench/models/qwen3.py.
+@pytest.mark.parametrize("count,args,want", [
+    ("prefill_flops", (2048,), 2285468647424.0),
+    ("decode_flops", (4095,), 2131492864.0),
+    ("decode_attention_flops", (4095,), 939524096.0),
+    ("paged_decode_bytes", ([0, 1000, 4095], 16), 588464128.0),
+])
+def test_qwen3_counts_at_published_widths(count, args, want):
+    m = qwen3()
+    cfg = m.model_config(_config("qwen3-0.6b"))
+    assert getattr(m, count)(cfg, *args) == want
+
+
+class _Trace:
+    def op_seconds(self, lo, hi, match=None):
+        ops = {"flash_decode_paged": 0.004, "fusion.3": 1.0}
+        return {k: v for k, v in ops.items() if match is None or match(k)}
+
+
+# Readings of a synthetic window at Qwen3-0.6B's widths, as the readers
+# gave them when they counted through bench/flops.py.
+@pytest.mark.parametrize("metric,want", [
+    ("serve.mfu", 0.8347070651126904),
+    ("flash_decode_roofline", 21.565264957264954),
+])
+def test_count_readers_read_as_before(metric, want):
+    m = qwen3()
+    run = {"model": m, "cfg": m.model_config(_config("qwen3-0.6b")),
+           "trace": _Trace(), "lo": 0, "hi": 1, "window_s": 2.0,
+           "peak": flops.peaks("TPU v5 lite"), "block_size": 16,
+           "ticks": [(0.1, [0, 1000, 4095]), (0.2, []), (0.3, [1, 1001])],
+           "admits": [(0.05, 2048, 2048, 0), (0.15, 1024, 0, 1024),
+                      (0.25, 3000, 1000, 2000)]}
+    assert common.metric_reader(metric)(run) == want
+
+
+# Each leaf's exact sum of tiny-lm's weights at one seed, as
+# bench/weights.py made them before they moved into bench/models/qwen3.py.
+TINY_LM_SUMS = {
+    "['embed']['table']": ((512, 64), 3.2400034738811314),
+    "['final_norm']['scale']": ((64,), 64.33971786499023),
+    "['layers']['attn']['k_norm']['scale']": ((2, 16), 31.64985716342926),
+    "['layers']['attn']['q_norm']['scale']": ((2, 16), 31.762085139751434),
+    "['layers']['attn']['wk']['w']": ((2, 64, 32), -0.1306711313627602),
+    "['layers']['attn']['wo']['w']": ((2, 64, 64), 26.963043638881118),
+    "['layers']['attn']['wq']['w']": ((2, 64, 64), -9.683272932973523),
+    "['layers']['attn']['wv']['w']": ((2, 64, 32), -4.449179310358886),
+    "['layers']['attn_norm']['scale']": ((2, 64), 130.02460032701492),
+    "['layers']['mlp']['down']['w']": ((2, 128, 64), -6.538345231214407),
+    "['layers']['mlp']['gate']['w']": ((2, 64, 128), 10.201201989643778),
+    "['layers']['mlp']['up']['w']": ((2, 64, 128), -19.207872173670694),
+    "['layers']['mlp_norm']['scale']": ((2, 64), 128.24915379285812),
+}
+
+
+def test_qwen3_params_fingerprint():
+    import math
+
+    import jax
+    m = qwen3()
+    cfg = m.model_config(_config(
+        "tiny-lm", os.path.join(BENCH, "tests", "data", "tiny-lm.json")))
+    leaves = jax.tree_util.tree_flatten_with_path(m.params(cfg, 2**33 + 5))[0]
+    got = {jax.tree_util.keystr(path): (leaf.shape, math.fsum(
+               np.asarray(leaf, np.float64).ravel()))
+           for path, leaf in leaves}
+    assert {k: s for k, (s, _) in got.items()} == \
+        {k: s for k, (s, _) in TINY_LM_SUMS.items()}
+    for k, (_, total) in TINY_LM_SUMS.items():
+        assert got[k][1] == pytest.approx(total, rel=0, abs=1e-6), k
 
 
 def test_roofline_share_never_reads_zero_for_want_of_time():

@@ -19,15 +19,61 @@ from typing import Optional, Tuple
 
 @dataclass(frozen=True)
 class MoEConfig:
-    """Mixture-of-experts block configuration (GShard-style dispatch)."""
+    """Mixture-of-experts block configuration: GShard capacity dispatch for
+    the training loss, dropless grouped matmuls for serving."""
 
-    num_experts: int = 8
+    num_experts: int = 8                 # the router's width (all experts)
     top_k: int = 2
     num_shared_experts: int = 0          # Qwen2-MoE style always-on experts
     capacity_factor: float = 1.25
     router_aux_loss_coef: float = 0.01
     # d_ff of each routed expert (may differ from the dense d_ff)
     expert_d_ff: int = 0
+    norm_topk_prob: bool = True          # renormalise the top-k gates
+    # the experts this chip holds under expert parallelism: ids
+    # [expert_offset, expert_offset + experts_held); 0 => all of them
+    expert_offset: int = 0
+    experts_held: int = 0
+    # leading dense SwiGLU layers of width ModelConfig.d_ff (DeepSeek
+    # ``first_k_dense_replace``)
+    first_dense_layers: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.num_experts
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2): keys and values come from
+    one ``kv_lora_rank``-wide latent per position (RMS-normed) through
+    ``kv_b``, beside a decoupled RoPE key of ``qk_rope_head_dim`` shared by
+    every head.  The cache holds the latent and the roped key, one entry of
+    ``kv_lora_rank + qk_rope_head_dim`` a position (stored ``cache_dim``
+    wide).  RoPE is YaRN-scaled
+    when ``rope_factor`` > 1 (``rope_scaling`` type ``yarn``)."""
+
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    rope_factor: float = 1.0
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 1.0
+
+    @property
+    def entry_dim(self) -> int:
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def cache_dim(self) -> int:
+        """Stored width of an entry: ``entry_dim`` zero-padded to whole
+        128-lane tiles, as the TPU's HBM layout holds it anyway and as a
+        page DMA has to move it."""
+        return -(-self.entry_dim // 128) * 128
 
 
 @dataclass(frozen=True)
@@ -137,6 +183,7 @@ class ModelConfig:
     post_block_norm: bool = False        # Gemma2 post-norms
     # --- family sub-configs ---
     moe: Optional[MoEConfig] = None
+    mla: Optional[MLAConfig] = None      # latent attention in place of GQA
     ssm: Optional[SSMConfig] = None
     xlstm: Optional[XLSTMConfig] = None
     hybrid: Optional[HybridConfig] = None

@@ -45,7 +45,11 @@ Two cache layouts, two launches with a kernel body each:
     block may appear in many tables at once (copy-on-write prefix
     sharing): every sharer streams the same page, and slots a sharer
     hasn't logically reached are excluded by the causal/ring masks, not by
-    table bookkeeping.  No split-KV here: a
+    table bookkeeping.  Latent mode (multi-head latent attention,
+    absorbed): the pool is (n_blocks, block_size, D) entries of one KV
+    head that all H queries share, no V pages; the values are the pages'
+    first ``v_dim`` columns, and the launch is named ``mla_decode``.  No
+    split-KV here: a
     v5e chip has one TensorCore, so splits would fill nothing, and the
     (m, l, acc) partials contract stays for ``return_partials`` alone.
 
@@ -177,7 +181,7 @@ def paged_gather(k, v, kv_pos, k_scale, v_scale, block_tables):
     kv_pos_g = kv_pos_g.reshape(B, T * kv_pos.shape[1])
     ks = g(k_scale) if k_scale is not None else None
     vs = g(v_scale) if v_scale is not None else None
-    return g(k), g(v), kv_pos_g, ks, vs
+    return g(k), g(v) if v is not None else None, kv_pos_g, ks, vs
 
 
 # ---------------------------------------------------------------------------
@@ -332,12 +336,13 @@ def _finish(m, l, acc, G: int, q, return_partials: bool):
 
 @functools.partial(
     jax.jit, static_argnames=("kind", "window", "softcap", "block_kv",
-                              "n_splits", "interpret", "return_partials"))
+                              "n_splits", "interpret", "return_partials",
+                              "scale", "v_dim"))
 def flash_decode(q, k, v, kv_pos, q_pos, *, k_scale=None, v_scale=None,
                  kind: str = "causal", window: int = 0, prefix_len=None,
                  softcap: float = 0.0, block_kv: int = 0, n_splits: int = 0,
                  block_tables=None, interpret: bool = False,
-                 return_partials: bool = False):
+                 return_partials: bool = False, scale=None, v_dim: int = 0):
     """One fused decode step against the ring (or paged) cache.
 
     q: (B, 1, H, D); k, v: (B, S, Hk, D) ring buffers, or — with
@@ -351,13 +356,19 @@ def flash_decode(q, k, v, kv_pos, q_pos, *, k_scale=None, v_scale=None,
     (B, 1, H, D) in q.dtype, or the raw f32 partials (m, l, acc) of shapes
     (B, Hk, G, 1)/(B, Hk, G, 1)/(B, Hk, G, D) when ``return_partials``
     (sequence-sharded combine, repro.dist.decode).
+
+    Latent mode (paged launch only; ``v`` None, ``v_dim`` > 0): the pool
+    is (n_blocks, block_size, D), one KV head shared by all H query heads
+    (latent attention, absorbed); its first ``v_dim`` columns are the
+    values, so the output is (B, 1, H, v_dim).  ``scale`` (default
+    D ** -0.5) multiplies the scores.
     """
     if block_tables is not None:
         return _flash_decode_paged(
             q, k, v, kv_pos, block_tables, q_pos, k_scale=k_scale,
             v_scale=v_scale, kind=kind, window=window, prefix_len=prefix_len,
             softcap=softcap, interpret=interpret,
-            return_partials=return_partials)
+            return_partials=return_partials, scale=scale, v_dim=v_dim)
     B, S, Hk, D = k.shape
     kv_pos = jnp.asarray(kv_pos, jnp.int32)
     if kv_pos.ndim == 1:
@@ -477,16 +488,23 @@ def _place(rows, offs, his, *, bs: int, S: int, fill):
 
 
 def _paged_kernel(qpos_ref, plen_ref, nlive_ref, tbl_ref, q_ref, kp_hbm,
-                  k_hbm, v_hbm, *refs, pages: int, bs: int, kind: str,
+                  k_hbm, *refs, pages: int, bs: int, kind: str,
                   window: int, softcap: float, scale: float, quantized: bool,
-                  qk_dtype, qk_precision):
+                  qk_dtype, qk_precision, v_dim: int):
     """One request row of the paged launch: a loop over the row's live
     steps, each gathering ``pages`` table-named pool pages (all KV heads,
     their slot positions and, for int8 pools, their scales) by DMA,
-    double-buffered so step i+1's pages land while step i computes."""
+    double-buffered so step i+1's pages land while step i computes.
+    ``v_dim`` > 0 is latent mode: one KV head, no V pages, the values the
+    first ``v_dim`` columns of the K pages."""
+    if not v_dim:
+        v_hbm, *refs = refs
     if quantized:
         ks_hbm, vs_hbm, *refs = refs
-    o_m, o_l, o_acc, kbuf, vbuf, kpbuf, *refs = refs
+    o_m, o_l, o_acc, kbuf, *refs = refs
+    if not v_dim:
+        vbuf, *refs = refs
+    kpbuf, *refs = refs
     if quantized:
         ksbuf, vsbuf, *refs = refs
     sem, m_s, l_s, acc_s = refs
@@ -507,7 +525,7 @@ def _paged_kernel(qpos_ref, plen_ref, nlive_ref, tbl_ref, q_ref, kp_hbm,
         return jnp.maximum(tbl, 0), live
 
     def fetch(i, slot):
-        pairs = [(k_hbm, kbuf), (v_hbm, vbuf)]
+        pairs = [(k_hbm, kbuf)] + ([] if v_dim else [(v_hbm, vbuf)])
         rows = [(kp_hbm, kpbuf)]
         if quantized:
             rows += [(ks_hbm, ksbuf), (vs_hbm, vsbuf)]
@@ -556,7 +574,11 @@ def _paged_kernel(qpos_ref, plen_ref, nlive_ref, tbl_ref, q_ref, kp_hbm,
 
         # heads lead for the batched matmuls: (S, Hk, D) -> (Hk, S, D)
         q = q_ref[0].astype(qk_dtype)                 # (Hk, G, D)
-        k = jnp.swapaxes(kbuf[slot], 0, 1).astype(qk_dtype)
+        if v_dim:                                     # latent: (S, D)
+            kv = kbuf[slot][None]
+            k = kv.astype(qk_dtype)
+        else:
+            k = jnp.swapaxes(kbuf[slot], 0, 1).astype(qk_dtype)
         s = jnp.einsum("hgd,hkd->hgk", q, k, precision=qk_precision,
                        preferred_element_type=jnp.float32) * scale
         if quantized:
@@ -574,7 +596,10 @@ def _paged_kernel(qpos_ref, plen_ref, nlive_ref, tbl_ref, q_ref, kp_hbm,
         l_s[...] = l_s[...] * corr + p.sum(-1, keepdims=True)
         if quantized:
             p = p * row(vsbuf, 0)[:, None, :]
-        v = jnp.swapaxes(vbuf[slot], 0, 1).astype(jnp.float32)
+        if v_dim:
+            v = kv[..., :v_dim].astype(jnp.float32)
+        else:
+            v = jnp.swapaxes(vbuf[slot], 0, 1).astype(jnp.float32)
         # p stays f32: HIGHEST keeps the MXU from rounding it to bf16
         acc_s[...] = acc_s[...] * corr + jnp.einsum(
             "hgk,hkd->hgd", p, v, precision=jax.lax.Precision.HIGHEST,
@@ -591,13 +616,19 @@ def _paged_kernel(qpos_ref, plen_ref, nlive_ref, tbl_ref, q_ref, kp_hbm,
 def _flash_decode_paged(q, k, v, kv_pos, block_tables, q_pos, *, k_scale,
                         v_scale, kind: str, window: int, prefix_len,
                         softcap: float, interpret: bool,
-                        return_partials: bool):
+                        return_partials: bool, scale=None, v_dim: int = 0):
     """Paged-pool kernel launch: grid (B,), one program a request row,
     looping over the row's live table entries in steps of
     ``_pages_per_step`` pages.  The pool stays in HBM; the kernel DMAs
     exactly the pages the table names, all KV heads at once, with their
-    slot positions and scales."""
-    nb, bs, Hk, D = k.shape
+    slot positions and scales.  ``v`` None: latent mode (one head, the
+    values the pages' first ``v_dim`` columns)."""
+    latent = v is None
+    if latent:
+        (nb, bs, D), Hk, Dv = k.shape, 1, v_dim
+    else:
+        nb, bs, Hk, D = k.shape
+        Dv = D
     tbl = jnp.asarray(block_tables, jnp.int32)
     B, T = tbl.shape
     pages = _pages_per_step(bs, T)
@@ -612,7 +643,7 @@ def _flash_decode_paged(q, k, v, kv_pos, block_tables, q_pos, *, k_scale,
     qk_precision = None if bf16 else jax.lax.Precision.HIGHEST
 
     kp = _lane_rows(jnp.asarray(kv_pos, jnp.int32)[..., None], -1)
-    hbm = [kp, k, v]
+    hbm = [kp, k] if latent else [kp, k, v]
     W = kp.shape[-1]
     rows = [pltpu.VMEM((2, pages, 1, W), jnp.int32)]
     if quantized:
@@ -622,35 +653,40 @@ def _flash_decode_paged(q, k, v, kv_pos, block_tables, q_pos, *, k_scale,
     def out_spec(last):
         return pl.BlockSpec((1, Hk, G_pad, last), lambda b, *_: (b, 0, 0, 0))
 
+    kv_bufs = ([pltpu.VMEM((2, S, D), k.dtype)] if latent else
+               [pltpu.VMEM((2, S, Hk, D), k.dtype),
+                pltpu.VMEM((2, S, Hk, D), v.dtype)])
     m, l, acc = pl.pallas_call(
         functools.partial(_paged_kernel, pages=pages, bs=bs, kind=kind,
-                          window=window, softcap=softcap, scale=D ** -0.5,
+                          window=window, softcap=softcap,
+                          scale=D ** -0.5 if scale is None else scale,
                           quantized=quantized, qk_dtype=qk_dtype,
-                          qk_precision=qk_precision),
+                          qk_precision=qk_precision, v_dim=v_dim),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(B,),
             in_specs=[pl.BlockSpec((1, Hk, G_pad, D),
                                    lambda b, *_: (b, 0, 0, 0))]
             + [pl.BlockSpec(memory_space=pl.ANY)] * len(hbm),
-            out_specs=[out_spec(1), out_spec(1), out_spec(D)],
+            out_specs=[out_spec(1), out_spec(1), out_spec(Dv)],
             scratch_shapes=[
-                pltpu.VMEM((2, S, Hk, D), k.dtype),
-                pltpu.VMEM((2, S, Hk, D), v.dtype),
+                *kv_bufs,
                 *rows,
                 pltpu.SemaphoreType.DMA((2,)),
                 pltpu.VMEM((Hk, G_pad, 1), jnp.float32),
                 pltpu.VMEM((Hk, G_pad, 1), jnp.float32),
-                pltpu.VMEM((Hk, G_pad, D), jnp.float32),
+                pltpu.VMEM((Hk, G_pad, Dv), jnp.float32),
             ]),
         out_shape=[
             jax.ShapeDtypeStruct((B, Hk, G_pad, 1), jnp.float32),
             jax.ShapeDtypeStruct((B, Hk, G_pad, 1), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hk, G_pad, D), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hk, G_pad, Dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
+        # the name a device trace gives the latent kernel
+        **({"name": "mla_decode"} if latent else {}),
     )(_row_pos(q_pos, B), _row_pos(prefix_len, B),
       live_pages(tbl).astype(jnp.int32), tbl, qg, *hbm)
     return _finish(m[:, :, None], l[:, :, None], acc[:, :, None], G, q,
@@ -709,17 +745,22 @@ def flash_decode_xla(q, k, v, kv_pos, q_pos, *, k_scale=None, v_scale=None,
                      kind: str = "causal", window: int = 0, prefix_len=None,
                      softcap: float = 0.0, block_kv: int = 0,
                      block_tables=None, return_partials: bool = False,
-                     **_unused):
+                     scale=None, v_dim: int = 0, **_unused):
     """Same signature/semantics as ``flash_decode`` without Pallas.
 
     ``block_kv`` <= 0 picks the measured policy: a single-pass wide form up
     to REPRO_DECODE_WIDE_MAX (4096) slots, else a ``lax.scan`` over
     2048-slot tiles with in-block dequant and online softmax — O(block)
     temporaries instead of O(cache_len).  An explicit ``block_kv`` >= S
-    also selects the wide form."""
+    also selects the wide form.  Latent mode (``v`` None, ring or pool of
+    (.., .., D) entries): one KV head, the values its first ``v_dim``
+    columns."""
     if block_tables is not None:
         k, v, kv_pos, k_scale, v_scale = paged_gather(
             k, v, kv_pos, k_scale, v_scale, block_tables)
+    if v is None:
+        k = k[:, :, None]
+        v = k[..., :v_dim]
     B, S, Hk, D = k.shape
     kv_pos = jnp.asarray(kv_pos, jnp.int32)
     if kv_pos.ndim == 1:
@@ -730,14 +771,15 @@ def flash_decode_xla(q, k, v, kv_pos, q_pos, *, k_scale=None, v_scale=None,
         return _decode_wide(q, k, v, kv_pos, q_pos, k_scale=k_scale,
                             v_scale=v_scale, kind=kind, window=window,
                             prefix_len=prefix_len, softcap=softcap,
-                            return_partials=return_partials)
+                            return_partials=return_partials, scale=scale)
     quantized = k_scale is not None
     qg, k, v, kv_pos, k_scale, v_scale, G, _ = _pad_inputs(
         q, k, v, kv_pos, k_scale, v_scale, block_kv)
     qg = qg[:, :, :G].astype(jnp.float32)            # no sublane padding here
     S_pad = k.shape[1]
     nb = S_pad // block_kv
-    scale = D ** -0.5
+    scale = D ** -0.5 if scale is None else scale
+    Dv = v.shape[-1]
     qp = _broadcast_pos(q_pos, B)[:, :, None, None]  # (B, 1, 1, 1)
     plen = _broadcast_pos(prefix_len, B)[:, :, None, None]
 
@@ -774,17 +816,17 @@ def flash_decode_xla(q, k, v, kv_pos, q_pos, *, k_scale=None, v_scale=None,
 
     m0 = jnp.full((B, Hk, G, 1), _NEG, jnp.float32)
     l0 = jnp.zeros((B, Hk, G, 1), jnp.float32)
-    a0 = jnp.zeros((B, Hk, G, D), jnp.float32)
+    a0 = jnp.zeros((B, Hk, G, Dv), jnp.float32)
     (m, l, acc), _ = jax.lax.scan(kv_step, (m0, l0, a0), tuple(blocks))
     if return_partials:
         return m, l, acc
     out = acc / jnp.maximum(l, 1e-30)
-    return out.reshape(B, 1, Hk * G, D).astype(q.dtype)
+    return out.reshape(B, 1, Hk * G, Dv).astype(q.dtype)
 
 
 def _decode_wide(q, k, v, kv_pos, q_pos, *, k_scale, v_scale, kind: str,
                  window: int, prefix_len, softcap: float,
-                 return_partials: bool):
+                 return_partials: bool, scale=None):
     """Single-pass short-context form: the int8 codes are transposed to
     (B, Hk, S, D) BEFORE dequant (1-byte traffic instead of the 4-byte
     transpose XLA would insert after), then one masked-softmax pass — the
@@ -802,7 +844,8 @@ def _decode_wide(q, k, v, kv_pos, q_pos, *, k_scale, v_scale, kind: str,
     else:
         kf, vf = kt.astype(jnp.float32), vt.astype(jnp.float32)
     s = jnp.einsum("bhgd,bhkd->bhgk", qg, kf,
-                   preferred_element_type=jnp.float32) * D ** -0.5
+                   preferred_element_type=jnp.float32) * (
+                       D ** -0.5 if scale is None else scale)
     if softcap > 0:
         s = softcap * jnp.tanh(s / softcap)
     qp = _broadcast_pos(q_pos, B).reshape(B, 1, 1, 1)
@@ -818,4 +861,4 @@ def _decode_wide(q, k, v, kv_pos, q_pos, *, k_scale, v_scale, kind: str,
     if return_partials:
         return m, l, acc
     out = acc / jnp.maximum(l, 1e-30)
-    return out.reshape(B, 1, Hk * G, D).astype(q.dtype)
+    return out.reshape(B, 1, Hk * G, vf.shape[-1]).astype(q.dtype)

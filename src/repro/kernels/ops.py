@@ -109,15 +109,17 @@ def _short_cache_xla(cache_len: int) -> bool:
 
 
 def flash_decode(q, k, v, kv_pos, q_pos, **kw):
-    """One decode step over the ring or paged cache; see
-    ``repro.kernels.flash_decode`` for signature and semantics, and
-    ``decode_mode`` for which path a cache length takes."""
+    """One decode step over the ring or paged cache (``v`` None: a latent
+    cache); see ``repro.kernels.flash_decode`` for signature and semantics,
+    and ``decode_mode`` for which path a cache length takes."""
     with jax.named_scope("obs.flash_decode"):
         if use_kernels():
             tbl = kw.get("block_tables")
             s_logical = (tbl.shape[1] * k.shape[1] if tbl is not None
                          else k.shape[1])
-            if _short_cache_xla(s_logical):
+            # latent rings (v None, no table): the ring launch has no
+            # latent mode
+            if _short_cache_xla(s_logical) or (v is None and tbl is None):
                 return _flash_decode_xla(q, k, v, kv_pos, q_pos, **kw)
             return _flash_decode(q, k, v, kv_pos, q_pos,
                                  interpret=not on_tpu(), **kw)

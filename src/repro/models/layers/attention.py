@@ -1,6 +1,8 @@
 """Attention: GQA + qk-norm + logit softcap + sliding window + prefix-LM,
 with a memory-bounded blockwise (online-softmax) path for long sequences and
-a ring-buffer KV cache for decode.
+a ring-buffer KV cache for decode; and DeepSeek-V2's multi-head latent
+attention (YaRN RoPE, one latent entry a position in the cache, absorbed
+decode through the paged kernel's latent mode).
 
 Position-based masking: every mask is derived from absolute positions of the
 query rows (``q_pos``) and of the KV slots (``kv_pos``); a slot with position
@@ -11,11 +13,13 @@ prefill, sliding-window decode and prefix-LM uniformly.
 from __future__ import annotations
 
 import functools
+import math
 import os
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.models.layers.embeddings import apply_rope
 from repro.models.layers.linear import dense, init_dense
@@ -94,10 +98,11 @@ def _scores(q, k, scale: float, softcap: float) -> jnp.ndarray:
 def sdpa(q, k, v, *, q_pos, kv_pos, kind: str = "causal", window: int = 0,
          prefix_len=None, softcap: float = 0.0,
          block_q: int = 0, block_kv: int = 0,
-         k_scale=None, v_scale=None) -> jnp.ndarray:
+         k_scale=None, v_scale=None, scale=None) -> jnp.ndarray:
     """General SDPA.
 
-    q: (B, Sq, H, D); k, v: (B, Skv, Hk, D); returns (B, Sq, H, D).
+    q: (B, Sq, H, D); k: (B, Skv, Hk, D); v: (B, Skv, Hk, Dv); returns
+    (B, Sq, H, Dv).  ``scale`` defaults to D ** -0.5.
     ``block_q``/``block_kv`` > 0 selects the memory-bounded blockwise path
     (required for 32k+ sequences; see DESIGN.md §3).  Ragged lengths are
     handled by padding the tail block with invalid (position -1) slots.
@@ -107,9 +112,9 @@ def sdpa(q, k, v, *, q_pos, kv_pos, kind: str = "causal", window: int = 0,
     the scan, so the full cache is never materialized at compute precision.
     """
     B, Sq, H, D = q.shape
-    Hk = k.shape[2]
+    Hk, Dv = k.shape[2], v.shape[-1]
     G = H // Hk
-    scale = D ** -0.5
+    scale = D ** -0.5 if scale is None else scale
     q_pos = _as_b(q_pos, B)
     kv_pos = _as_b(kv_pos, B)
     quantized = k_scale is not None
@@ -127,7 +132,7 @@ def sdpa(q, k, v, *, q_pos, kv_pos, kind: str = "causal", window: int = 0,
         # fully-masked rows produce uniform garbage; zero them via the mask
         p = jnp.where(m.any(-1, keepdims=True), p, 0.0).astype(q.dtype)
         o = jnp.einsum("bhgqk,bkhd->bqhgd", p, v)
-        return o.reshape(B, Sq, H, D)
+        return o.reshape(B, Sq, H, Dv)
 
     # ---- blockwise path: outer map over Q blocks, inner scan over KV ----
     # ragged tails are padded: KV slots with position -1 (masked invalid),
@@ -152,7 +157,7 @@ def sdpa(q, k, v, *, q_pos, kv_pos, kind: str = "causal", window: int = 0,
     qg = q.reshape(B, Sq_pad, Hk, G, D)
 
     k_blocks = k.reshape(B, nk, block_kv, Hk, D)
-    v_blocks = v.reshape(B, nk, block_kv, Hk, D)
+    v_blocks = v.reshape(B, nk, block_kv, Hk, Dv)
     kp_blocks = kv_pos.reshape(B, nk, block_kv)
     if quantized:
         ks_blocks = k_scale.reshape(B, nk, block_kv, Hk, 1)
@@ -183,7 +188,7 @@ def sdpa(q, k, v, *, q_pos, kv_pos, kind: str = "causal", window: int = 0,
 
         m0 = jnp.full((B, Hk, G, block_q), _NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, Hk, G, block_q), jnp.float32)
-        a0 = jnp.zeros((B, Hk, G, block_q, D), jnp.float32)
+        a0 = jnp.zeros((B, Hk, G, block_q, Dv), jnp.float32)
         xs = [k_blocks.transpose(1, 0, 2, 3, 4),
               v_blocks.transpose(1, 0, 2, 3, 4),
               kp_blocks.transpose(1, 0, 2)]
@@ -197,9 +202,9 @@ def sdpa(q, k, v, *, q_pos, kv_pos, kind: str = "causal", window: int = 0,
     qg_blocks = qg.reshape(B, nq, block_q, Hk, G, D).transpose(1, 0, 2, 3, 4, 5)
     qp_blocks = q_pos.reshape(B, nq, block_q).transpose(1, 0, 2)
     outs = jax.lax.map(one_q_block, (qg_blocks, qp_blocks))
-    # outs: (nq, B, Hk, G, block_q, D) -> (B, nq·block_q, Hk, G, D)
-    o = outs.transpose(1, 0, 4, 2, 3, 5).reshape(B, Sq_pad, Hk, G, D)
-    return o.reshape(B, Sq_pad, H, D)[:, :Sq].astype(q.dtype)
+    # outs: (nq, B, Hk, G, block_q, Dv) -> (B, nq·block_q, Hk, G, Dv)
+    o = outs.transpose(1, 0, 4, 2, 3, 5).reshape(B, Sq_pad, Hk, G, Dv)
+    return o.reshape(B, Sq_pad, H, Dv)[:, :Sq].astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -274,6 +279,14 @@ def init_attn_cache(batch: int, cache_len: int, num_kv_heads: int, head_dim: int
     }
 
 
+def map_kv_pos(fn, cache):
+    """``fn`` applied to every ``kv_pos`` leaf of a cache pytree (rings and
+    paged pools of any layout: GQA or latent, stacked or nested)."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, x: fn(x) if getattr(path[-1], "key", None) == "kv_pos"
+        else x, cache)
+
+
 def _quant_kv(x):
     """(B, S, Hk, dh) -> (int8 codes, bf16 scales (B,S,Hk,1))."""
     amax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1, keepdims=True)
@@ -293,6 +306,64 @@ def _decode_block_kv() -> int:
     across KV splits).  Read per call so REPRO_DECODE_BLOCK_KV behaves like
     every other REPRO_ flag."""
     return int(os.environ.get("REPRO_DECODE_BLOCK_KV", "0"))
+
+
+def _slot_writers(kv_pos, pos, B: int, *, block_tbl=None, ring_len=None):
+    """(upd, upd_pos): write one new entry a row into a cache leaf, and the
+    row positions into ``kv_pos``.  ``pos`` is a scalar (synchronous batch)
+    or (B,) per-row positions, -1 for an inactive row.
+
+    Paged pools (``block_tbl`` (B, T) + ``ring_len``): leaves are (n_blocks,
+    block_size, ...) and a row's ring slot ``pos % ring_len`` resolves
+    through its table row.  Writes scatter with ``mode="drop"``: inactive
+    rows and ungranted blocks index out of bounds and write nothing.
+    Rings: leaves are (B, cache_len, ...); an inactive ragged row keeps its
+    old slot contents."""
+    if block_tbl is not None:
+        n_blocks, bs = kv_pos.shape[:2]
+        active = pos >= 0
+        rl = jnp.asarray(ring_len, jnp.int32)
+        slot = jnp.mod(jnp.maximum(pos, 0), rl)             # (B,) ring slot
+        pb = block_tbl[jnp.arange(B), slot // bs]           # physical block
+        off = slot % bs
+        # out-of-bounds index == dropped write (inactive / ungranted rows)
+        widx = jnp.where(active & (pb >= 0), pb, n_blocks)
+
+        def upd(buf, val):
+            return buf.at[widx, off].set(val[:, 0].astype(buf.dtype),
+                                         mode="drop")
+
+        def upd_pos(buf):
+            return buf.at[widx, off].set(pos, mode="drop")
+        return upd, upd_pos
+    cache_len = kv_pos.shape[1]
+    if pos.ndim == 1:
+        # per-row ring slot: every row writes its own slot; inactive rows
+        # (pos < 0) keep the old slot contents and stay fully masked
+        active = pos >= 0
+        slots = jnp.mod(jnp.maximum(pos, 0), cache_len)        # (B,)
+        bidx = jnp.arange(B)
+
+        def upd(buf, val):
+            old = buf[bidx, slots]                             # (B, ...)
+            keep = active.reshape((B,) + (1,) * (old.ndim - 1))
+            return buf.at[bidx, slots].set(
+                jnp.where(keep, val[:, 0].astype(buf.dtype), old))
+
+        def upd_pos(buf):
+            old = buf[bidx, slots]
+            return buf.at[bidx, slots].set(jnp.where(active, pos, old))
+        return upd, upd_pos
+    slot = jnp.mod(pos, cache_len)
+
+    def upd(buf, val):
+        return jax.lax.dynamic_update_slice_in_dim(
+            buf, val.astype(buf.dtype), slot, axis=1)
+
+    def upd_pos(buf):
+        return jax.lax.dynamic_update_slice_in_dim(
+            buf, jnp.full((B, 1), pos, jnp.int32), slot, axis=1)
+    return upd, upd_pos
 
 
 def attn_decode(params, cfg, x_t, cache, pos, *, window: int = 0,
@@ -340,49 +411,10 @@ def attn_decode(params, cfg, x_t, cache, pos, *, window: int = 0,
     q, k_t, v_t = _project_qkv(
         params, cfg, x_t, None,
         positions=pos_b, kv_positions=pos_b, use_rope=True)
-
+    upd, upd_pos = _slot_writers(cache["kv_pos"], pos, B,
+                                 block_tbl=block_tbl, ring_len=ring_len)
     if paged:
-        n_blocks, bs = cache["k"].shape[:2]
-        active = pos >= 0
-        rl = jnp.asarray(ring_len, jnp.int32)
-        slot = jnp.mod(jnp.maximum(pos, 0), rl)             # (B,) ring slot
-        pb = block_tbl[jnp.arange(B), slot // bs]           # physical block
-        off = slot % bs
-        # out-of-bounds index == dropped write (inactive / ungranted rows)
-        widx = jnp.where(active & (pb >= 0), pb, n_blocks)
-
-        def upd(buf, val):
-            return buf.at[widx, off].set(val[:, 0].astype(buf.dtype),
-                                         mode="drop")
-
-        def upd_pos(buf):
-            return buf.at[widx, off].set(pos, mode="drop")
-    elif ragged:
-        # per-row ring slot: every row writes its own slot; inactive rows
-        # (pos < 0) keep the old slot contents and stay fully masked below
-        active = pos >= 0
-        slots = jnp.mod(jnp.maximum(pos, 0), cache_len)        # (B,)
-        bidx = jnp.arange(B)
-
-        def upd(buf, val):
-            old = buf[bidx, slots]                             # (B, ...)
-            keep = active.reshape((B,) + (1,) * (old.ndim - 1))
-            return buf.at[bidx, slots].set(
-                jnp.where(keep, val[:, 0].astype(buf.dtype), old))
-
-        def upd_pos(buf):
-            old = buf[bidx, slots]
-            return buf.at[bidx, slots].set(jnp.where(active, pos, old))
-    else:
-        slot = jnp.mod(pos, cache_len)
-
-        def upd(buf, val):
-            return jax.lax.dynamic_update_slice_in_dim(
-                buf, val.astype(buf.dtype), slot, axis=1)
-
-        def upd_pos(buf):
-            return jax.lax.dynamic_update_slice_in_dim(
-                buf, jnp.full((B, 1), pos, jnp.int32), slot, axis=1)
+        n_blocks = cache["k"].shape[0]
 
     new_cache = {}
     if int8:
@@ -458,3 +490,185 @@ def attn_cross_decode(params, cfg, x_t, mem_k, mem_v, mem_pos):
              q_pos=jnp.zeros((B, 1), jnp.int32), kv_pos=mem_pos,
              kind="full", softcap=cfg.attn_logit_softcap)
     return dense(params["wo"], o.reshape(B, 1, -1))
+
+
+# ---------------------------------------------------------------------------
+# Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434)
+# ---------------------------------------------------------------------------
+
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, mla) -> np.ndarray:
+    """Rotary frequencies of a ``dim``-wide rotary part: plain RoPE, or
+    YaRN's blend (DeepSeek-V2's ``DeepseekV2YarnRotaryEmbedding``) when
+    ``mla.rope_factor`` > 1 — frequencies past the correction range of
+    ``beta_fast``..``beta_slow`` rotations over the original context are
+    divided by the factor, those before it kept, a linear ramp between."""
+    extra = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim)
+    f = mla.rope_factor
+    if f <= 1:
+        return extra
+    inter = 1.0 / (f * theta ** (np.arange(0, dim, 2, dtype=np.float32)
+                                 / dim))
+
+    def corr(rotations):
+        return (dim * math.log(mla.original_max_position
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr(mla.beta_fast)), 0)
+    high = min(math.ceil(corr(mla.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0, 1)
+    keep = 1.0 - ramp
+    return (inter * (1 - keep) + extra * keep).astype(np.float32)
+
+
+def mla_softmax_scale(cfg) -> float:
+    """q_head_dim ** -0.5, times YaRN's mscale(mscale_all_dim) squared."""
+    m = cfg.mla
+    s = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+    if m.mscale_all_dim:
+        s *= _yarn_mscale(m.rope_factor, m.mscale_all_dim) ** 2
+    return s
+
+
+def rope_pairs(x, positions, cfg):
+    """RoPE on interleaved pairs, the published DeepSeek-V2 layout: pair
+    (x[2i], x[2i+1]) turns by ``position * inv_freq[i]``.  x: (B, S, H, D);
+    positions (B, S).  The result keeps the pair layout (the published
+    code de-interleaves first; scores, the only use, are the same)."""
+    m = cfg.mla
+    D = x.shape[-1]
+    inv = jnp.asarray(yarn_inv_freq(D, cfg.rope_theta, m))
+    mscale = (_yarn_mscale(m.rope_factor, m.mscale)
+              / _yarn_mscale(m.rope_factor, m.mscale_all_dim))
+    ang = positions[..., None, None].astype(jnp.float32) * inv  # (B,S,1,D/2)
+    cos, sin = jnp.cos(ang) * mscale, jnp.sin(ang) * mscale
+    xp = x.astype(jnp.float32).reshape(x.shape[:-1] + (D // 2, 2))
+    x0, x1 = xp[..., 0], xp[..., 1]
+    out = jnp.stack([x0 * cos - x1 * sin, x1 * cos + x0 * sin], -1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def init_mla(key, cfg, dtype=jnp.float32):
+    """q, kv_a (latent + rope key), the latent's RMSNorm, kv_b, o."""
+    m, H, d = cfg.mla, cfg.num_heads, cfg.d_model
+    qd = m.qk_nope_head_dim + m.qk_rope_head_dim
+    kq, ka, kb, ko = jax.random.split(key, 4)
+    return {
+        "wq": init_dense(kq, d, H * qd, dtype),
+        "wkv_a": init_dense(ka, d, m.entry_dim, dtype),
+        "kv_norm": init_rmsnorm(m.kv_lora_rank),
+        "wkv_b": init_dense(kb, m.kv_lora_rank,
+                            H * (m.qk_nope_head_dim + m.v_head_dim), dtype),
+        "wo": init_dense(ko, H * m.v_head_dim, d, dtype),
+    }
+
+
+def init_mla_cache(batch: int, cache_len: int, cache_dim: int,
+                   dtype=jnp.bfloat16):
+    """One latent entry a position (normed latent, roped key, zero pad to
+    ``MLAConfig.cache_dim``), no separate V; as a paged pool ``batch`` is
+    the block count and ``cache_len`` the block size."""
+    return {
+        "latent": jnp.zeros((batch, cache_len, cache_dim), dtype),
+        "kv_pos": jnp.full((batch, cache_len), -1, jnp.int32),
+    }
+
+
+def _mla_query(params, cfg, x, positions):
+    """(q_nope (B,S,H,nope), roped q_pe (B,S,H,rope))."""
+    m = cfg.mla
+    B, S = x.shape[:2]
+    q = dense(params["wq"], x).reshape(
+        B, S, cfg.num_heads, m.qk_nope_head_dim + m.qk_rope_head_dim)
+    return (q[..., :m.qk_nope_head_dim],
+            rope_pairs(q[..., m.qk_nope_head_dim:], positions, cfg))
+
+
+def _mla_entry(params, cfg, x, positions):
+    """The cache entry (B, S, cache_dim): the RMS-normed latent, the roped
+    key every head shares, zeros."""
+    m = cfg.mla
+    r = m.kv_lora_rank
+    ckv = dense(params["wkv_a"], x)
+    c = rmsnorm(params["kv_norm"], ckv[..., :r], cfg.norm_eps)
+    kr = rope_pairs(ckv[..., None, r:], positions, cfg)[..., 0, :]
+    pad = jnp.zeros(c.shape[:-1] + (m.cache_dim - m.entry_dim,), c.dtype)
+    return jnp.concatenate([c, kr.astype(c.dtype), pad], -1)
+
+
+def mla_attention(params, cfg, x, *, positions, window: int = 0,
+                  block_q: int = 0, block_kv: int = 0):
+    """Causal latent attention over a whole sequence, not absorbed: each
+    head's key is [kv_b's k_nope of the latent, the shared roped key], its
+    value kv_b's v.  x: (B, S, d) -> (y (B, S, d), cache entries (B, S,
+    rank + rope))."""
+    with jax.named_scope("obs.mla"):
+        m, H = cfg.mla, cfg.num_heads
+        B, S = x.shape[:2]
+        pos = _as_b(positions, B)
+        q_nope, q_pe = _mla_query(params, cfg, x, pos)
+        entry = _mla_entry(params, cfg, x, pos)
+        r, nope = m.kv_lora_rank, m.qk_nope_head_dim
+        kv = dense(params["wkv_b"], entry[..., :r]).reshape(
+            B, S, H, nope + m.v_head_dim)
+        k_pe = jnp.broadcast_to(entry[..., None, r:m.entry_dim],
+                                (B, S, H, m.qk_rope_head_dim))
+        q = jnp.concatenate([q_nope, q_pe.astype(q_nope.dtype)], -1)
+        k = jnp.concatenate([kv[..., :nope], k_pe.astype(kv.dtype)], -1)
+        o = sdpa(q, k, kv[..., nope:], q_pos=pos, kv_pos=pos, kind="causal",
+                 window=window, block_q=block_q, block_kv=block_kv,
+                 scale=mla_softmax_scale(cfg))
+        return dense(params["wo"], o.reshape(B, S, -1)), entry
+
+
+def mla_decode(params, cfg, x_t, cache, pos, *, window: int = 0,
+               block_tbl=None, ring_len=None):
+    """One decode step of latent attention, absorbed: kv_b's key half folds
+    into the query (q_nope W_UK gives a ``kv_lora_rank``-wide latent query
+    beside the roped one), so every head scores the cached entries
+    directly; the output is p.c, then kv_b's value half W_UV, then o.
+    ``cache``: ``init_mla_cache`` leaves, a ring or (``block_tbl`` +
+    ``ring_len``) a paged pool; positions and writes as ``attn_decode``.
+    Returns (y_t, new_cache)."""
+    from repro.kernels import ops
+    with jax.named_scope("obs.mla"):
+        m, H = cfg.mla, cfg.num_heads
+        B = x_t.shape[0]
+        pos = jnp.asarray(pos, jnp.int32)
+        if block_tbl is not None and pos.ndim != 1:
+            raise ValueError("paged decode requires per-row (B,) positions")
+        pos_b = (pos[:, None] if pos.ndim == 1
+                 else jnp.full((B, 1), pos, jnp.int32))
+        q_nope, q_pe = _mla_query(params, cfg, x_t, pos_b)
+        upd, upd_pos = _slot_writers(cache["kv_pos"], pos, B,
+                                     block_tbl=block_tbl, ring_len=ring_len)
+        new_cache = {
+            "latent": upd(cache["latent"],
+                          _mla_entry(params, cfg, x_t, pos_b)),
+            "kv_pos": upd_pos(cache["kv_pos"])}
+        r, nope = m.kv_lora_rank, m.qk_nope_head_dim
+        dtype = q_nope.dtype
+        w_b = params["wkv_b"]["w"].reshape(r, H, nope + m.v_head_dim)
+        q_lat = jnp.einsum("bshn,rhn->bshr", q_nope,
+                           w_b[..., :nope].astype(dtype),
+                           preferred_element_type=jnp.float32)
+        # scored against whole entries: the zero pad meets zeros
+        q = jnp.concatenate([q_lat.astype(dtype), q_pe.astype(dtype),
+                             jnp.zeros(q_pe.shape[:-1]
+                                       + (m.cache_dim - m.entry_dim,),
+                                       dtype)], -1)
+        kw = {"block_tables": block_tbl} if block_tbl is not None else {}
+        o_lat = ops.flash_decode(q, new_cache["latent"], None,
+                                 new_cache["kv_pos"], pos, window=window,
+                                 scale=mla_softmax_scale(cfg), v_dim=r, **kw)
+        o = jnp.einsum("bshr,rhv->bshv", o_lat,
+                       w_b[..., nope:].astype(dtype),
+                       preferred_element_type=jnp.float32).astype(dtype)
+        return dense(params["wo"], o.reshape(B, 1, -1)), new_cache

@@ -1,15 +1,24 @@
-"""Mixture-of-Experts block: top-k router + capacity-based dispatch.
+"""Mixture-of-Experts block: a top-k router over all experts, and two
+dispatches of the tokens to the experts this chip holds.
 
-Dispatch is GShard-style (one-hot dispatch/combine einsums with per-group
-token capacity) so that compiled FLOPs reflect the *routed* compute
-(top-k / E of dense), which is what the roofline analysis must see — a
-"compute every expert densely and mask" implementation would overstate MoE
-FLOPs by E/k.
+* ``moe_block`` (the training loss): GShard-style capacity dispatch
+  (one-hot dispatch/combine einsums with per-group token capacity), so that
+  compiled FLOPs reflect the *routed* compute (top-k / E of dense); tokens
+  over an expert's capacity get no routed contribution.
+* ``moe_serve`` (prefill and decode): dropless — every (token, expert)
+  assignment is sorted by expert and the held experts run as grouped
+  matmuls (``jax.lax.ragged_dot``), so no token is ever dropped.
+
+Expert parallelism: the layer is told which experts it holds, ids
+``[expert_offset, expert_offset + experts_held)`` of the router's
+``num_experts``.  It routes over all of them and adds only its own experts'
+share (plus the shared experts, which every chip computes alike); the
+absent experts' share is another chip's.  The capacity dispatch needs every
+expert held.
 
 Sharding note (DESIGN.md §5): expert weights are (E, d, d_ff) arrays; the
 baseline shards d_ff over the ``model`` axis (tensor-parallel experts) since
 the assigned expert counts (60, 8) do not divide the 16-way model axis.
-Expert-parallel + all-to-all is a §Perf variant.
 """
 
 from __future__ import annotations
@@ -22,12 +31,14 @@ from repro.models.layers.mlp import init_mlp, mlp
 
 
 def init_moe(key, cfg: ModelConfig, dtype=jnp.float32):
+    """Router over all ``num_experts``; weights of the held experts."""
     m = cfg.moe
-    d, f, e = cfg.d_model, m.expert_d_ff or cfg.d_ff, m.num_experts
+    d, f, e = cfg.d_model, m.expert_d_ff or cfg.d_ff, m.held
     kr, kg, ku, kd, ks = jax.random.split(key, 5)
     s_in, s_ff = d ** -0.5, f ** -0.5
     p = {
-        "router": {"w": (jax.random.normal(kr, (d, e)) * s_in).astype(jnp.float32)},
+        "router": {"w": (jax.random.normal(kr, (d, m.num_experts))
+                         * s_in).astype(jnp.float32)},
         # routed experts: stacked (E, d, f) / (E, f, d)
         "gate_proj": (jax.random.normal(kg, (e, d, f)) * s_in).astype(dtype),
         "up_proj": (jax.random.normal(ku, (e, d, f)) * s_in).astype(dtype),
@@ -50,6 +61,9 @@ def moe_block(params, cfg: ModelConfig, x: jnp.ndarray, *,
     """x: (B, S, d) -> (y, aux_loss). Capacity-dropped tokens fall through
     with zero routed contribution (shared experts / residual still apply)."""
     m = cfg.moe
+    if m.held != m.num_experts:
+        raise ValueError("capacity dispatch needs every expert held; "
+                         "serving takes moe_serve")
     B, S, d = x.shape
     T = B * S
     g = min(group_size, T)
@@ -62,8 +76,9 @@ def moe_block(params, cfg: ModelConfig, x: jnp.ndarray, *,
                         params["router"]["w"])
     probs = jax.nn.softmax(logits, axis=-1)                 # (G,g,E)
     gate_vals, expert_idx = jax.lax.top_k(probs, m.top_k)   # (G,g,k)
-    gate_vals = gate_vals / jnp.maximum(
-        gate_vals.sum(-1, keepdims=True), 1e-9)             # renormalize top-k
+    if m.norm_topk_prob:
+        gate_vals = gate_vals / jnp.maximum(
+            gate_vals.sum(-1, keepdims=True), 1e-9)
 
     # position of each (token, k) assignment inside its expert's buffer
     onehot = jax.nn.one_hot(expert_idx, m.num_experts, dtype=jnp.int32)  # (G,g,k,E)
@@ -107,24 +122,7 @@ def moe_block(params, cfg: ModelConfig, x: jnp.ndarray, *,
                                 params["down_proj"].astype(x.dtype))
         return jnp.einsum("Ggec,Gecd->Ggd", comb, expert_out)
 
-    # Slab-scanned expert compute (REFUTED §Perf hypothesis: the scan blocks
-    # SPMD propagation into the body — 6.8x FLOPs, worse memory. Kept
-    # opt-in for single-host use; default off.)
-    import os
-    want = int(os.environ.get("REPRO_MOE_SLABS", "1"))
-    n_slabs = want if want > 1 and nG % max(want, 1) == 0 else 1
-    if n_slabs > 1:
-        slab = nG // n_slabs
-        def body(_, args):
-            return None, expert_compute(*args)
-        _, ys = jax.lax.scan(
-            body, None,
-            (dispatch.reshape(n_slabs, slab, g, m.num_experts, C),
-             combine.reshape(n_slabs, slab, g, m.num_experts, C),
-             xt.reshape(n_slabs, slab, g, d)))
-        y = ys.reshape(nG, g, d)
-    else:
-        y = expert_compute(dispatch, combine, xt)
+    y = expert_compute(dispatch, combine, xt)
     y = y.reshape(B, S, d)
 
     if "shared" in params:
@@ -138,3 +136,60 @@ def moe_block(params, cfg: ModelConfig, x: jnp.ndarray, *,
     aux = m.num_experts * jnp.sum(frac_tokens * frac_probs) \
         * m.router_aux_loss_coef
     return y, aux
+
+
+def _route(params, cfg: ModelConfig, x):
+    """Softmax over all experts' logits, greedy top-k: (gates, expert
+    ids), each (T, k); gates renormalised when ``norm_topk_prob``.  The
+    router runs in float32 at full precision (a default-precision float32
+    matmul on the MXU rounds its operands to bf16, which reorders close
+    experts)."""
+    m = cfg.moe
+    logits = jnp.dot(x.astype(jnp.float32), params["router"]["w"],
+                     precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gates, idx = jax.lax.top_k(probs, m.top_k)
+    if m.norm_topk_prob:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    return gates, idx
+
+
+def moe_serve(params, cfg: ModelConfig, x):
+    """Dropless MoE for prefill and decode: x (B, S, d) -> y (B, S, d).
+
+    The (token, expert) assignments are sorted by expert; those of the held
+    experts come first, grouped, and run through the experts' SwiGLU as
+    grouped matmuls over exactly their rows; the rest (other chips'
+    experts) sort last and add nothing.  Each output row is weighted by
+    its gate and summed back to its token, then the shared experts add."""
+    if cfg.activation != "swiglu":
+        raise ValueError("moe_serve runs SwiGLU experts")
+    with jax.named_scope("obs.moe"):
+        m = cfg.moe
+        B, S, d = x.shape
+        xt = x.reshape(B * S, d)
+        gates, idx = _route(params, cfg, xt)
+        n, k = m.held, m.top_k
+        local = idx - m.expert_offset
+        mine = (local >= 0) & (local < n)
+        e = jnp.where(mine, local, n).reshape(-1)       # n: not held here
+        order = jnp.argsort(e, stable=True)
+        sizes = jnp.bincount(e, length=n + 1)[:n].astype(jnp.int32)
+        rows = xt[order // k]                           # (T*k, d)
+
+        def grouped(h, w):
+            return jax.lax.ragged_dot(h, w.astype(x.dtype), sizes)
+
+        h = (jax.nn.silu(grouped(rows, params["gate_proj"]))
+             * grouped(rows, params["up_proj"]))
+        out = grouped(h, params["down_proj"])           # (T*k, d) by expert
+        held_row = jnp.arange(out.shape[0]) < sizes.sum()
+        w = jnp.where(held_row, gates.reshape(-1)[order], 0.0)
+        out = jnp.where(held_row[:, None], out, 0).astype(jnp.float32) \
+            * w[:, None]
+        inv = jnp.argsort(order)                        # back to (token, k)
+        y = out[inv].reshape(B * S, k, d).sum(1).astype(x.dtype)
+        y = y.reshape(B, S, d)
+        if "shared" in params:
+            y = y + mlp(params["shared"], x, cfg.activation)
+        return y

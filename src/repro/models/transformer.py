@@ -19,7 +19,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models.layers.attention import (
-    attention, attn_decode, init_attention, init_attn_cache)
+    attention, attn_decode, init_attention, init_attn_cache, map_kv_pos)
 from repro.models.layers.embeddings import embed, init_embedding, unembed
 from repro.models.layers.linear import dense, init_dense
 from repro.models.layers.mlp import init_mlp, mlp
@@ -256,15 +256,13 @@ def decode_step(params, cfg: ModelConfig, cache, token, pos, *,
 # Prefill: full forward capturing KV into ring caches + last-token logits
 # ---------------------------------------------------------------------------
 
-def _scatter_ring(k, v, positions, cache_len):
-    """k,v: (B,S,Hk,dh) post-RoPE -> ring cache of cache_len slots holding
-    the last ``cache_len`` positions (int8-quantized when REPRO_KV_INT8)."""
-    from repro.models.layers.attention import _quant_kv, kv_cache_int8
-    S = k.shape[1]
-    take = min(S, cache_len)
+def ring_scatter(leaves: dict, positions, cache_len):
+    """Named (B, S, ...) leaves -> ring caches of ``cache_len`` slots
+    holding the last ``cache_len`` positions, with their ``kv_pos``."""
+    take = min(positions.shape[0], cache_len)
     pos_tail = positions[-take:]
     slots = jnp.mod(pos_tail, cache_len)
-    B = k.shape[0]
+    B = next(iter(leaves.values())).shape[0]
 
     def scatter(val):
         return jnp.zeros((B, cache_len) + val.shape[2:], val.dtype).at[
@@ -272,13 +270,19 @@ def _scatter_ring(k, v, positions, cache_len):
 
     cp = jnp.full((B, cache_len), -1, jnp.int32).at[:, slots].set(
         jnp.broadcast_to(pos_tail[None], (B, take)))
+    return {**{n: scatter(x) for n, x in leaves.items()}, "kv_pos": cp}
+
+
+def _scatter_ring(k, v, positions, cache_len):
+    """k,v: (B,S,Hk,dh) post-RoPE -> ring cache of cache_len slots holding
+    the last ``cache_len`` positions (int8-quantized when REPRO_KV_INT8)."""
+    from repro.models.layers.attention import _quant_kv, kv_cache_int8
     if kv_cache_int8():
         kq, ks = _quant_kv(k)
         vq, vs = _quant_kv(v)
-        return {"k": scatter(kq), "v": scatter(vq),
-                "k_scale": scatter(ks), "v_scale": scatter(vs),
-                "kv_pos": cp}
-    return {"k": scatter(k), "v": scatter(v), "kv_pos": cp}
+        return ring_scatter({"k": kq, "v": vq, "k_scale": ks,
+                             "v_scale": vs}, positions, cache_len)
+    return ring_scatter({"k": k, "v": v}, positions, cache_len)
 
 
 def _finalize_prefill(params, cfg: ModelConfig, x, cache, true_len):
@@ -294,16 +298,10 @@ def _finalize_prefill(params, cfg: ModelConfig, x, cache, true_len):
     tl = jnp.broadcast_to(jnp.asarray(true_len, jnp.int32).reshape(-1), (B,))
     last = x[jnp.arange(B), jnp.clip(tl - 1, 0, S - 1)][:, None, :]
 
-    def drop_pad(c):
-        # kv_pos: (L, B, cache_len) — pad slots carry positions >= true_len
-        return {**c, "kv_pos": jnp.where(c["kv_pos"] >= tl[None, :, None],
-                                         -1, c["kv_pos"])}
-
-    if isinstance(cache, dict) and "local" in cache:
-        cache = {"local": drop_pad(cache["local"]),
-                 "global": drop_pad(cache["global"])}
-    else:
-        cache = drop_pad(cache)
+    # kv_pos leaves: (L, B, cache_len) — pad slots carry positions >=
+    # true_len
+    cache = map_kv_pos(
+        lambda kp: jnp.where(kp >= tl[None, :, None], -1, kp), cache)
     return cache, logits_fn(params, cfg, last)
 
 

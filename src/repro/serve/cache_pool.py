@@ -10,7 +10,9 @@ every servable family (attention rings AND SSM/hybrid state, bf16 and int8,
 
 ``PagedCachePool`` is the HBM-efficient layout for uniform attention-ring
 families (dense/moe without local/global alternation): ONE donated block
-pool of shape ``(L, n_blocks, block_size, Hk, dh)`` plus a host-side block
+pool in the model's own cache layout with the block count for its batch —
+``(L, n_blocks, block_size, Hk, dh)`` K and V, or for latent attention one
+``(L, n_blocks, block_size, rank + rope)`` entry leaf — plus a host-side block
 table ``(num_slots, blocks_per_slot)`` mapping each lane's logical ring
 blocks to physical pool blocks.  A lane only holds the blocks its tokens
 actually occupy — short requests stop reserving a full ``cache_len`` lane,
@@ -63,6 +65,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.flash_decode import live_pages
+from repro.models.layers.attention import map_kv_pos
 
 PAGED_FAMILIES = ("dense", "moe")
 
@@ -341,7 +344,7 @@ class PagedCachePool(_LanePool):
                 f"paged KV pools need one uniform ring geometry per layer "
                 f"(families {PAGED_FAMILIES}, no local/global alternation); "
                 f"got {cfg.family!r}")
-        from repro.models.layers.attention import init_attn_cache
+        from repro.models.registry import get_model
         w = force_window or cfg.sliding_window
         ring_len = min(cache_len, w) if w > 0 else cache_len
         block_size = block_size or auto_block_size(ring_len)
@@ -353,10 +356,12 @@ class PagedCachePool(_LanePool):
         self.blocks_per_slot = ring_len // block_size
         n_blocks = pool_blocks or num_slots * self.blocks_per_slot
         dtype = jnp.dtype(cfg.compute_dtype) if dtype is None else dtype
-        dh = cfg.resolved_head_dim()
-        self.cache = jax.vmap(lambda _: init_attn_cache(
-            n_blocks, block_size, cfg.num_kv_heads, dh, dtype))(
-            jnp.arange(cfg.num_layers))
+        # the model's own cache layout with the block count as its batch
+        # and the block size as its ring: (L, n_blocks, block_size, ...)
+        # leaves (GQA k/v, or one latent entry a position), kv_pos
+        # (L, n_blocks, block_size)
+        self.cache = get_model(cfg).init_cache(cfg, n_blocks, block_size,
+                                               dtype=dtype)
         self.allocator = BlockAllocator(n_blocks)
         self.table = np.full((num_slots, self.blocks_per_slot), -1, np.int32)
         # prefix-hash index: key -> {"blocks": tuple, "logits": np | None}.
@@ -413,12 +418,15 @@ class PagedCachePool(_LanePool):
                 y = p[:, safe]                 # (L, T, bs, ...)
                 return y.reshape((p.shape[0], 1, T * bs) + p.shape[3:])
 
-            out = {k: pick(p) for k, p in pool.items()}
             granted = (row >= 0)[None, :, None]
-            kvp = pool["kv_pos"][:, safe]
-            out["kv_pos"] = jnp.where(granted, kvp, -1).reshape(
-                (pool["kv_pos"].shape[0], 1, T * bs))
-            return out
+
+            def snap(path, p):
+                if getattr(path[-1], "key", None) != "kv_pos":
+                    return pick(p)
+                return jnp.where(granted, p[:, safe], -1).reshape(
+                    (p.shape[0], 1, T * bs))
+
+            return jax.tree_util.tree_map_with_path(snap, pool)
 
         self._gather = jax.jit(_gather)
 
@@ -523,8 +531,8 @@ class PagedCachePool(_LanePool):
             return
         idx = np.full((self.num_slots,), self.allocator.n_blocks, np.int32)
         idx[:len(blocks)] = blocks
-        self.cache["kv_pos"] = self._reset(self.cache["kv_pos"],
-                                           jnp.asarray(idx))
+        idx = jnp.asarray(idx)
+        self.cache = map_kv_pos(lambda kp: self._reset(kp, idx), self.cache)
 
     # -- prefix sharing / copy-on-write --------------------------------------
 

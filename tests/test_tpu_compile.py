@@ -111,6 +111,27 @@ def test_flash_decode_paged_compiles(one_chip, block_size, kv_dtype, batch,
     assert names and all("flash_decode" in n for n in names), names
 
 
+def test_flash_decode_latent_compiles(one_chip):
+    """The paged kernel's latent mode at DeepSeek-V2-Lite's serving shape:
+    32 lanes x 4,096 positions in 16-slot pages, 16 query heads over one
+    576-wide latent entry stored 640 wide, values its first 512 columns.
+    The kernel is named ``mla_decode``, by which the benchmark's roofline
+    reader finds it in a device trace."""
+    from repro.kernels.flash_decode import flash_decode
+    s = lambda shp, dt: _sds(one_chip, shp, dt)  # noqa: E731
+    b, slots, bs = 32, 4096, 16
+    nb, T = b * slots // bs, slots // bs
+    args = [s((b, 1, 16, 640), jnp.bfloat16), s((nb, bs, 640), jnp.bfloat16),
+            s((nb, bs), jnp.int32), s((b,), jnp.int32), s((b, T), jnp.int32)]
+
+    def fn(q, latent, kv_pos, q_pos, tbl):
+        return flash_decode(q, latent, None, kv_pos, q_pos, block_tables=tbl,
+                            scale=0.1147, v_dim=512)
+
+    names = _custom_call_names(_compiled_text(fn, *args))
+    assert names and all(n.startswith("mla_decode") for n in names), names
+
+
 @pytest.mark.parametrize("leaf", ["kv", "kv_int8", "kv_scale", "kv_pos"])
 def test_paged_block_copy_compiles(one_chip, leaf):
     """Every leaf of a layer-stacked paged pool: KV tiles, int8 codes,

@@ -74,12 +74,14 @@ def seq_shard_mesh(cache_len: int):
 def sharded_flash_decode(q, k, v, kv_pos, q_pos, mesh, *, k_scale=None,
                          v_scale=None, kind: str = "causal", window: int = 0,
                          prefix_len=None, softcap: float = 0.0,
-                         block_kv: int = 0, block_tables=None):
+                         block_kv: int = 0, block_tables=None, layer=None):
     """One decode step against a cache sharded over ``model`` — the slot
     axis of per-request rings, or the block axis of a paged pool
     (``block_tables`` given: k/v are (n_blocks, block_size, Hk, dh), the
-    table is replicated and localized inside each shard).  Per-shard kernel
-    partials + psum-style combine; same signature/result as
+    table is replicated and localized inside each shard; with ``layer``
+    the leaves are layer-stacked and each shard's (L, n_blocks/m, ...)
+    stripe is read at ``layer`` in place).  Per-shard kernel partials +
+    psum-style combine; same signature/result as
     ``repro.kernels.ops.flash_decode``."""
 
     from repro.kernels import ops
@@ -90,8 +92,9 @@ def sharded_flash_decode(q, k, v, kv_pos, q_pos, mesh, *, k_scale=None,
     bax = _batch_axes(B, shape)
     q_spec = P(bax, None, None, None)
     if paged:
-        kv_spec = P("model", None, None, None)       # pool block axis
-        pos_spec = P("model", None)
+        lead = () if layer is None else (None,)      # the layer axis
+        kv_spec = P(*lead, "model", None, None, None)  # pool block axis
+        pos_spec = P(*lead, "model", None)
     else:
         kv_spec = P(bax, "model", None, None)        # per-request slot axis
         pos_spec = P(bax, "model")
@@ -104,6 +107,9 @@ def sharded_flash_decode(q, k, v, kv_pos, q_pos, mesh, *, k_scale=None,
     if paged:
         args.append(jnp.asarray(block_tables, jnp.int32))
         specs.append(P(bax, None))                   # replicated over model
+    if layer is not None:
+        args.append(jnp.asarray(layer, jnp.int32))
+        specs.append(P())
     if k_scale is not None:
         args += [k_scale, v_scale]
         specs += [kv_spec, kv_spec]
@@ -113,17 +119,19 @@ def sharded_flash_decode(q, k, v, kv_pos, q_pos, mesh, *, k_scale=None,
     def body(q, k, v, kv_pos, qp, plen, *rest):
         rest = list(rest)
         tbl = rest.pop(0) if paged else None
+        lyr = rest.pop(0) if layer is not None else None
         ks, vs = rest if rest else (None, None)
         if paged:
             # localize the table: this shard owns physical blocks
             # [lo, lo + nb_loc); everything else is another shard's problem
-            nb_loc = k.shape[0]
+            nb_loc = kv_pos.shape[-2]
             lo = jax.lax.axis_index("model") * nb_loc
             tbl = jnp.where((tbl >= lo) & (tbl < lo + nb_loc), tbl - lo, -1)
         m, l, acc = ops.flash_decode(
             q, k, v, kv_pos, qp, k_scale=ks, v_scale=vs, kind=kind,
             window=window, prefix_len=plen, softcap=softcap,
-            block_kv=block_kv, block_tables=tbl, return_partials=True)
+            block_kv=block_kv, block_tables=tbl, return_partials=True,
+            layer=lyr)
         m_g = jax.lax.pmax(m, "model")
         w = jnp.exp(m - m_g)
         l_g = jax.lax.psum(l * w, "model")

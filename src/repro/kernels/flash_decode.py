@@ -48,8 +48,11 @@ Two cache layouts, two launches with a kernel body each:
     table bookkeeping.  Latent mode (multi-head latent attention,
     absorbed): the pool is (n_blocks, block_size, D) entries of one KV
     head that all H queries share, no V pages; the values are the pages'
-    first ``v_dim`` columns, and the launch is named ``mla_decode``.  No
-    split-KV here: a
+    first ``v_dim`` columns, and the launch is named ``mla_decode``.  The
+    decode layer scan hands the launch the whole layer-stacked pool
+    (L, n_blocks, ...) and the layer's index, one more scalar-prefetch
+    operand: the page DMAs read at (layer, block), so no layer is ever
+    sliced out of the pool.  No split-KV here: a
     v5e chip has one TensorCore, so splits would fill nothing, and the
     (m, l, acc) partials contract stays for ``return_partials`` alone.
 
@@ -158,30 +161,48 @@ def _combine(m, l, acc, axis: int):
     return acc_tot / jnp.maximum(l_tot, 1e-30)
 
 
-def paged_gather(k, v, kv_pos, k_scale, v_scale, block_tables):
+def paged_gather(k, v, kv_pos, k_scale, v_scale, block_tables, layer=None):
     """Materialize the (B, T*block_size) logical cache view of a paged pool.
 
     k/v: (n_blocks, bs, Hk, dh) pool; block_tables: (B, T) physical block
     ids (-1 == ungranted — its slots come back with position -1, i.e.
-    masked).  The gathered view is bit-identical to the contiguous ring it
-    replaces when T*bs equals the ring length, which is what keeps paged
-    greedy decode exactly equal to the contiguous pool's.  (Off-TPU
-    fallback + oracle only — the Pallas kernel indexes the pool in place.)
+    masked).  ``layer`` given: every leaf is layer-stacked, (L, n_blocks,
+    bs, ...), and the view is layer ``layer``'s: K/V gathered from the
+    stack at (layer, block) without slicing the layer out first, the small
+    per-slot leaves (positions, scales) from the layer's slice.  The gathered
+    view is bit-identical to the contiguous ring it replaces when T*bs
+    equals the ring length, which is what keeps paged greedy decode
+    exactly equal to the contiguous pool's.  The oracle and the XLA
+    decode path use it: off the TPU, and on it for pools shorter than
+    ``REPRO_FLASH_DECODE_MIN_S`` (1,024) positions, such as 512-position
+    lanes; the Pallas kernel reads the pool in place.
     """
     tbl = jnp.asarray(block_tables, jnp.int32)
     B, T = tbl.shape
-    nb = k.shape[0]
+    nb = kv_pos.shape[-2]
     safe = jnp.clip(tbl, 0, nb - 1)
+    if layer is not None:
+        kv_pos = kv_pos[layer]
+        k_scale, v_scale = (None if x is None else x[layer]
+                            for x in (k_scale, v_scale))
 
-    def g(x):
-        y = x[safe]                              # (B, T, bs, ...)
-        return y.reshape((B, T * x.shape[1]) + x.shape[2:])
+    def g(x, idx=safe):
+        y = x[idx]                               # (B, T, bs, ...)
+        return y.reshape((B, T * y.shape[2]) + y.shape[3:])
+
+    def g_kv(x):
+        if layer is None:
+            return g(x)
+        # rows layer * n_blocks + block of the free (L * n_blocks, bs, ...)
+        # view of the stacked leaf
+        return g(x.reshape((-1,) + x.shape[2:]),
+                 safe + jnp.asarray(layer, jnp.int32) * nb)
 
     kv_pos_g = jnp.where(tbl[:, :, None] >= 0, kv_pos[safe], -1)
-    kv_pos_g = kv_pos_g.reshape(B, T * kv_pos.shape[1])
+    kv_pos_g = kv_pos_g.reshape(B, T * kv_pos.shape[-1])
     ks = g(k_scale) if k_scale is not None else None
     vs = g(v_scale) if v_scale is not None else None
-    return g(k), g(v) if v is not None else None, kv_pos_g, ks, vs
+    return g_kv(k), g_kv(v) if v is not None else None, kv_pos_g, ks, vs
 
 
 # ---------------------------------------------------------------------------
@@ -342,7 +363,8 @@ def flash_decode(q, k, v, kv_pos, q_pos, *, k_scale=None, v_scale=None,
                  kind: str = "causal", window: int = 0, prefix_len=None,
                  softcap: float = 0.0, block_kv: int = 0, n_splits: int = 0,
                  block_tables=None, interpret: bool = False,
-                 return_partials: bool = False, scale=None, v_dim: int = 0):
+                 return_partials: bool = False, scale=None, v_dim: int = 0,
+                 layer=None):
     """One fused decode step against the ring (or paged) cache.
 
     q: (B, 1, H, D); k, v: (B, S, Hk, D) ring buffers, or — with
@@ -362,13 +384,19 @@ def flash_decode(q, k, v, kv_pos, q_pos, *, k_scale=None, v_scale=None,
     (latent attention, absorbed); its first ``v_dim`` columns are the
     values, so the output is (B, 1, H, v_dim).  ``scale`` (default
     D ** -0.5) multiplies the scores.
+
+    ``layer`` (paged launch only; a traced int32 scalar): every pool leaf
+    is layer-stacked, (L, n_blocks, ...), and the step attends over layer
+    ``layer``, read in place — the decode layer scan's pool never has a
+    layer sliced out of it.
     """
     if block_tables is not None:
         return _flash_decode_paged(
             q, k, v, kv_pos, block_tables, q_pos, k_scale=k_scale,
             v_scale=v_scale, kind=kind, window=window, prefix_len=prefix_len,
             softcap=softcap, interpret=interpret,
-            return_partials=return_partials, scale=scale, v_dim=v_dim)
+            return_partials=return_partials, scale=scale, v_dim=v_dim,
+            layer=layer)
     B, S, Hk, D = k.shape
     kv_pos = jnp.asarray(kv_pos, jnp.int32)
     if kv_pos.ndim == 1:
@@ -487,14 +515,15 @@ def _place(rows, offs, his, *, bs: int, S: int, fill):
     return row if row.shape[1] == S else row[:, :S]
 
 
-def _paged_kernel(qpos_ref, plen_ref, nlive_ref, tbl_ref, q_ref, kp_hbm,
-                  k_hbm, *refs, pages: int, bs: int, kind: str,
+def _paged_kernel(qpos_ref, plen_ref, nlive_ref, tbl_ref, layer_ref, q_ref,
+                  kp_hbm, k_hbm, *refs, pages: int, bs: int, kind: str,
                   window: int, softcap: float, scale: float, quantized: bool,
                   qk_dtype, qk_precision, v_dim: int):
     """One request row of the paged launch: a loop over the row's live
     steps, each gathering ``pages`` table-named pool pages (all KV heads,
     their slot positions and, for int8 pools, their scales) by DMA,
-    double-buffered so step i+1's pages land while step i computes.
+    double-buffered so step i+1's pages land while step i computes.  The
+    K/V pages come from layer ``layer_ref[0]`` of the layer-stacked pool.
     ``v_dim`` > 0 is latent mode: one KV head, no V pages, the values the
     first ``v_dim`` columns of the K pages."""
     if not v_dim:
@@ -534,8 +563,8 @@ def _paged_kernel(qpos_ref, plen_ref, nlive_ref, tbl_ref, q_ref, kp_hbm,
             blk, _ = page(i, p)
             for hbm, buf in pairs:
                 copies.append(pltpu.make_async_copy(
-                    hbm.at[blk], buf.at[slot, pl.ds(p * bs, bs)],
-                    sem.at[slot]))
+                    hbm.at[layer_ref[0], blk],
+                    buf.at[slot, pl.ds(p * bs, bs)], sem.at[slot]))
             for hbm, buf in rows:
                 copies.append(pltpu.make_async_copy(
                     hbm.at[blk // k_row], buf.at[slot, p], sem.at[slot]))
@@ -616,19 +645,26 @@ def _paged_kernel(qpos_ref, plen_ref, nlive_ref, tbl_ref, q_ref, kp_hbm,
 def _flash_decode_paged(q, k, v, kv_pos, block_tables, q_pos, *, k_scale,
                         v_scale, kind: str, window: int, prefix_len,
                         softcap: float, interpret: bool,
-                        return_partials: bool, scale=None, v_dim: int = 0):
+                        return_partials: bool, scale=None, v_dim: int = 0,
+                        layer=None):
     """Paged-pool kernel launch: grid (B,), one program a request row,
     looping over the row's live table entries in steps of
     ``_pages_per_step`` pages.  The pool stays in HBM; the kernel DMAs
     exactly the pages the table names, all KV heads at once, with their
     slot positions and scales.  ``v`` None: latent mode (one head, the
-    values the pages' first ``v_dim`` columns)."""
-    latent = v is None
-    if latent:
-        (nb, bs, D), Hk, Dv = k.shape, 1, v_dim
+    values the pages' first ``v_dim`` columns).  ``layer`` given: the
+    leaves are layer-stacked; K/V enter whole and the page DMAs read at
+    ``layer``, while the small per-slot rows come from that layer's
+    slice."""
+    if layer is None:               # one layer's leaves: a stack of one
+        k, v, layer = k[None], None if v is None else v[None], 0
     else:
-        nb, bs, Hk, D = k.shape
-        Dv = D
+        kv_pos = kv_pos[layer]
+        if k_scale is not None:
+            k_scale, v_scale = k_scale[layer], v_scale[layer]
+    latent = v is None
+    bs, D = k.shape[2], k.shape[-1]
+    Hk, Dv = (1, v_dim) if latent else (k.shape[3], D)
     tbl = jnp.asarray(block_tables, jnp.int32)
     B, T = tbl.shape
     pages = _pages_per_step(bs, T)
@@ -663,7 +699,7 @@ def _flash_decode_paged(q, k, v, kv_pos, block_tables, q_pos, *, k_scale,
                           quantized=quantized, qk_dtype=qk_dtype,
                           qk_precision=qk_precision, v_dim=v_dim),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=5,
             grid=(B,),
             in_specs=[pl.BlockSpec((1, Hk, G_pad, D),
                                    lambda b, *_: (b, 0, 0, 0))]
@@ -688,7 +724,8 @@ def _flash_decode_paged(q, k, v, kv_pos, block_tables, q_pos, *, k_scale,
         # the name a device trace gives the latent kernel
         **({"name": "mla_decode"} if latent else {}),
     )(_row_pos(q_pos, B), _row_pos(prefix_len, B),
-      live_pages(tbl).astype(jnp.int32), tbl, qg, *hbm)
+      live_pages(tbl).astype(jnp.int32), tbl,
+      jnp.asarray(layer, jnp.int32).reshape(1), qg, *hbm)
     return _finish(m[:, :, None], l[:, :, None], acc[:, :, None], G, q,
                    return_partials)
 
@@ -745,7 +782,7 @@ def flash_decode_xla(q, k, v, kv_pos, q_pos, *, k_scale=None, v_scale=None,
                      kind: str = "causal", window: int = 0, prefix_len=None,
                      softcap: float = 0.0, block_kv: int = 0,
                      block_tables=None, return_partials: bool = False,
-                     scale=None, v_dim: int = 0, **_unused):
+                     scale=None, v_dim: int = 0, layer=None, **_unused):
     """Same signature/semantics as ``flash_decode`` without Pallas.
 
     ``block_kv`` <= 0 picks the measured policy: a single-pass wide form up
@@ -754,10 +791,10 @@ def flash_decode_xla(q, k, v, kv_pos, q_pos, *, k_scale=None, v_scale=None,
     temporaries instead of O(cache_len).  An explicit ``block_kv`` >= S
     also selects the wide form.  Latent mode (``v`` None, ring or pool of
     (.., .., D) entries): one KV head, the values its first ``v_dim``
-    columns."""
+    columns.  ``layer``: a layer-stacked pool, as ``flash_decode``'s."""
     if block_tables is not None:
         k, v, kv_pos, k_scale, v_scale = paged_gather(
-            k, v, kv_pos, k_scale, v_scale, block_tables)
+            k, v, kv_pos, k_scale, v_scale, block_tables, layer)
     if v is None:
         k = k[:, :, None]
         v = k[..., :v_dim]

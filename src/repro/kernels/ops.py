@@ -115,8 +115,9 @@ def flash_decode(q, k, v, kv_pos, q_pos, **kw):
     with jax.named_scope("obs.flash_decode"):
         if use_kernels():
             tbl = kw.get("block_tables")
-            s_logical = (tbl.shape[1] * k.shape[1] if tbl is not None
-                         else k.shape[1])
+            # kv_pos ends in the slot axis: a ring's, or a pool block's
+            s_logical = kv_pos.shape[-1] * (tbl.shape[1] if tbl is not None
+                                            else 1)
             # latent rings (v None, no table): the ring launch has no
             # latent mode
             if _short_cache_xla(s_logical) or (v is None and tbl is None):

@@ -308,19 +308,21 @@ def _decode_block_kv() -> int:
     return int(os.environ.get("REPRO_DECODE_BLOCK_KV", "0"))
 
 
-def _slot_writers(kv_pos, pos, B: int, *, block_tbl=None, ring_len=None):
+def _slot_writers(kv_pos, pos, B: int, *, block_tbl=None, ring_len=None,
+                  layer=None):
     """(upd, upd_pos): write one new entry a row into a cache leaf, and the
     row positions into ``kv_pos``.  ``pos`` is a scalar (synchronous batch)
     or (B,) per-row positions, -1 for an inactive row.
 
     Paged pools (``block_tbl`` (B, T) + ``ring_len``): leaves are (n_blocks,
-    block_size, ...) and a row's ring slot ``pos % ring_len`` resolves
-    through its table row.  Writes scatter with ``mode="drop"``: inactive
-    rows and ungranted blocks index out of bounds and write nothing.
-    Rings: leaves are (B, cache_len, ...); an inactive ragged row keeps its
-    old slot contents."""
+    block_size, ...), or layer-stacked (L, n_blocks, block_size, ...) and
+    written at ``layer``, in place; a row's ring slot ``pos % ring_len``
+    resolves through its table row.  Writes scatter with ``mode="drop"``:
+    inactive rows and ungranted blocks index out of bounds and write
+    nothing.  Rings: leaves are (B, cache_len, ...); an inactive ragged row
+    keeps its old slot contents."""
     if block_tbl is not None:
-        n_blocks, bs = kv_pos.shape[:2]
+        n_blocks, bs = kv_pos.shape[-2:]
         active = pos >= 0
         rl = jnp.asarray(ring_len, jnp.int32)
         slot = jnp.mod(jnp.maximum(pos, 0), rl)             # (B,) ring slot
@@ -328,13 +330,13 @@ def _slot_writers(kv_pos, pos, B: int, *, block_tbl=None, ring_len=None):
         off = slot % bs
         # out-of-bounds index == dropped write (inactive / ungranted rows)
         widx = jnp.where(active & (pb >= 0), pb, n_blocks)
+        at = (widx, off) if layer is None else (layer, widx, off)
 
         def upd(buf, val):
-            return buf.at[widx, off].set(val[:, 0].astype(buf.dtype),
-                                         mode="drop")
+            return buf.at[at].set(val[:, 0].astype(buf.dtype), mode="drop")
 
         def upd_pos(buf):
-            return buf.at[widx, off].set(pos, mode="drop")
+            return buf.at[at].set(pos, mode="drop")
         return upd, upd_pos
     cache_len = kv_pos.shape[1]
     if pos.ndim == 1:
@@ -368,7 +370,7 @@ def _slot_writers(kv_pos, pos, B: int, *, block_tbl=None, ring_len=None):
 
 def attn_decode(params, cfg, x_t, cache, pos, *, window: int = 0,
                 kind: str = "causal", prefix_len=None, block_tbl=None,
-                ring_len=None):
+                ring_len=None, layer=None):
     """One decode step.
 
     x_t: (B, 1, d_in); ``pos`` scalar int32 (synchronous batch decode) OR
@@ -385,6 +387,9 @@ def attn_decode(params, cfg, x_t, cache, pos, *, window: int = 0,
     scatter with ``mode="drop"``: inactive rows and ungranted blocks index
     out of bounds and write nothing, so no freeze pass over the pool is
     needed (allocator invariant: live requests never share a block).
+    ``layer`` given: every pool leaf is layer-stacked (L, n_blocks, ...),
+    and the step writes and reads layer ``layer`` in place (the decode
+    layer scan carries the whole pool; no layer is sliced out of it).
 
     Attention over the cache goes through the fused flash-decode path
     (``repro.kernels.ops.flash_decode``): Pallas kernel on TPU /
@@ -412,9 +417,10 @@ def attn_decode(params, cfg, x_t, cache, pos, *, window: int = 0,
         params, cfg, x_t, None,
         positions=pos_b, kv_positions=pos_b, use_rope=True)
     upd, upd_pos = _slot_writers(cache["kv_pos"], pos, B,
-                                 block_tbl=block_tbl, ring_len=ring_len)
+                                 block_tbl=block_tbl, ring_len=ring_len,
+                                 layer=layer)
     if paged:
-        n_blocks = cache["k"].shape[0]
+        n_blocks = cache["kv_pos"].shape[-2]
 
     new_cache = {}
     if int8:
@@ -439,7 +445,7 @@ def attn_decode(params, cfg, x_t, cache, pos, *, window: int = 0,
                   softcap=cfg.attn_logit_softcap,
                   block_kv=_decode_block_kv())  # kernels clamp to cache_len
         if paged:
-            kw["block_tables"] = block_tbl
+            kw.update(block_tables=block_tbl, layer=layer)
         # sharded layout: slot axis for rings, block axis for paged pools
         mesh = seq_shard_mesh(n_blocks if paged else cache_len)
         if mesh is not None:
@@ -457,7 +463,7 @@ def attn_decode(params, cfg, x_t, cache, pos, *, window: int = 0,
         if paged:
             from repro.kernels.flash_decode import paged_gather
             k_leg, v_leg, pos_leg, ks_leg, vs_leg = paged_gather(
-                k_leg, v_leg, pos_leg, ks_leg, vs_leg, block_tbl)
+                k_leg, v_leg, pos_leg, ks_leg, vs_leg, block_tbl, layer)
         if int8:
             k_full = _dequant_kv(k_leg, ks_leg, q.dtype)
             v_full = _dequant_kv(v_leg, vs_leg, q.dtype)
@@ -629,14 +635,14 @@ def mla_attention(params, cfg, x, *, positions, window: int = 0,
 
 
 def mla_decode(params, cfg, x_t, cache, pos, *, window: int = 0,
-               block_tbl=None, ring_len=None):
+               block_tbl=None, ring_len=None, layer=None):
     """One decode step of latent attention, absorbed: kv_b's key half folds
     into the query (q_nope W_UK gives a ``kv_lora_rank``-wide latent query
     beside the roped one), so every head scores the cached entries
     directly; the output is p.c, then kv_b's value half W_UV, then o.
     ``cache``: ``init_mla_cache`` leaves, a ring or (``block_tbl`` +
-    ``ring_len``) a paged pool; positions and writes as ``attn_decode``.
-    Returns (y_t, new_cache)."""
+    ``ring_len``) a paged pool; positions, writes and ``layer`` as
+    ``attn_decode``.  Returns (y_t, new_cache)."""
     from repro.kernels import ops
     with jax.named_scope("obs.mla"):
         m, H = cfg.mla, cfg.num_heads
@@ -648,7 +654,8 @@ def mla_decode(params, cfg, x_t, cache, pos, *, window: int = 0,
                  else jnp.full((B, 1), pos, jnp.int32))
         q_nope, q_pe = _mla_query(params, cfg, x_t, pos_b)
         upd, upd_pos = _slot_writers(cache["kv_pos"], pos, B,
-                                     block_tbl=block_tbl, ring_len=ring_len)
+                                     block_tbl=block_tbl, ring_len=ring_len,
+                                     layer=layer)
         new_cache = {
             "latent": upd(cache["latent"],
                           _mla_entry(params, cfg, x_t, pos_b)),
@@ -664,7 +671,8 @@ def mla_decode(params, cfg, x_t, cache, pos, *, window: int = 0,
                              jnp.zeros(q_pe.shape[:-1]
                                        + (m.cache_dim - m.entry_dim,),
                                        dtype)], -1)
-        kw = {"block_tables": block_tbl} if block_tbl is not None else {}
+        kw = ({"block_tables": block_tbl, "layer": layer}
+              if block_tbl is not None else {})
         o_lat = ops.flash_decode(q, new_cache["latent"], None,
                                  new_cache["kv_pos"], pos, window=window,
                                  scale=mla_softmax_scale(cfg), v_dim=r, **kw)

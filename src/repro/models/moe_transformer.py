@@ -25,7 +25,8 @@ from repro.models.layers.moe import init_moe, moe_block, moe_serve
 from repro.models.layers.norms import init_rmsnorm, rmsnorm
 from repro.models.transformer import (
     BLOCK_KV, BLOCK_Q, BLOCKWISE_THRESHOLD, _finalize_prefill,
-    _scatter_ring, _seq_constraint, embed_tokens, logits_fn, ring_scatter)
+    _scatter_ring, _seq_constraint, decode_layers, embed_tokens, logits_fn,
+    ring_scatter)
 from repro.models.layers.embeddings import init_embedding
 from repro.models.layers.linear import init_dense
 
@@ -155,18 +156,18 @@ def decode_step(params, cfg: ModelConfig, cache, token, pos, *,
     w = force_window or cfg.sliding_window
     step = mla_decode if cfg.mla is not None else attn_decode
 
-    def body(h, lp_cache):
-        lp, c = lp_cache
-        a, c2 = step(lp["attn"], cfg,
-                     rmsnorm(lp["attn_norm"], h, cfg.norm_eps),
-                     c, pos, window=w, block_tbl=block_tbl,
-                     ring_len=ring_len)
+    def block(lp, h, c, layer):
+        a, c = step(lp["attn"], cfg,
+                    rmsnorm(lp["attn_norm"], h, cfg.norm_eps),
+                    c, pos, window=w, block_tbl=block_tbl,
+                    ring_len=ring_len, layer=layer)
         h = h + a
-        return h + _ffn(lp, cfg, h), c2
+        return h + _ffn(lp, cfg, h), c
 
     caches = []
     for stack, c in _stacks(params, cfg, cache):
-        x, c = jax.lax.scan(body, x, (stack, c))
+        x, c = decode_layers(block, x, stack, c,
+                             paged=block_tbl is not None)
         caches.append(c)
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     return logits_fn(params, cfg, x), _join(cfg, caches)

@@ -104,13 +104,15 @@ def _block(p, cfg: ModelConfig, x, *, positions, window, kind="causal",
 
 
 def _block_decode(p, cfg: ModelConfig, x_t, cache, pos, *, window,
-                  prefix_len=None, block_tbl=None, ring_len=None):
+                  prefix_len=None, block_tbl=None, ring_len=None,
+                  layer=None):
     gemma = cfg.post_block_norm
     h, cache = attn_decode(p["attn"], cfg,
                            rmsnorm(p["attn_norm"], x_t, cfg.norm_eps,
                                    gemma_style=gemma),
                            cache, pos, window=window, prefix_len=prefix_len,
-                           block_tbl=block_tbl, ring_len=ring_len)
+                           block_tbl=block_tbl, ring_len=ring_len,
+                           layer=layer)
     if gemma:
         h = rmsnorm(p["post_attn_norm"], h, cfg.norm_eps, gemma_style=True)
     x_t = x_t + h
@@ -213,6 +215,32 @@ def init_cache(cfg: ModelConfig, batch: int, seq_len: int,
     return mk(jnp.arange(cfg.num_layers))
 
 
+def decode_layers(block, x, stack, cache, *, paged: bool):
+    """Scan ``block(layer params, h, cache, layer) -> (h, cache)`` over a
+    layer stack.
+
+    Rings are the scan's xs/ys: ``block`` sees one layer's ring and
+    ``layer`` None.  A paged pool rides the carry whole and ``block`` sees
+    every layer-stacked leaf with the layer's index, to write and read at
+    it in place: XLA then updates the loop's pool buffer in place (the
+    engine donates it), where xs/ys would slice each layer out, write it
+    back whole into a second stacked buffer and copy that into the output.
+    """
+    if not paged:
+        def body(h, lp_c):
+            lp, c = lp_c
+            return block(lp, h, c, None)
+        return jax.lax.scan(body, x, (stack, cache))
+
+    def body_paged(carry, lp_layer):
+        (h, c), (lp, layer) = carry, lp_layer
+        return block(lp, h, c, layer), None
+    n = jax.tree.leaves(stack)[0].shape[0]
+    carry, _ = jax.lax.scan(body_paged, (x, cache),
+                            (stack, jnp.arange(n, dtype=jnp.int32)))
+    return carry
+
+
 def decode_step(params, cfg: ModelConfig, cache, token, pos, *,
                 force_window: int = 0, prefix_len=None, block_tbl=None,
                 ring_len=None):
@@ -220,7 +248,8 @@ def decode_step(params, cfg: ModelConfig, cache, token, pos, *,
 
     ``block_tbl``/``ring_len`` select the paged-pool cache layout (uniform
     rings only — every layer shares one block geometry and one table; see
-    repro.serve.cache_pool.PagedCachePool)."""
+    repro.serve.cache_pool.PagedCachePool), which the layer scan reads and
+    writes in place (``decode_layers``)."""
     x = embed_tokens(params, cfg, token)
     w = force_window or cfg.sliding_window
 
@@ -239,13 +268,12 @@ def decode_step(params, cfg: ModelConfig, cache, token, pos, *,
             return h, {"local": c_l, "global": c_g}
         x, new_cache = jax.lax.scan(body, x, (params["layers"], cache))
     else:
-        def body(h, lp_cache):
-            lp, c = lp_cache
-            h, c2 = _block_decode(lp, cfg, h, c, pos, window=w,
-                                  prefix_len=prefix_len,
-                                  block_tbl=block_tbl, ring_len=ring_len)
-            return h, c2
-        x, new_cache = jax.lax.scan(body, x, (params["layers"], cache))
+        def block(lp, h, c, layer):
+            return _block_decode(lp, cfg, h, c, pos, window=w,
+                                 prefix_len=prefix_len, block_tbl=block_tbl,
+                                 ring_len=ring_len, layer=layer)
+        x, new_cache = decode_layers(block, x, params["layers"], cache,
+                                     paged=block_tbl is not None)
 
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps,
                 gemma_style=cfg.post_block_norm)

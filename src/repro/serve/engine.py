@@ -111,8 +111,10 @@ per sync point: ``engine.admit.prefill``, ``.first_token``, ``.index``
 (or ``.swap_in``).  Each tick is an ``engine.tick`` span holding its
 phases: ``engine.slo_sweep``, ``engine.schedule``, the admissions,
 ``engine.grant``, ``engine.batch``, ``engine.decode_step`` (``active`` of
-``lanes``; on the paged Pallas flash-decode path also ``live_pages``, the
-table entries the kernel visits), ``engine.emit`` and
+``lanes``; ``in_place`` 1 where the step reads and writes a paged pool at
+each layer in place, 0 where the layer scan slices ring lanes per layer;
+on the paged Pallas flash-decode path also ``live_pages``, the table
+entries the kernel visits), ``engine.emit`` and
 ``engine.swap_drain``; under the JAX profiler every one is also an
 annotation with its args, so host phases and XLA device work share one
 timeline.  A ``pool`` counter track samples
@@ -1035,8 +1037,12 @@ class ForecastEngine:
             self.metrics.record_page_visits(pages["live_pages"],
                                             self.pool.table.size)
         t0 = time.perf_counter()
+        # in_place: a paged pool rides the decode layer scan's carry, read
+        # and written at each layer (transformer.decode_layers); ring lanes
+        # are the scan's per-layer xs/ys
         with obs.span("engine.decode_step", step=self.step_count,
-                      active=len(active), lanes=len(self.slots), **pages):
+                      active=len(active), lanes=len(self.slots),
+                      in_place=int(self.paged), **pages):
             tok, ok, self.pool.cache = self._step_fn(self.params,
                                                      self.pool.cache, batch)
             tok_np = np.asarray(tok)          # blocks until the step lands

@@ -347,6 +347,8 @@ def test_engine_tick_phase_and_admission_spans():
     assert len(dec) == eng.metrics.decode_steps
     assert all(e["args"]["lanes"] == 2 and 1 <= e["args"]["active"] <= 2
                for e in dec)
+    # the paged pool is read and written at each layer in place
+    assert eng.paged and all(e["args"]["in_place"] == 1 for e in dec)
 
     admits = {e["args"]["id"]: e for e in tr.events("engine.admit")}
     assert len(tr.events("engine.admit")) == \
@@ -367,6 +369,16 @@ def test_engine_tick_phase_and_admission_spans():
     # "c" waited in the queue for a lane through at least one decode step
     assert c["queued_ms"] > max(a["queued_ms"], b["queued_ms"])
     assert c["queued_ms"] >= 1e-3 * min(e["dur"] for e in dec)
+
+    # contiguous lanes: the layer scan slices each layer's ring out
+    ring = ForecastEngine(cfg, eng.params, num_slots=2, cache_len=CACHE_LEN,
+                          paged=False)
+    obs.reset()
+    ring.submit(Request(id="d", prompt=_three_requests(cfg)[2].prompt,
+                        max_new_tokens=2))
+    ring.run(max_steps=50)
+    dec = obs.get_tracer().events("engine.decode_step")
+    assert dec and all(e["args"]["in_place"] == 0 for e in dec)
 
 
 def test_engine_spans_on_the_profiler_timeline(tmp_path):
@@ -401,9 +413,9 @@ def test_engine_spans_on_the_profiler_timeline(tmp_path):
             for st in prof["engine.admit"]] == \
         [(e["args"]["queued_ms"], e["args"]["prefilled"], e["args"]["id"])
          for e in admits]
-    assert [(st["active"], st["lanes"], st["step"])
+    assert [(st["active"], st["lanes"], st["step"], st["in_place"])
             for st in prof["engine.decode_step"]] == \
-        [(e["args"]["active"], e["args"]["lanes"], e["args"]["step"])
+        [(e["args"]["active"], e["args"]["lanes"], e["args"]["step"], 1)
          for e in tr.events("engine.decode_step")]
     assert len(prof["engine.tick"]) == tr.span_count("engine.tick")
     for name in _PHASES + ("engine.admit.prefill",

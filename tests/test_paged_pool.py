@@ -3,6 +3,8 @@ fragmentation, partition property), block-table flash-decode vs the oracle,
 engine parity on the paged path (incl. int8), pool-exhaustion parking and
 livelock-breaking eviction, and the seq-sharded paged combine."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -275,6 +277,53 @@ def test_paged_flash_decode_matches_oracle(int8):
                                atol=tol)
 
 
+def _stacked(leaves, L, seed):
+    """``L`` layers of pool leaves: layer 0 the given leaves, the others
+    fresh draws of the same shapes and dtypes (positions kept)."""
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 8 * L))
+    out = []
+    for x in leaves:
+        if x is None or x.dtype == jnp.int32:
+            out.append(None if x is None else jnp.stack([x] * L))
+            continue
+        draw = [x] + [jax.random.normal(next(keys), x.shape).astype(x.dtype)
+                      if x.dtype != jnp.int8 else
+                      jax.random.randint(next(keys), x.shape, -127, 128,
+                                         jnp.int32).astype(jnp.int8)
+                      for _ in range(L - 1)]
+        out.append(jnp.stack(draw))
+    return out
+
+
+@pytest.mark.parametrize("pool", ["bf16", "int8", "latent"])
+@pytest.mark.parametrize("path", ["kernel", "xla"])
+def test_paged_decode_reads_a_stacked_pool_at_its_layer(pool, path):
+    """Given the layer-stacked pool and a layer's index, the paged launch
+    (interpret mode) and the XLA path return exactly what they return on
+    that layer sliced out, for the first and the last layer: the page
+    DMAs and the gather start at the layer, nothing else changes."""
+    q, k, v, kv_pos, tbl, q_pos, kw = _paged_case(pool == "int8", seed=5)
+    if pool == "latent":                      # one 32-wide KV head
+        k, v, q = k[:, :, 0], None, q[:, :, :4]
+    L = 3
+    k, v, kv_pos, ks, vs = _stacked([k, v, kv_pos, kw.get("k_scale"),
+                                     kw.get("v_scale")], L, seed=6)
+    extra = {"v_dim": 16} if pool == "latent" else {}
+    if path == "kernel":
+        fn = functools.partial(flash_decode, interpret=True)
+    else:
+        fn = flash_decode_xla
+    for layer in (0, L - 1):
+        def at(x):
+            return None if x is None else x[layer]
+        want = fn(q, at(k), at(v), at(kv_pos), q_pos, block_tables=tbl,
+                  k_scale=at(ks), v_scale=at(vs), **extra)
+        got = fn(q, k, v, kv_pos, q_pos, block_tables=tbl, k_scale=ks,
+                 v_scale=vs, layer=jnp.int32(layer), **extra)
+        assert np.array_equal(np.asarray(got), np.asarray(want)), layer
+    assert not np.array_equal(np.asarray(k[0]), np.asarray(k[L - 1]))
+
+
 def test_paged_gather_is_bit_identical_to_ring():
     """A fully-granted identity-layout table reproduces the contiguous ring
     EXACTLY — the invariant behind paged == contiguous greedy decode."""
@@ -298,7 +347,8 @@ def test_paged_gather_is_bit_identical_to_ring():
 
 def test_sharded_paged_decode_on_emulated_mesh():
     """Block axis sharded over ``model``: per-shard localized tables +
-    pmax/psum combine must match the unsharded paged path.  Subprocess —
+    pmax/psum combine must match the unsharded paged path, on one layer's
+    leaves and on a layer-stacked pool read at its layer.  Subprocess —
     the device-count flag must precede jax init."""
     import os
     import subprocess
@@ -340,6 +390,16 @@ want = flash_decode_xla(q, k, v, kv_pos, pos, block_tables=tbl)
 np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                            rtol=1e-5, atol=1e-5)
 assert np.all(np.asarray(out)[3] == 0.0)
+# the decode layer scan's form: layer-stacked leaves, each shard's
+# (L, nb/m, ...) stripe read at the layer in place
+ks2 = jax.random.split(jax.random.PRNGKey(1), 2)
+k2 = jnp.stack([jax.random.normal(ks2[0], k.shape), k])
+v2 = jnp.stack([jax.random.normal(ks2[1], v.shape), v])
+with mesh:
+    out2 = sharded_flash_decode(q, k2, v2, jnp.stack([kv_pos] * 2), pos,
+                                mesh, block_tables=tbl, layer=jnp.int32(1))
+np.testing.assert_allclose(np.asarray(out2), np.asarray(want),
+                           rtol=1e-5, atol=1e-5)
 print("SHARDED_PAGED_OK")
 """
     env = dict(os.environ)
@@ -378,6 +438,62 @@ def test_paged_engine_matches_solo(dense):
     assert eng.pool.blocks_in_use == 0
     eng.pool.assert_partition()
     assert eng.metrics.summary()["mean_block_utilization"] > 0
+
+
+@pytest.mark.parametrize("pool", ["bf16", "int8", "latent"])
+@pytest.mark.parametrize("path", ["kernel", "xla"])
+def test_in_place_serve_step_matches_per_layer(dense, monkeypatch, pool,
+                                               path):
+    """One paged serve step whose layer scan carries the pool and reads and
+    writes it at each layer in place gives the per-layer form's next
+    tokens, logits and whole new pool bit for bit — the per-layer form
+    being the ring scan's, each layer's pool sliced out as a scan input and
+    restacked as its output.  The engine's lanes hold an idle lane
+    (position -1) and ungranted (-1) table entries; ``kernel`` is the
+    paged launch in interpret mode."""
+    from repro.launch.steps import make_serve_step
+    from repro.models import moe_transformer, transformer
+    from test_mla_moe import params_for, preset
+    if pool == "int8":
+        monkeypatch.setenv("REPRO_KV_INT8", "1")
+    if pool == "latent":
+        cfg = preset()
+        api, params = get_model(cfg), params_for(cfg)
+    else:
+        cfg, api, params = dense
+    eng = ForecastEngine(cfg, params, num_slots=3, cache_len=CACHE_LEN,
+                         paged=True, block_size=8)
+    for i, n in enumerate([13, 6]):
+        eng.submit(Request(id=f"r{i}", prompt=_prompts(cfg, [n], i)[0],
+                           max_new_tokens=8))
+    eng.step()
+    eng.step()
+    batch, cache = eng.decode_batch(), eng.pool.cache
+    assert (np.asarray(batch["pos"]) == -1).sum() == 1
+    assert (eng.pool.table == -1).any() and (eng.pool.table >= 0).any()
+    if path == "kernel":
+        monkeypatch.setenv("REPRO_FORCE_KERNELS", "1")
+
+    def run():
+        serve = make_serve_step(cfg, sampling=True, guard=True)
+        logits, new = jax.jit(lambda p, c, b: api.decode_step(
+            p, cfg, c, b))(params, cache, batch)
+        tok = jax.jit(serve)(params, cache, batch)[0]
+        return [np.asarray(x) for x in (tok, logits, *jax.tree.leaves(new))]
+
+    in_place = run()
+    ring_scan = transformer.decode_layers
+    for mod in (transformer, moe_transformer):
+        monkeypatch.setattr(mod, "decode_layers",
+                            lambda *a, paged: ring_scan(*a, paged=False))
+    per_layer = run()
+    assert len(in_place) == len(per_layer)
+    for got, want in zip(in_place, per_layer):
+        assert got.dtype == want.dtype and np.array_equal(got, want)
+    # the step wrote this tick's entries: the pool moved
+    before = [np.asarray(x) for x in jax.tree.leaves(cache)]
+    assert any(not np.array_equal(a, b)
+               for a, b in zip(in_place[2:], before))
 
 
 def test_decode_page_visit_share_counts_live_table_entries(dense,

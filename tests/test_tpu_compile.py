@@ -13,6 +13,7 @@ compiled for a described chip cannot be read back without one.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -130,6 +131,116 @@ def test_flash_decode_latent_compiles(one_chip):
 
     names = _custom_call_names(_compiled_text(fn, *args))
     assert names and all(n.startswith("mla_decode") for n in names), names
+
+
+def _latent_cfg():
+    """DeepSeek-V2-Lite as the serving cell runs it (latent entry 576,
+    stored 640 wide; a dense first layer, then 8 held experts of 64 a
+    layer): a 1-layer dense stack and a 26-layer MoE stack."""
+    from repro.configs.base import MLAConfig, ModelConfig, MoEConfig
+    return ModelConfig(
+        name="dsv2-lite-8of64", family="moe", num_layers=27, d_model=2048,
+        num_heads=16, num_kv_heads=16, head_dim=192, d_ff=10944,
+        vocab_size=102400, rope_theta=10000.0, tie_embeddings=False,
+        moe=MoEConfig(num_experts=64, top_k=6, num_shared_experts=2,
+                      expert_d_ff=1408, norm_topk_prob=False,
+                      experts_held=8, first_dense_layers=1),
+        mla=MLAConfig(kv_lora_rank=512, qk_nope_head_dim=128,
+                      qk_rope_head_dim=64, v_head_dim=128, rope_factor=40.0,
+                      mscale=0.707, mscale_all_dim=0.707))
+
+
+# opcodes that would move a whole pool leaf, or one layer of it: the layer
+# scan's per-layer slice and write-back (async slices once a short scan is
+# unrolled), the copy out of a second stacked buffer, and that buffer
+_POOL_MOVES = ("copy", "copy-start", "dynamic-slice", "dynamic-update-slice",
+               "slice", "slice-start")
+_OPCODE = re.compile(r"\s([a-z][a-z0-9-]*)\(")
+_DIMS = re.compile(r"[a-z][a-z0-9]*\[([0-9,]*)\]")
+
+
+def _pool_moves(text, leaf_shapes, whole_shapes=()):
+    """Instructions of a compiled program, fused computations included,
+    that copy, slice or allocate a pool leaf of ``leaf_shapes`` or one
+    layer of it, or a whole stacked leaf of ``whole_shapes`` (unit dims
+    ignored)."""
+    def squeeze(dims):
+        return tuple(d for d in dims if d != 1)
+
+    banned = {squeeze(s) for shp in leaf_shapes for s in (shp, shp[1:])}
+    banned |= {squeeze(s) for s in whole_shapes}
+    hits = []
+    for line in text.splitlines():
+        if " = " not in line:
+            continue
+        lhs, rhs = line.split(" = ", 1)
+        m = _OPCODE.search(" " + rhs)
+        if m is None:
+            continue
+        op = m.group(1)
+        if not (op in _POOL_MOVES or (op == "custom-call"
+                                      and "AllocateBuffer" in rhs)):
+            continue
+        result = rhs[:m.start()]
+        for dims in _DIMS.findall(result):
+            shape = tuple(int(d) for d in dims.split(",") if d)
+            if squeeze(shape) in banned:
+                hits.append(f"{lhs.strip()} = {op} {list(shape)}")
+    return hits
+
+
+# lanes x positions of the serving cells' pools (16-slot blocks)
+_SERVE_GEOMETRY = {"pallas-bf16": (16, 4096), "pallas-int8": (16, 4096),
+                   "latent": (32, 4096), "xla-512": (96, 512)}
+
+
+@pytest.mark.parametrize("case", list(_SERVE_GEOMETRY))
+def test_serve_step_moves_no_pool_layer(one_chip, monkeypatch, case):
+    """The engine's paged serve step, compiled for v5e with the pool
+    donated as the engine donates it, reads and writes the pool in place
+    at each layer: no instruction copies, slices or allocates a K, V or
+    latent leaf, or one layer of it, nor a whole stack of the small
+    per-slot leaves (positions, int8 scales), whose one layer the step may
+    slice.  Cases: the Pallas kernel over bf16
+    and int8 GQA pools and over the 640-wide latent pool, and the XLA
+    decode path at 512 positions (the path the program takes under
+    1,024), each at its serving cell's lanes and positions: a pool small
+    enough for VMEM (128 MiB) is prefetched there by the compiler, whole
+    or a layer at a time, which is a pool move of its own.  ``on_tpu`` is
+    patched so that the trace takes the chip's dispatch."""
+    from repro.kernels import ops
+    from repro.launch.steps import make_serve_step
+    from repro.models.registry import get_model
+    from repro.serve import ForecastEngine
+
+    if case == "pallas-int8":
+        monkeypatch.setenv("REPRO_KV_INT8", "1")
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    cfg = (_latent_cfg() if case == "latent" else CFG.replace(
+        param_dtype="bfloat16", compute_dtype="bfloat16"))
+    (lanes, slots), bs = _SERVE_GEOMETRY[case], 16
+    api = get_model(cfg)
+    # the engine's batch (its own pool is not used: one block)
+    eng = ForecastEngine(cfg, None, num_slots=lanes, cache_len=slots,
+                         paged=True, block_size=bs, pool_blocks=1)
+    batch = eng.decode_batch()
+    cache = jax.eval_shape(lambda: api.init_cache(
+        cfg, lanes * slots // bs, bs, dtype=jnp.bfloat16))
+    params = jax.eval_shape(lambda: api.init(cfg, jax.random.PRNGKey(0)))
+    sds = lambda t: jax.tree.map(  # noqa: E731
+        lambda x: _sds(one_chip, x.shape, x.dtype), t)
+    step = jax.jit(make_serve_step(cfg, sampling=True, guard=True),
+                   donate_argnums=(1,))
+    text = step.lower(sds(params), sds(cache), sds(batch)).compile() \
+        .as_text()
+    leaves = [(getattr(name[-1], "key", None), x.shape) for name, x
+              in jax.tree_util.tree_flatten_with_path(cache)[0]]
+    big = [shp for key, shp in leaves if key in ("k", "v", "latent")]
+    small = [shp for key, shp in leaves if key not in ("k", "v", "latent")]
+    assert len(big) == 2, big        # k and v, or two stacks' latents
+    assert ("tpu_custom_call" in text) == (case != "xla-512")
+    # a one-layer stack's whole is its layer
+    assert _pool_moves(text, big, [s for s in small if s[0] > 1]) == []
 
 
 @pytest.mark.parametrize("leaf", ["kv", "kv_int8", "kv_scale", "kv_pos"])
